@@ -14,6 +14,7 @@ import sys
 import time
 
 from benchmarks import common
+from repro.launch import compile_cache
 
 MODULES = (
     "fig4_convergence",
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale training studies (slow on CPU)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     scale = common.Scale(quick=not args.full)
     names = args.only.split(",") if args.only else list(MODULES)

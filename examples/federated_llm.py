@@ -24,13 +24,13 @@ from repro import configs
 from repro.core import aggregation as agg
 from repro.core import compression as comp
 from repro.data.pipeline import lm_batches
-from repro.launch.mesh import shard_map_compat
+from repro.launch.mesh import make_mesh, shard_map_compat
 from repro.models import api
 
 
 def main() -> None:
     cfg = configs.get("llama3-8b", reduced=True)
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     key = jax.random.key(0)
     params = api.init_params(key, cfg)
     lfn = api.loss_fn(cfg)

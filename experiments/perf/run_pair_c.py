@@ -25,14 +25,14 @@ from repro import configs  # noqa: E402
 from repro.configs.base import SHAPES  # noqa: E402
 from repro.core import mesh_fl  # noqa: E402
 from repro.launch import dryrun, roofline, sharding as shlib  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.models import api  # noqa: E402
 
 ARCH, SHAPE = "llama3_8b", "train_4k"
 
 
 def make_small_multipod():
-    return jax.make_mesh((2, 4, 4), ("pod", "data", "model"))
+    return make_mesh((2, 4, 4), ("pod", "data", "model"))
 
 
 def lower_hfl(cfg, mesh, rho, comp_mode="int8"):

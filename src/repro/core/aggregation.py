@@ -477,10 +477,7 @@ def hierarchical_mean(
 def ring_mix(update: Any, mix_weight: float, axis: str = "pod") -> Any:
     """Gossip mixing with the ring neighbour over ``axis`` — the mesh
     analogue of fog-to-fog cooperation, lowering to collective_permute."""
-    # jax.lax.axis_size is newer-JAX only; psum of the constant 1 over a
-    # named axis folds to the same static size on every version.
-    axis_size = getattr(jax.lax, "axis_size", None)
-    n = int(axis_size(axis) if axis_size else jax.lax.psum(1, axis))
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def mix(leaf):

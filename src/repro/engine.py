@@ -56,8 +56,8 @@ the round loop: local SGD + fused compression run per-shard under
 mesh, and the fog buffers are reduced with psum collectives
 (``aggregation.hierarchical_mean``-style) — the multi-host lever for
 deployments too large for a single device's memory.  It applies to the
-hfl / flat-FL families when the sensor count divides the device count;
-other cells silently run the default placement.
+hfl / flat-FL families; a sensor count the device count does not divide
+raises, and the other families run the default placement.
 
 Benchmarks
 ----------
@@ -250,6 +250,7 @@ class Engine:
         self.percentile = percentile
         self.point_adjusted = point_adjusted
         self._programs: dict[Any, Callable] = {}
+        self._last_calls: list[tuple[Callable, tuple]] = []
         self.compile_count = 0
         self.call_log: list[dict] = []
 
@@ -356,18 +357,24 @@ class Engine:
     def _client_mesh(self, method: str, stacked: SensorDataset):
         """The in-loop client-axis mesh for a ``run`` cell, or None.
 
-        Client sharding needs >1 device, a round-loop family that routes
-        through the fused pipeline (hfl / flat FL), and a sensor count the
-        device count divides; every other cell keeps default placement.
+        Client sharding needs >1 device and a round-loop family that
+        routes through the fused pipeline (hfl / flat FL); other cells keep
+        default placement.  A sensor count the device count does not
+        divide is an error, not a silent drop to one device.
         """
         if not self.shard_clients or method in (
             "centralised", "scaffold", "hfl-async"
         ):
             return None
         devices = jax.devices()
-        n_clients = stacked.train.shape[1]
-        if len(devices) <= 1 or n_clients % len(devices) != 0:
+        if len(devices) <= 1:
             return None
+        n_clients = stacked.train.shape[1]
+        if n_clients % len(devices) != 0:
+            raise ValueError(
+                f"shard_clients needs the sensor count ({n_clients}) to be "
+                f"a multiple of the device count ({len(devices)})"
+            )
         return shard_rules.client_mesh(devices)
 
     def _place(self, tree: Any, n_leading: int) -> Any:
@@ -403,7 +410,10 @@ class Engine:
 
         return jax.tree_util.tree_map(place, tree)
 
-    def _timed_call(self, fn, *args):
+    def _timed_call(self, fn, *args, append: bool = False):
+        """Run ``fn`` to completion; remember it for :meth:`compiled`
+        (``append``: one more program of the same call, a sweep class)."""
+        self._last_calls = (self._last_calls if append else []) + [(fn, args)]
         t0 = time.perf_counter()
         out = fn(*args)
         out = jax.tree_util.tree_map(jax.block_until_ready, out)
@@ -411,6 +421,15 @@ class Engine:
 
     def _log(self, **entry) -> None:
         self.call_log.append(entry)
+
+    def compiled(self) -> list[jax.stages.Compiled]:
+        """The programs the latest ``run`` / ``sweep`` (one per shape-class)
+        / ``audit`` / ``score`` call executed, as compiled for their
+        devices — free, the jit cache holds them.  ``as_text()`` shows a
+        Pallas kernel as a ``tpu_custom_call`` named after its wrapper and
+        a client-sharded cell's fog psum as an ``all-reduce``;
+        ``input_shardings`` shows how the inputs were laid out."""
+        return [fn.lower(*args).compile() for fn, args in self._last_calls]
 
     def take_log(self) -> list[dict]:
         """Drain the per-call log (benchmarks snapshot this into JSON)."""
@@ -482,8 +501,14 @@ class Engine:
 
         fn, fresh = self._get_program(cache_key, build)
         if client_mesh is None:
-            # client-sharded cells leave placement to the in-loop shard_map
             keys, stacked = self._place(keys, s_n), self._place(stacked, s_n)
+        else:
+            # Sensor axis (axis 1 of every stacked leaf) over the client
+            # mesh: the in-loop shard_map then reads local slices as-is.
+            on_mesh = jax.sharding.NamedSharding(
+                client_mesh, jax.sharding.PartitionSpec(None, "data")
+            )
+            stacked = jax.device_put(stacked, on_mesh)
         out, wall = self._timed_call(fn, keys, stacked)
         if store is not None:
             params0 = jax.tree_util.tree_map(lambda a: a[0, 0], out.pop("params"))
@@ -689,7 +714,10 @@ class Engine:
                 (x.shape, str(x.dtype))
                 for x in jax.tree_util.tree_leaves(one)
             )
-            if isinstance(ds, (list, tuple)):
+            # A SensorDataset is itself a (named) tuple: one shared dataset.
+            if isinstance(ds, (list, tuple)) and not isinstance(
+                ds, SensorDataset
+            ):
                 if len(ds) != len(rcfgs):
                     raise ValueError(
                         f"got {len(ds)} datasets for {len(rcfgs)} configs"
@@ -705,7 +733,7 @@ class Engine:
 
         per_cfg: list[Any] = [None] * len(rcfgs)
         classes, wall_total = [], 0.0
-        for sig, idxs in groups.items():
+        for ci, (sig, idxs) in enumerate(groups.items()):
             stacked_cfg = self.stack_configs([norm[i] for i in idxs])
             rep = rcfgs[idxs[0]]
             knobs = dict(self._kernel_static_knobs(rep))
@@ -770,7 +798,7 @@ class Engine:
                     ds_arg, len(idxs) if ds_axis == 0 else s_n
                 )
                 out, wall = self._timed_call(
-                    fn, stacked_cfg, placed_keys, placed_ds
+                    fn, stacked_cfg, placed_keys, placed_ds, append=ci > 0
                 )
             else:
                 l_u = jnp.asarray(
@@ -805,7 +833,8 @@ class Engine:
 
                 fn, fresh = self._get_program(cache_key, build)
                 out, wall = self._timed_call(
-                    fn, stacked_cfg, l_u, midx, self._place(keys, s_n)
+                    fn, stacked_cfg, l_u, midx, self._place(keys, s_n),
+                    append=ci > 0,
                 )
 
             for pos, i in enumerate(idxs):
@@ -957,9 +986,10 @@ class Engine:
         shared ``optim/sgd`` local-training driver (delta exchange).
         """
         from repro.core import mesh_fl
+        from repro.launch.mesh import make_mesh
 
         if mesh is None:
-            mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+            mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
         cache_key = ("pod", repr(model_cfg), tuple(sorted(mesh.shape.items())),
                      rho_s, self_weight, mode, local_epochs)
 

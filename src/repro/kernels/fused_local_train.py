@@ -22,9 +22,10 @@ compress-and-aggregate kernel and the whole sensor side of a round is two
 launches with no dense intermediates.
 
 Layout: ops.py pads the window and every layer dimension (feature dim
-included) to LANES = 128 and the batch rows to SUBLANES = 8, zero-filling
-data/weights/biases and -1-filling index padding.  Zero padding is exact
-end to end: padded window rows are never selected (indices only address
+included) to LANES = 128 and the (steps, batch) index table to SUBLANES
+= 8 on both axes, zero-filling data/weights/biases and -1-filling index
+padding; each SGD step reads its index row with a dynamic ref slice.
+Zero padding is exact end to end: padded window rows are never selected (indices only address
 real rows), padded batch rows select nothing (all-zero one-hot row) and
 are masked out of the loss/gradient, and padded layer lanes stay
 identically zero through forward, backward, and the update (tanh(0) = 0,
@@ -57,7 +58,7 @@ SUBLANES = 8     # batch-row padding unit (f32 sublane count)
 
 def _local_train_kernel(
     x_ref,        # (1, W_pad, D_pad) this client's data window
-    idx_ref,      # (1, B_pad, S_pad) int32 minibatch indices, -1 = padding
+    idx_ref,      # (1, S_pad, B_pad) int32 minibatch indices, -1 = padding
     *refs,
     n_layers: int,
     steps: int,
@@ -83,20 +84,28 @@ def _local_train_kernel(
         sb[li][...] = b_refs[li][...]
 
     x = x_ref[0]                                         # (W_pad, D_pad)
-    idx_all = idx_ref[0]                                 # (B_pad, S_pad)
     w_pad = x.shape[0]
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (1, w_pad), 1)
+    b_pad = idx_ref.shape[2]
+    iota_w = jax.lax.broadcasted_iota(jnp.int32, (w_pad, b_pad), 0)
+    ones_x = jnp.ones_like(x)
     inv_b = 1.0 / batch
+    tn = (((0,), (0,)), ((), ()))                        # contract dim 0
 
     def step(s, loss_sum):
-        idx_col = jax.lax.dynamic_slice(
-            idx_all, (0, s), (idx_all.shape[0], 1)
-        )                                                # (B_pad, 1) int32
-        row_mask = (idx_col >= 0).astype(jnp.float32)    # (B_pad, 1)
-        # Gather-as-matmul: one-hot selector rows pick the minibatch out of
-        # the resident window (padding rows select nothing).
-        sel = (idx_col == iota_w).astype(jnp.float32)    # (B_pad, W_pad)
-        xb = jnp.dot(sel, x, preferred_element_type=jnp.float32)
+        idx_row = idx_ref[0, pl.ds(s, 1), :]             # (1, B_pad) int32
+        # Gather-as-matmul: the transposed one-hot selector (one column per
+        # batch row) picks the minibatch out of the resident window; padded
+        # batch rows select nothing.  HIGHEST keeps the gather exact (a
+        # default-precision f32 dot may round the data to bf16).
+        sel_t = (iota_w == idx_row).astype(jnp.float32)  # (W_pad, B_pad)
+        xb = jax.lax.dot_general(
+            sel_t, x, tn, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )                                                # (B_pad, D_pad)
+        # 1.0 on real batch rows, 0.0 on padding (exact 0/1 sums).
+        row_mask = jax.lax.dot_general(
+            sel_t, ones_x, tn, preferred_element_type=jnp.float32,
+        )
 
         ws_now = [sw[li][...] for li in range(nl)]
         bs_now = [sb[li][...] for li in range(nl)]
@@ -139,7 +148,7 @@ def _local_train_kernel(
     for li in range(nl):
         dw_refs[li][0] = sw[li][...] - w_refs[li][...]
         db_refs[li][0] = sb[li][...] - b_refs[li][...]
-    loss_ref[0, 0] = loss_sum / steps
+    loss_ref[...] = jnp.full(loss_ref.shape, loss_sum / steps, jnp.float32)
 
 
 @functools.partial(
@@ -147,7 +156,7 @@ def _local_train_kernel(
 )
 def local_train_blocks(
     x: jax.Array,                  # (N, W_pad, D_pad) f32 client windows
-    idx: jax.Array,                # (N, B_pad, S_pad) int32, -1 padding
+    idx: jax.Array,                # (N, S_pad, B_pad) int32, -1 padding
     ws: tuple[jax.Array, ...],     # padded weights, (d_in_pad, d_out_pad)
     bs: tuple[jax.Array, ...],     # padded biases, (1, d_out_pad)
     steps: int,                    # real SGD steps (E * nb), <= S_pad
@@ -160,18 +169,19 @@ def local_train_blocks(
 
     Grid = one step per client; the broadcast params stay resident across
     the sweep.  Returns (dws [(N, d_in_pad, d_out_pad)] per layer,
-    dbs [(N, 1, d_out_pad)] per layer, loss (N, 1) f32) — the per-layer
-    parameter deltas and mean local loss; ops.py slices off the padding
-    and assembles the flat ``ravel_pytree``-ordered delta.
+    dbs [(N, 1, d_out_pad)] per layer, loss (N, 1, LANES) f32, the mean
+    local loss broadcast along lanes so its ``(1, LANES)`` block spans
+    the array's last two dims) — ops.py slices off the padding and
+    assembles the flat ``ravel_pytree``-ordered delta.
     """
     n, w_pad, d_pad = x.shape
     assert w_pad % LANES == 0 and d_pad % LANES == 0, x.shape
-    b_pad, s_pad = idx.shape[1], idx.shape[2]
-    assert idx.shape[0] == n and s_pad % LANES == 0, idx.shape
+    s_pad, b_pad = idx.shape[1], idx.shape[2]
+    assert idx.shape[0] == n and s_pad % SUBLANES == 0, idx.shape
     assert 0 < steps <= s_pad and 0 < batch <= b_pad, (steps, batch)
 
     x_spec = pl.BlockSpec((1, w_pad, d_pad), lambda i: (i, 0, 0))
-    idx_spec = pl.BlockSpec((1, b_pad, s_pad), lambda i: (i, 0, 0))
+    idx_spec = pl.BlockSpec((1, s_pad, b_pad), lambda i: (i, 0, 0))
     wb_specs = []
     for w, b in zip(ws, bs):
         wb_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
@@ -184,8 +194,8 @@ def local_train_blocks(
         out_shape.append(jax.ShapeDtypeStruct((n, *b.shape), jnp.float32))
         scratch.append(pltpu.VMEM(w.shape, jnp.float32))
         scratch.append(pltpu.VMEM(b.shape, jnp.float32))
-    out_specs.append(pl.BlockSpec((1, 1), lambda i: (i, 0)))
-    out_shape.append(jax.ShapeDtypeStruct((n, 1), jnp.float32))
+    out_specs.append(pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0)))
+    out_shape.append(jax.ShapeDtypeStruct((n, 1, LANES), jnp.float32))
 
     outs = pl.pallas_call(
         functools.partial(

@@ -22,7 +22,9 @@ paper's 32-16-8-16-32 autoencoder that is four 128x128 f32 matrices,
 ~256 KiB next to a 64 KiB row tile.  Each (SCORE_ROWS, 128) @ (128, 128)
 layer step is MXU-shaped.  Thresholds arrive pre-broadcast per row (the
 serving layer maps per-fog taus onto rows), tiled (1, SCORE_ROWS) like the
-outputs so every block keeps the 128-lane minor dimension.
+outputs: the per-row vectors live as ``(nb, 1, SCORE_ROWS)`` arrays whose
+``(1, SCORE_ROWS)`` blocks span the full array in their last two dims, the
+layout Mosaic accepts for any tile count.
 """
 from __future__ import annotations
 
@@ -47,9 +49,9 @@ def _fused_score_kernel(x_ref, tau_ref, *refs, n_layers: int):
         if li < n_layers - 1:
             h = jnp.tanh(h)
     diff = x - h
-    err = jnp.sum(diff * diff, axis=-1)           # (SCORE_ROWS,)
-    err_ref[...] = err[None, :]
-    flag_ref[...] = (err[None, :] > tau_ref[...]).astype(jnp.float32)
+    err = jnp.sum(diff * diff, axis=-1)[None, None, :]   # (1, 1, SCORE_ROWS)
+    err_ref[...] = err
+    flag_ref[...] = (err > tau_ref[...]).astype(jnp.float32)
 
 
 def _fused_score_q8_kernel(x_ref, tau_ref, *refs, n_layers: int):
@@ -69,15 +71,15 @@ def _fused_score_q8_kernel(x_ref, tau_ref, *refs, n_layers: int):
         if li < n_layers - 1:
             h = jnp.tanh(h)
     diff = x - h
-    err = jnp.sum(diff * diff, axis=-1)           # (SCORE_ROWS,)
-    err_ref[...] = err[None, :]
-    flag_ref[...] = (err[None, :] > tau_ref[...]).astype(jnp.float32)
+    err = jnp.sum(diff * diff, axis=-1)[None, None, :]   # (1, 1, SCORE_ROWS)
+    err_ref[...] = err
+    flag_ref[...] = (err > tau_ref[...]).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def score_blocks_q8(
     x: jax.Array,                  # (R_pad, d_pad) f32, R_pad % SCORE_ROWS == 0
-    tau: jax.Array,                # (nb, SCORE_ROWS) f32 (+inf on padded rows)
+    tau: jax.Array,                # (nb, 1, SCORE_ROWS) f32 (+inf on padded rows)
     qws: tuple[jax.Array, ...],    # padded int8 weights, (d_in_pad, d_out_pad)
     sws: tuple[jax.Array, ...],    # padded scales, (1, d_out_pad) f32
     bs: tuple[jax.Array, ...],     # padded biases, (1, d_out_pad) f32
@@ -91,10 +93,10 @@ def score_blocks_q8(
     r_pad, d_pad = x.shape
     assert r_pad % SCORE_ROWS == 0 and d_pad % LANES == 0, x.shape
     nb = r_pad // SCORE_ROWS
-    assert tau.shape == (nb, SCORE_ROWS), tau.shape
+    assert tau.shape == (nb, 1, SCORE_ROWS), tau.shape
 
     x_spec = pl.BlockSpec((SCORE_ROWS, d_pad), lambda i: (i, 0))
-    row_spec = pl.BlockSpec((1, SCORE_ROWS), lambda i: (i, 0))
+    row_spec = pl.BlockSpec((1, 1, SCORE_ROWS), lambda i: (i, 0, 0))
     wb_specs = []
     for q, s, b in zip(qws, sws, bs):
         wb_specs.append(pl.BlockSpec(q.shape, lambda i: (0, 0)))
@@ -106,8 +108,8 @@ def score_blocks_q8(
         in_specs=[x_spec, row_spec, *wb_specs],
         out_specs=[row_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, SCORE_ROWS), jnp.float32),
-            jax.ShapeDtypeStruct((nb, SCORE_ROWS), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, SCORE_ROWS), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, SCORE_ROWS), jnp.float32),
         ],
         interpret=interpret,
     )(x, tau, *[a for qsb in zip(qws, sws, bs) for a in qsb])
@@ -116,24 +118,24 @@ def score_blocks_q8(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def score_blocks(
     x: jax.Array,                  # (R_pad, d_pad) f32, R_pad % SCORE_ROWS == 0
-    tau: jax.Array,                # (nb, SCORE_ROWS) f32 (+inf on padded rows)
+    tau: jax.Array,                # (nb, 1, SCORE_ROWS) f32 (+inf on padded rows)
     ws: tuple[jax.Array, ...],     # padded weights, (d_in_pad, d_out_pad)
     bs: tuple[jax.Array, ...],     # padded biases, (1, d_out_pad)
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Run the fused score kernel over padded row tiles.
 
-    Returns (err (nb, SCORE_ROWS) f32, flag (nb, SCORE_ROWS) f32 0/1 —
+    Returns (err (nb, 1, SCORE_ROWS) f32, flag (nb, 1, SCORE_ROWS) f32 0/1 —
     float so every output block shares the f32 tiling; ops.py casts back
     to bool after unpadding).
     """
     r_pad, d_pad = x.shape
     assert r_pad % SCORE_ROWS == 0 and d_pad % LANES == 0, x.shape
     nb = r_pad // SCORE_ROWS
-    assert tau.shape == (nb, SCORE_ROWS), tau.shape
+    assert tau.shape == (nb, 1, SCORE_ROWS), tau.shape
 
     x_spec = pl.BlockSpec((SCORE_ROWS, d_pad), lambda i: (i, 0))
-    row_spec = pl.BlockSpec((1, SCORE_ROWS), lambda i: (i, 0))
+    row_spec = pl.BlockSpec((1, 1, SCORE_ROWS), lambda i: (i, 0, 0))
     wb_specs = []
     for w, b in zip(ws, bs):
         wb_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
@@ -144,8 +146,8 @@ def score_blocks(
         in_specs=[x_spec, row_spec, *wb_specs],
         out_specs=[row_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, SCORE_ROWS), jnp.float32),
-            jax.ShapeDtypeStruct((nb, SCORE_ROWS), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, SCORE_ROWS), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, SCORE_ROWS), jnp.float32),
         ],
         interpret=interpret,
     )(x, tau, *[a for wb in zip(ws, bs) for a in wb])

@@ -278,7 +278,8 @@ def _compress_wire_pallas(deltas, err, k: int, quantize: bool,
     idx, q, scale, new_err = _fa.compress_wire_blocks(
         blocks, err_blocks, k, quantize, interpret
     )
-    return idx, q, scale, new_err.reshape(deltas.shape[0], -1)[:, :d]
+    return (idx[:, :, 0, :k], q[:, :, 0, :k], scale[:, :, 0, 0],
+            new_err.reshape(deltas.shape[0], -1)[:, :d])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "quantize"))
@@ -322,8 +323,15 @@ def compress_wire(
 @functools.partial(jax.jit, static_argnames=("n_fog", "d", "interpret"))
 def _wire_aggregate_pallas(idx, q, scale, fog_id, weights, n_fog: int,
                            d: int, interpret: bool):
+    n, nb, k = idx.shape
+    # Kernel layout: slots padded to whole lanes with no-op (index 0,
+    # code 0) slots, the per-block scale broadcast along one lane row.
+    pad = ((0, 0), (0, 0), (0, _fa.slot_pad(k) - k))
     fog_blocks = _fa.wire_aggregate_blocks(
-        idx, q, scale, fog_id, weights, n_fog, interpret
+        jnp.pad(idx, pad)[:, :, None, :],
+        jnp.pad(q.astype(jnp.float32), pad)[:, :, None, :],
+        jnp.broadcast_to(scale[:, :, None, None], (n, nb, 1, _tk.BLOCK_LANES)),
+        fog_id, weights, n_fog, interpret,
     )
     return fog_blocks.reshape(n_fog, -1)[:, :d]
 
@@ -500,7 +508,7 @@ def fused_score(
     )
     tau_pad = jnp.full((rows_pad,), jnp.inf, jnp.float32).at[:r].set(tau_rows)
     err, flag = _fs.score_blocks(
-        x_pad, tau_pad.reshape(-1, _fs.SCORE_ROWS), ws_pad, bs_pad, interpret
+        x_pad, tau_pad.reshape(-1, 1, _fs.SCORE_ROWS), ws_pad, bs_pad, interpret
     )
     return err.reshape(-1)[:r], flag.reshape(-1)[:r] > 0.0
 
@@ -546,7 +554,7 @@ def fused_score_q8(
     )
     tau_pad = jnp.full((rows_pad,), jnp.inf, jnp.float32).at[:r].set(tau_rows)
     err, flag = _fs.score_blocks_q8(
-        x_pad, tau_pad.reshape(-1, _fs.SCORE_ROWS), qws_pad, sws_pad, bs_pad,
+        x_pad, tau_pad.reshape(-1, 1, _fs.SCORE_ROWS), qws_pad, sws_pad, bs_pad,
         interpret,
     )
     return err.reshape(-1)[:r], flag.reshape(-1)[:r] > 0.0
@@ -593,15 +601,14 @@ def _local_train_pallas(
     dims_pad = tuple(max(1, -(-dd // lanes)) * lanes for dd in dims)
     w_pad = max(1, -(-data.shape[1] // lanes)) * lanes
     b_pad = max(1, -(-bsz // sub)) * sub
-    s_pad = max(1, -(-steps // lanes)) * lanes
+    s_pad = max(1, -(-steps // sub)) * sub
     x_pad = (
         jnp.zeros((n, w_pad, dims_pad[0]), jnp.float32)
         .at[:, : data.shape[1], :d].set(data.astype(jnp.float32))
     )
-    idx_t = jnp.swapaxes(idx, 1, 2)                  # (N, bsz, steps)
     idx_pad = (
-        jnp.full((n, b_pad, s_pad), -1, jnp.int32)
-        .at[:, :bsz, :steps].set(idx_t.astype(jnp.int32))
+        jnp.full((n, s_pad, b_pad), -1, jnp.int32)
+        .at[:, :steps, :bsz].set(idx.astype(jnp.int32))
     )
     ws_pad = tuple(
         _pad2(w.astype(jnp.float32), dims_pad[i], dims_pad[i + 1])
@@ -617,7 +624,7 @@ def _local_train_pallas(
     )
     dws = [dw[:, : w.shape[0], : w.shape[1]] for dw, w in zip(dws_p, ws)]
     dbs = [db[:, :, : b.shape[0]] for db, b in zip(dbs_p, bs)]
-    return _ravel_deltas(dws, dbs, n), loss[:, 0]
+    return _ravel_deltas(dws, dbs, n), loss[:, 0, 0]
 
 
 def local_train(
@@ -633,9 +640,9 @@ def local_train(
     phase of a federated round in ONE operator).
 
     Layout owner for :mod:`repro.kernels.fused_local_train`: windows and
-    every layer dimension are zero-padded to LANES multiples, batch rows
-    to SUBLANES, and the index table is transposed to (bsz, steps) and
-    -1-filled so padded rows select nothing.  ``idx`` comes from
+    every layer dimension are zero-padded to LANES multiples, and the
+    (steps, bsz) index table to SUBLANES multiples on both axes, -1-filled
+    so padded rows select nothing.  ``idx`` comes from
     :func:`repro.data.pipeline.multi_epoch_indices`, which makes this
     batch-for-batch identical to ``local_sgd`` over
     ``multi_epoch_batches`` — without the dense (steps, bsz, D) stream.

@@ -163,12 +163,13 @@ def compress_wire_ref(
     Selection is the identical bisection-threshold rule as
     :func:`compress_aggregate_ref` (mask = |v| > t), but instead of a dense
     masked array the survivors are packed into ``k_per_block`` fixed slots
-    per block.  Returns
+    per block, in ascending coordinate order.  Returns
 
-    - ``idx``   (N, nb, k) int32 — within-block coordinate of each slot,
+    - ``idx``   (N, nb, k) int32 — within-block coordinate of each slot;
+      slots past the survivor count carry index 0,
     - ``q``     (N, nb, k) int8 (``quantize``) or f32 — slot values; unused
-      slots (fewer than k survivors) carry value 0, making them no-ops for
-      any consumer that scatter-adds,
+      slots carry value 0, making them no-ops for any consumer that
+      scatter-adds,
     - ``scale`` (N, nb) f32 — per-block dequant scale (block max / 127;
       1.0 when not quantizing so ``q * scale`` is always the recon),
     - ``new_err`` (N, nb, block) — EF state, bit-identical to the dense
@@ -185,13 +186,16 @@ def compress_wire_ref(
     survive = absv > t
     block = v.shape[-1]
     k = min(int(k_per_block), block)
-    # Rank survivors first (absv >= 0 > -1 for non-survivors), then take the
-    # k best slots.  Bisection guarantees <= k_per_block survivors, so every
-    # survivor lands in a slot; surplus slots are masked to exact zeros.
-    rank_key = jnp.where(survive, absv, -1.0)
+    # Survivors first in ascending coordinate order, then the rest: the
+    # top-k of a key that is -coord for survivors and below -block
+    # otherwise.  Bisection guarantees <= k_per_block survivors, so every
+    # survivor lands in a slot; surplus slots are zeroed below.
+    coord = jnp.arange(block, dtype=jnp.float32)
+    rank_key = jnp.where(survive, -coord, -block - coord)
     _, idx = jax.lax.top_k(rank_key, k)
     kept = jnp.take_along_axis(survive, idx, axis=-1)
     vals = jnp.where(kept, jnp.take_along_axis(v, idx, axis=-1), 0.0)
+    idx = jnp.where(kept, idx, 0)
     if quantize:
         # Same scale rule as compress_aggregate_ref: block max of absv (the
         # top survivor IS the block max whenever anything survives).

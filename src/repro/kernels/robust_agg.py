@@ -18,14 +18,18 @@ gathers, no sorting network — only masked reductions, which is exactly what
 vectorises on the VPU.  At ``beta == 0`` the overlap ratio is exactly 1, so
 the kernel degrades to the plain weighted mean (the equivalence pin).
 
-Grid layout: ``(nb, n_fog)`` with the fog axis INNERMOST, so the full
-(N, 1, R, L) column of client reconstructions stays resident in VMEM while
-every fog reduces it (at the paper's N = 200 that is ~800 KiB — fine next
-to the accumulators).  ``fog_id`` / ``weights`` ride in as scalar-prefetch
-operands (SMEM); membership masking is a scalar select per client, so no
-one-hot matrix is materialised.  The O(N^2) pairwise rank pass runs as two
-nested ``fori_loop``s over (R, L) tiles — each iteration is a full VPU tile
-op, and N is the fleet size (tens to low hundreds), not the model dim.
+Grid layout: ``(nb, R / TR, n_fog)`` with the fog axis INNERMOST, so the
+full (N, 1, TR, L) column of client reconstructions stays resident in VMEM
+while every fog reduces it.  The column is tiled along rows so it fits the
+scoped VMEM: ``TR`` is the largest of 64/32/16/8 rows whose double-buffered
+column stays under :data:`COLUMN_BUDGET` (at the paper's N = 200 that is
+the whole 64-row block, 6.4 MiB), and only fleets too large for 8-row
+tiles raise the VMEM limit explicitly.  ``fog_id`` / ``weights`` ride in
+as scalar-prefetch operands (SMEM); membership masking is a scalar select
+per client, so no one-hot matrix is materialised.  The O(N^2) pairwise
+rank pass runs as two nested ``fori_loop``s over (TR, L) tiles — each
+iteration is a full VPU tile op, and N is the fleet size (tens to low
+hundreds), not the model dim.
 
 ``beta`` and the median flag are baked into the kernel body (static), like
 ``lr``/``k`` in the other kernels; traced trim fractions are oracle-only.
@@ -39,20 +43,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_agg import vmem_params
 from repro.kernels.topk_ef import BLOCK_LANES, BLOCK_ROWS
+
+# Double-buffered bytes the resident client column may take before rows
+# are tiled finer (half the default scoped VMEM, leaving the rest for the
+# output tiles and the loop temporaries).
+COLUMN_BUDGET = 8 * 1024 * 1024
 
 
 def _robust_agg_kernel(
     fog_id_ref,   # (N,) int32  scalar prefetch
     w_ref,        # (N,) f32    scalar prefetch
-    v_ref,        # (N, 1, R, L) all client reconstructions for this column
-    out_ref,      # (1, 1, R, L) this fog's robust aggregate
+    v_ref,        # (N, 1, TR, L) all client reconstructions, this row tile
+    out_ref,      # (1, 1, TR, L) this fog's robust aggregate
     *,
     n: int,
     beta: float,
     median: bool,
 ):
-    m = pl.program_id(1)  # fog index (innermost grid axis)
+    m = pl.program_id(2)  # fog index (innermost grid axis)
 
     def member_w(k):
         # Membership-masked weight: scalar select against the prefetched
@@ -64,10 +74,7 @@ def _robust_agg_kernel(
     )
 
     def client_tile(k):
-        return pl.load(
-            v_ref,
-            (pl.dslice(k, 1), pl.dslice(0, 1), slice(None), slice(None)),
-        )
+        return v_ref[k, 0]
 
     def outer(i, carry):
         num, den = carry
@@ -96,9 +103,9 @@ def _robust_agg_kernel(
         eff = w_i * ratio
         return num + eff * v_i, den + eff
 
-    zero = jnp.zeros((1, 1, BLOCK_ROWS, BLOCK_LANES), jnp.float32)
+    zero = jnp.zeros(out_ref.shape[2:], jnp.float32)
     num, den = jax.lax.fori_loop(0, n, outer, (zero, zero))
-    out_ref[...] = num / jnp.maximum(den, 1e-12)
+    out_ref[0, 0] = num / jnp.maximum(den, 1e-12)
 
 
 @functools.partial(
@@ -120,13 +127,15 @@ def robust_aggregate_blocks(
     """
     n, nb = v.shape[:2]
     assert v.shape == (n, nb, BLOCK_ROWS, BLOCK_LANES), v.shape
-    col = pl.BlockSpec((n, 1, BLOCK_ROWS, BLOCK_LANES),
-                       lambda j, m, *_: (0, j, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, BLOCK_ROWS, BLOCK_LANES),
-                            lambda j, m, *_: (m, j, 0, 0))
+    row_bytes = 2 * n * BLOCK_LANES * 4          # double-buffered, per row
+    tr = next((t for t in (64, 32, 16, 8) if t * row_bytes <= COLUMN_BUDGET), 8)
+    col = pl.BlockSpec((n, 1, tr, BLOCK_LANES),
+                       lambda j, r, m, *_: (0, j, r, 0))
+    out_spec = pl.BlockSpec((1, 1, tr, BLOCK_LANES),
+                            lambda j, r, m, *_: (m, j, r, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nb, n_fog),
+        grid=(nb, BLOCK_ROWS // tr, n_fog),
         in_specs=[col],
         out_specs=out_spec,
     )
@@ -138,6 +147,7 @@ def robust_aggregate_blocks(
         out_shape=jax.ShapeDtypeStruct(
             (n_fog, nb, BLOCK_ROWS, BLOCK_LANES), jnp.float32
         ),
+        compiler_params=vmem_params((n + 4) * tr * BLOCK_LANES * 8),
         interpret=interpret,
     )(fog_id.astype(jnp.int32), weights.astype(jnp.float32),
       v.astype(jnp.float32))

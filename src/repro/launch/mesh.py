@@ -10,23 +10,23 @@ import jax
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions.
+    """``jax.shard_map`` with the replication check off: callers here mix
+    collectives in ways the static checker rejects."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
+    )
 
-    Newer JAX exposes ``jax.shard_map`` (replication check flag named
-    ``check_vma``); the 0.4.x line has it under ``jax.experimental`` with
-    the flag named ``check_rep``.  Both checks are disabled — callers here
-    mix collectives in ways the static replication checker rejects.
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which
+    ``with_sharding_constraint`` (the ``shard_hint`` activation
+    constraints and the pod-mesh batch placement) refuses; every mesh in
+    this repo is built here so those constraints stay legal.
     """
-    top_level = getattr(jax, "shard_map", None)
-    if top_level is not None:
-        return top_level(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 
@@ -34,12 +34,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_federated_mesh(*, multi_pod: bool = False):

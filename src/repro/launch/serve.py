@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import api
 
 
@@ -70,6 +71,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.get(args.arch, reduced=True)
     key = jax.random.key(args.seed)

@@ -11,22 +11,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _ambient_mesh():
-    """The mesh installed by ``with mesh:``, or None.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on newer JAX; on the
-    0.4.x line the ambient mesh lives in the pxla thread resources.  Both
-    report axis names/sizes the same way, which is all shard_hint needs.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
 def shard_hint(x: jax.Array, logical: tuple[str | None, ...]) -> jax.Array:
     """Soft activation-sharding constraint (perf: EXPERIMENTS.md §Perf).
 
@@ -45,8 +29,8 @@ def shard_hint(x: jax.Array, logical: tuple[str | None, ...]) -> jax.Array:
     batch-only (heads replicated when indivisible) keeps the attention
     math local; the only added traffic is the per-layer weight gather.
     """
-    mesh = _ambient_mesh()
-    if mesh is None or not mesh.axis_names or mesh.size <= 1:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or mesh.size <= 1:
         return x
     from repro.launch.sharding import resolve_spec  # no circular import
 
@@ -54,10 +38,7 @@ def shard_hint(x: jax.Array, logical: tuple[str | None, ...]) -> jax.Array:
     # step) sharding constraints on the remaining auto axes trip an XLA
     # SPMD-partitioner CHECK (mixed Manual/Auto groups) — let the
     # partitioner choose freely there instead.
-    manual = getattr(getattr(jax.sharding, "AxisType", None), "Manual", None)
-    if manual is not None and any(
-        t == manual for t in getattr(mesh, "axis_types", ())
-    ):
+    if jax.sharding.AxisType.Manual in mesh.axis_types:
         return x
     spec = resolve_spec(logical, x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, spec)

@@ -5,7 +5,7 @@ import numpy as np
 
 from repro.core import aggregation as agg
 from repro.core import cooperation as coop
-from repro.launch.mesh import shard_map_compat
+from repro.launch.mesh import make_mesh, shard_map_compat
 
 
 def test_fog_aggregate_matches_manual():
@@ -65,7 +65,7 @@ def test_hierarchical_mean_shard_map_matches_flat():
     """Mesh two-level reduction == flat weighted mean on a 1x1 mesh."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     update = jnp.arange(4.0)
     weight = jnp.float32(2.0)
 
@@ -81,7 +81,7 @@ def test_hierarchical_mean_shard_map_matches_flat():
 def test_ring_mix_single_device_identity():
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     x = jnp.arange(3.0)
     out = shard_map_compat(
         lambda u: agg.ring_mix(u, 0.3, axis="pod"),

@@ -88,6 +88,47 @@ def test_program_cache_reuses_compilations():
     assert eng.take_log() == []
 
 
+def test_compiled_returns_the_latest_calls_programs():
+    """``compiled()`` hands back the programs the latest call executed: one
+    for a run, one per shape-class for a sweep.  A bare SensorDataset (a
+    named tuple) is one shared sweep dataset, not a per-cell sequence."""
+    eng = eng_mod.Engine()
+    cfg = _small_cfg(rounds=2)
+    eng.run("hfl-nocoop", cfg, (0,), _make_ds)
+    (prog,) = eng.compiled()
+    assert "ENTRY" in prog.as_text()
+    cells = [cfg.replace(compressor=cfg.compressor.replace(rho_s=r))
+             for r in (0.05, 0.1)]
+    sw = eng.sweep("hfl-nocoop", cells, (0,), _make_ds(0))
+    assert len(eng.compiled()) == sw.n_classes >= 1
+
+
+def test_federated_cli_trains_through_engine():
+    """The ``federated`` launcher trains through Engine, so it reports the
+    engine-resolved backend (the jnp oracles on the CPU) and the metrics of
+    the sequential pipeline on the engine-resolved config."""
+    import argparse
+
+    from repro.launch import train
+
+    args = argparse.Namespace(
+        method="hfl-selective", sensors=8, fog=2, rounds=2, local_epochs=1,
+        lr=0.01, dirichlet_alpha=1.0, seed=0,
+    )
+    out = train.run_federated(args)
+    assert out["local_solver"] == "ref" and "[ref]" in out["compressor"]
+    cfg = exp.make_config(n_sensors=8, n_fog=2, rounds=2, local_epochs=1,
+                          lr=0.01)
+    ds = normalize(generate(jax.random.key(0), SyntheticConfig(
+        n_sensors=8, dirichlet_alpha=1.0)))
+    ref = exp.run_method("hfl-selective", ds,
+                         eng_mod.Engine().resolve_config(cfg), seed=0)
+    np.testing.assert_allclose(out["f1"], ref.f1, atol=1e-3)
+    np.testing.assert_allclose(out["energy_j"]["total"], ref.e_total,
+                               rtol=1e-5)
+    np.testing.assert_allclose(out["final_loss"], ref.losses[-1], rtol=1e-4)
+
+
 def test_deployment_axis_varies_topology():
     """n_deployments adds an independent-deployment column per seed."""
     eng = eng_mod.Engine()

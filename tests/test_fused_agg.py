@@ -157,6 +157,38 @@ def test_pallas_interpret_matches_ref(d, quantize):
     np.testing.assert_allclose(np.asarray(ne_p), np.asarray(ne_r), atol=1e-5)
 
 
+@pytest.mark.parametrize(
+    "d,k_frac,quantize",
+    [(1352, 68 / 8192, True), (20000, 0.05, False), (8192, 0.3, True)],
+)
+def test_wire_pallas_interpret_matches_ref(d, k_frac, quantize):
+    """The wire emitter's in-kernel compaction packs exactly the oracle's
+    slots (survivors in ascending coordinate order, padding slots index 0 /
+    code 0), and the wire scatter-accumulate reproduces the oracle's fog
+    sums.  Client 0 is all-zero: nothing survives its bisection."""
+    deltas, err, fog_id, weights = _inputs(5, d, seed=d)
+    deltas, err = deltas.at[0].set(0.0), err.at[0].set(0.0)
+    ref = ops.compress_wire(deltas, err, k_frac, quantize, use_pallas=False)
+    pal = ops.compress_wire(
+        deltas, err, k_frac, quantize, use_pallas=True, interpret=True
+    )
+    idx, q, scale, new_err = (np.asarray(a) for a in pal)
+    np.testing.assert_array_equal(idx, np.asarray(ref[0]))
+    np.testing.assert_array_equal(q, np.asarray(ref[1]))
+    np.testing.assert_array_equal(scale, np.asarray(ref[2]))
+    np.testing.assert_allclose(new_err, np.asarray(ref[3]), rtol=0, atol=1e-6)
+    assert not q[0].any() and not idx[0].any()
+    kept = q != 0
+    for row_idx, row_kept in zip(idx.reshape(-1, idx.shape[-1]),
+                                 kept.reshape(-1, kept.shape[-1])):
+        assert np.all(np.diff(row_idx[row_kept]) > 0)
+    fog_r = ops.wire_aggregate(*ref[:3], fog_id, weights, N_FOG, d)
+    fog_p = ops.wire_aggregate(
+        *pal[:3], fog_id, weights, N_FOG, d, use_pallas=True, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(fog_p), np.asarray(fog_r))
+
+
 def test_round_loop_fused_matches_unfused():
     """End-to-end: hfl.train with the fused default == the legacy
     per-client pipeline (CompressorConfig(fused=False))."""
@@ -226,6 +258,17 @@ _SHMAP_SCRIPT = textwrap.dedent("""
         np.asarray(r1.losses), np.asarray(r2.losses), rtol=1e-4
     )
     np.testing.assert_allclose(np.asarray(r1.f1), np.asarray(r2.f1), atol=1e-6)
+
+    # a fleet the device count does not divide is refused, not run on one
+    odd = exp.make_config(n_sensors=6, n_fog=3, rounds=1, local_epochs=1)
+    odd_ds = normalize(generate(jax.random.key(0), SyntheticConfig(
+        n_sensors=6, train_len=48, val_len=24, test_len=48)))
+    try:
+        sh_eng.run("hfl-selective", odd, (0,), odd_ds)
+    except ValueError as e:
+        assert "multiple of the device count" in str(e), e
+    else:
+        raise AssertionError("shard_clients accepted 6 sensors on 4 devices")
     print("SHARD_MAP_EQUIVALENCE_OK")
 """)
 

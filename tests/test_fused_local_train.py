@@ -18,6 +18,7 @@ from repro.core import cooperation as coop
 from repro.core import hfl
 from repro.data.pipeline import multi_epoch_indices
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import autoencoder as ae
 from repro.optim.sgd import (
     LocalTrainConfig,
@@ -231,7 +232,7 @@ def test_mesh_pod_local_epochs_runs_and_degenerates():
     from repro.models import api
 
     cfg = configs.get("llama3_8b", reduced=True).replace(learning_rate=1e-2)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     key = jax.random.key(0)
     params = api.init_params(key, cfg)
     batch = {"tokens": jax.random.randint(key, (2, 16), 0, cfg.vocab_size)}

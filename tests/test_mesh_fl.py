@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import mesh_fl
+from repro.launch.mesh import make_mesh
 
 
 def test_compact_roundtrip_ef_invariant():
@@ -44,7 +45,7 @@ def test_pod_hfl_step_single_pod_mesh():
     from repro.models import api
 
     cfg = configs.get("llama3_8b", reduced=True).replace(learning_rate=1e-2)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     step = mesh_fl.make_pod_hfl_train_step(cfg, mesh, mode="int8")
     key = jax.random.key(0)
     params = api.init_params(key, cfg)

@@ -9,6 +9,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import sharding as sh
+from repro.launch.mesh import make_mesh
 
 
 class FakeMesh:
@@ -81,7 +82,7 @@ def test_experts_shardable():
 
 
 def test_batch_shardings_on_real_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = {"tokens": jax.ShapeDtypeStruct((8, 128), jax.numpy.int32)}
     out = sh.batch_shardings(specs, mesh)
     assert out["tokens"].spec == P("data", None)

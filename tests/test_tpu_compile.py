@@ -1,0 +1,164 @@
+"""Compile every main-path Pallas kernel for a described TPU v5e chip.
+
+Interpret mode runs the kernel bodies on the CPU but checks none of the
+TPU compiler's rules: block shapes aligned to the (8, 128) tiling, the
+scoped-VMEM budget, which primitives Mosaic can lower.  These tests hand
+each kernel wrapper in ``kernels/ops.py`` shapes placed on one chip of a
+``v5e:2x2`` topology that the installed TPU compiler describes without a
+chip attached, compile at the paper's sizes (N = 200 sensors, d = 1,352,
+n_fog = 20, D = 32, AE 32-16-8-16-32, rho_s = 0.05, buckets 128 / 1,024;
+the wire path also at N = 2,000 in 512-client chunks) and check that the
+compiled program holds the kernel as a ``tpu_custom_call``.  Nothing runs.
+
+The topology is described inside the module fixture and never at import,
+so every xdist worker collects the same tests and only the worker that
+runs this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import aggregation as agg
+from repro.core import compression as comp
+from repro.kernels import ops
+
+N, D_MODEL, N_FOG, DIM = 200, 1352, 20, 32
+DIMS = (DIM, 16, 8, 16, DIM)
+RHO_S = 0.05
+K_FRAC = comp.blockwise_k_frac(D_MODEL, RHO_S)
+PALLAS = dict(use_pallas=True, interpret=False)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep these compiles out of it.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_count(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; count its Mosaic kernels."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call"
+    )
+
+
+def _ae_params(sh, quantized=False):
+    if quantized:
+        return [
+            {"qw": _spec(sh, (a, b), jnp.int8), "sw": _spec(sh, (1, b)),
+             "b": _spec(sh, (b,))}
+            for a, b in zip(DIMS, DIMS[1:])
+        ]
+    return [{"w": _spec(sh, (a, b)), "b": _spec(sh, (b,))}
+            for a, b in zip(DIMS, DIMS[1:])]
+
+
+def test_local_train_compiles(one_chip):
+    window, batch, epochs = 256, 32, 5
+    steps = epochs * (window // batch)
+    n_kernels = _kernel_count(
+        lambda p, x, idx: ops.local_train(p, x, idx, 0.01, 0.0, **PALLAS),
+        _ae_params(one_chip),
+        _spec(one_chip, (N, window, DIM)),
+        _spec(one_chip, (N, steps, batch), jnp.int32),
+    )
+    assert n_kernels >= 1
+
+
+@pytest.mark.parametrize("n_fog", [N_FOG, N], ids=["fogs", "identity"])
+def test_dense_compress_aggregate_compiles(one_chip, n_fog):
+    """``n_fog = N`` is the robust path's per-client segmentation, whose
+    resident accumulator needs the raised VMEM limit."""
+    n_kernels = _kernel_count(
+        lambda dl, e, f, w: ops.compress_aggregate(
+            dl, e, f, w, n_fog, K_FRAC, **PALLAS
+        ),
+        _spec(one_chip, (N, D_MODEL)), _spec(one_chip, (N, D_MODEL)),
+        _spec(one_chip, (N,), jnp.int32), _spec(one_chip, (N,)),
+    )
+    assert n_kernels >= 1
+
+
+def test_wire_emit_compiles(one_chip):
+    n_kernels = _kernel_count(
+        lambda dl, e: ops.compress_wire(dl, e, K_FRAC, **PALLAS),
+        _spec(one_chip, (N, D_MODEL)), _spec(one_chip, (N, D_MODEL)),
+    )
+    assert n_kernels >= 1
+
+
+def test_wire_aggregate_compiles(one_chip):
+    k = ops.wire_k(K_FRAC)
+    n_kernels = _kernel_count(
+        lambda i, q, s, f, w: ops.wire_aggregate(
+            i, q, s, f, w, N_FOG, D_MODEL, **PALLAS
+        ),
+        _spec(one_chip, (N, 1, k), jnp.int32), _spec(one_chip, (N, 1, k)),
+        _spec(one_chip, (N, 1)), _spec(one_chip, (N,), jnp.int32),
+        _spec(one_chip, (N,)),
+    )
+    assert n_kernels >= 1
+
+
+def test_chunked_wire_round_compiles(one_chip):
+    """N = 2,000 in 512-client chunks: the scan body emits and consumes
+    the sparse wire, one kernel each."""
+    n = 2000
+    cfg = comp.CompressorConfig(
+        rho_s=RHO_S, mode="blockwise", use_pallas=True, interpret=False
+    )
+    n_kernels = _kernel_count(
+        lambda dl, e, f, w: agg.compress_and_accumulate(
+            dl, e, f, w, N_FOG, cfg, chunk=512
+        ),
+        _spec(one_chip, (n, D_MODEL)), _spec(one_chip, (n, D_MODEL)),
+        _spec(one_chip, (n,), jnp.int32), _spec(one_chip, (n,)),
+    )
+    assert n_kernels >= 2
+
+
+@pytest.mark.parametrize("mode", ["trimmed", "median"])
+def test_robust_aggregate_compiles(one_chip, mode):
+    n_kernels = _kernel_count(
+        lambda r, f, w: ops.robust_aggregate(
+            r, f, w, N_FOG, 0.1, mode, **PALLAS
+        ),
+        _spec(one_chip, (N, D_MODEL)), _spec(one_chip, (N,), jnp.int32),
+        _spec(one_chip, (N,)),
+    )
+    assert n_kernels >= 1
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_fused_score_compiles(one_chip, bucket, quantized):
+    score = ops.fused_score_q8 if quantized else ops.fused_score
+    n_kernels = _kernel_count(
+        lambda p, x, t: score(x, p, t, **PALLAS),
+        _ae_params(one_chip, quantized),
+        _spec(one_chip, (bucket, DIM)), _spec(one_chip, (bucket,)),
+    )
+    assert n_kernels >= 1
