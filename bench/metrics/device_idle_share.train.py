@@ -1,0 +1,6 @@
+"""Share of the training window in which the device ran nothing."""
+from bench import trace
+
+
+def read(ctx):
+    return trace.idle_percent(ctx.events, ctx.window_ns)
