@@ -355,41 +355,42 @@ def make_round_fn(
             )
 
         # --- 1. association + cooperation decisions (lines 1-7) ----------
-        if drift_on:
-            # Stale assignment, live physics: refresh the carried
-            # sensor->fog assignment every ``reassoc_every`` rounds (round
-            # 0 always refreshes), then recompute distances / feasibility /
-            # clusters from CURRENT geometry against the frozen fog id.
-            t_f = state.t.astype(jnp.float32)
-            cadence = jnp.maximum(
-                jnp.asarray(dr.reassoc_every, jnp.float32), 1.0
+        with jax.named_scope("round.associate"):
+            if drift_on:
+                # Stale assignment, live physics: refresh the carried
+                # sensor->fog assignment every ``reassoc_every`` rounds (round
+                # 0 always refreshes), then recompute distances / feasibility /
+                # clusters from CURRENT geometry against the frozen fog id.
+                t_f = state.t.astype(jnp.float32)
+                cadence = jnp.maximum(
+                    jnp.asarray(dr.reassoc_every, jnp.float32), 1.0
+                )
+                refresh = jnp.mod(t_f, cadence) < 0.5
+                fresh = assoc.nearest_feasible_fog(dep, cfg.channel)
+                assoc_fog = jnp.where(refresh, fresh.fog_id, state.assoc_fog)
+                assoc_ok = jnp.where(refresh, fresh.participates, state.assoc_ok)
+                fa = assoc.assigned_fog_association(
+                    dep, cfg.channel, assoc_fog, assoc_ok
+                )
+            else:
+                assoc_fog, assoc_ok = state.assoc_fog, state.assoc_ok
+                fa = assoc.nearest_feasible_fog(dep, cfg.channel)
+            alive = state.battery > cfg.energy.e_min_j
+            active = fa.participates & alive
+            if fault_on:
+                # Crashed clients drop out like a dead battery: no training,
+                # no transmission, no energy spend this round.
+                active = active & ~flt.draw_crash(
+                    k_crash, alive.shape[0], fl.crash_prob
+                )
+            # Cooperation sees ROUND-ACTIVE cluster sizes (battery included):
+            # a cluster whose sensors are all dead this round holds no
+            # aggregate to exchange, exactly like an empty one — so the
+            # decision, the Eq. 15 mixing, and the Eq. 18/21 masks agree.
+            c_active = jax.ops.segment_sum(
+                active.astype(jnp.int32), fa.fog_id, num_segments=n_fog
             )
-            refresh = jnp.mod(t_f, cadence) < 0.5
-            fresh = assoc.nearest_feasible_fog(dep, cfg.channel)
-            assoc_fog = jnp.where(refresh, fresh.fog_id, state.assoc_fog)
-            assoc_ok = jnp.where(refresh, fresh.participates, state.assoc_ok)
-            fa = assoc.assigned_fog_association(
-                dep, cfg.channel, assoc_fog, assoc_ok
-            )
-        else:
-            assoc_fog, assoc_ok = state.assoc_fog, state.assoc_ok
-            fa = assoc.nearest_feasible_fog(dep, cfg.channel)
-        alive = state.battery > cfg.energy.e_min_j
-        active = fa.participates & alive
-        if fault_on:
-            # Crashed clients drop out like a dead battery: no training,
-            # no transmission, no energy spend this round.
-            active = active & ~flt.draw_crash(
-                k_crash, alive.shape[0], fl.crash_prob
-            )
-        # Cooperation sees ROUND-ACTIVE cluster sizes (battery included):
-        # a cluster whose sensors are all dead this round holds no
-        # aggregate to exchange, exactly like an empty one — so the
-        # decision, the Eq. 15 mixing, and the Eq. 18/21 masks agree.
-        c_active = jax.ops.segment_sum(
-            active.astype(jnp.int32), fa.fog_id, num_segments=n_fog
-        )
-        decision = coop.decide(cfg.rule, dep.fog_pos, c_active, cfg.channel)
+            decision = coop.decide(cfg.rule, dep.fog_pos, c_active, cfg.channel)
 
         # --- 2+3. local training, fused compression + fog aggregation
         # (lines 8-18, Eqs. 30 + 13 as one operator) -----------------------
@@ -417,7 +418,8 @@ def make_round_fn(
         weights = ds.n_samples * delivered.astype(jnp.float32)
 
         if client_mesh is None:
-            deltas, losses = clients_fn(state.params, train, keys)
+            with jax.named_scope("round.local_train"):
+                deltas, losses = clients_fn(state.params, train, keys)
             if fault_on:
                 deltas = flt.corrupt_deltas(
                     k_byz, deltas, fl, prev_delta=state.prev_delta
@@ -425,20 +427,21 @@ def make_round_fn(
             n_nonfinite = jnp.sum(
                 (delivered & flt.nonfinite_rows(deltas)).astype(jnp.int32)
             )
-            if cfg.robust == "mean":
-                fog_sum, fog_weight, new_err = agg.compress_and_accumulate(
-                    deltas, state.err, fa.fog_id, weights, n_fog,
-                    cfg.compressor, chunk=cfg.client_chunk,
-                )
-                fog_delta = fog_sum / jnp.maximum(fog_weight, 1e-12)[:, None]
-            else:
-                fog_delta, fog_weight, new_err = (
-                    agg.robust_compress_and_aggregate(
+            with jax.named_scope("round.aggregate"):
+                if cfg.robust == "mean":
+                    fog_sum, fog_weight, new_err = agg.compress_and_accumulate(
                         deltas, state.err, fa.fog_id, weights, n_fog,
-                        cfg.compressor, cfg.trim_frac, cfg.robust,
-                        chunk=cfg.client_chunk,
+                        cfg.compressor, chunk=cfg.client_chunk,
                     )
-                )
+                    fog_delta = fog_sum / jnp.maximum(fog_weight, 1e-12)[:, None]
+                else:
+                    fog_delta, fog_weight, new_err = (
+                        agg.robust_compress_and_aggregate(
+                            deltas, state.err, fa.fog_id, weights, n_fog,
+                            cfg.compressor, cfg.trim_frac, cfg.robust,
+                            chunk=cfg.client_chunk,
+                        )
+                    )
         else:
             sharded = shard_map_compat(
                 lambda p, dat, kk, e, w, fid: _clients_round(
@@ -450,9 +453,10 @@ def make_round_fn(
                           P("data"), P("data")),
                 out_specs=(P(), P(), P("data"), P("data")),
             )
-            fog_delta, fog_weight, new_err, losses = sharded(
-                state.params, train, keys, state.err, weights, fa.fog_id
-            )
+            with jax.named_scope("round.local_train_aggregate"):
+                fog_delta, fog_weight, new_err, losses = sharded(
+                    state.params, train, keys, state.err, weights, fa.fog_id
+                )
             # Sharded deltas never leave their shard: the isfinite guard
             # inside compress_and_accumulate still protects, only the
             # counter is unavailable there.
@@ -460,55 +464,57 @@ def make_round_fn(
         # Non-participants keep their error buffer and contribute nothing.
         new_err = jnp.where(active[:, None], new_err, state.err)
 
-        fog_model = fog_delta + flat0[None, :]          # theta_m^{t+1/2}
-        mixed = agg.cooperative_mix(fog_model, decision)  # Eq. 15
+        with jax.named_scope("round.global"):
+            fog_model = fog_delta + flat0[None, :]          # theta_m^{t+1/2}
+            mixed = agg.cooperative_mix(fog_model, decision)  # Eq. 15
 
-        # --- 4. global aggregation (Eq. 16, lines 19-21) -------------------
-        # prev=flat0: a dead-network round (every cluster weightless) holds
-        # the global model instead of collapsing it to zeros.
-        new_flat = agg.global_aggregate(mixed, fog_weight, prev=flat0)
-        if cfg.server_opt == "adam":
-            # FedAdam [34]: the aggregated movement is a pseudo-gradient.
-            incr, server = srv.adam_update(
-                new_flat - flat0, state.server, lr=cfg.server_lr
-            )
-            new_flat = flat0 + incr
-        else:
-            server = state.server
-        new_params = unravel(new_flat)
+            # --- 4. global aggregation (Eq. 16, lines 19-21) -------------------
+            # prev=flat0: a dead-network round (every cluster weightless) holds
+            # the global model instead of collapsing it to zeros.
+            new_flat = agg.global_aggregate(mixed, fog_weight, prev=flat0)
+            if cfg.server_opt == "adam":
+                # FedAdam [34]: the aggregated movement is a pseudo-gradient.
+                incr, server = srv.adam_update(
+                    new_flat - flat0, state.server, lr=cfg.server_lr
+                )
+                new_flat = flat0 + incr
+            else:
+                server = state.server
+            new_params = unravel(new_flat)
 
         # --- 5. energy / latency / battery accounting ---------------------
-        l_u = comp.payload_bits(d, cfg.compressor)     # sensor uplink bits
-        l_full = 32.0 * d                               # fog exchanges, dense
-        e_up = en.tx_energy_j(l_u, fa.dist_m, cfg.channel, cfg.energy)
-        e_up = jnp.where(active, e_up, 0.0)
-        e_s2f = jnp.sum(e_up)
+        with jax.named_scope("round.energy"):
+            l_u = comp.payload_bits(d, cfg.compressor)     # sensor uplink bits
+            l_full = 32.0 * d                               # fog exchanges, dense
+            e_up = en.tx_energy_j(l_u, fa.dist_m, cfg.channel, cfg.energy)
+            e_up = jnp.where(active, e_up, 0.0)
+            e_s2f = jnp.sum(e_up)
 
-        fog_active = fog_weight > 0
-        e_ff = en.tx_energy_j(l_full, decision.dist_m, cfg.channel, cfg.energy)
-        e_ff = jnp.where(decision.cooperates & fog_active, e_ff, 0.0)
-        e_f2f = jnp.sum(e_ff)
+            fog_active = fog_weight > 0
+            e_ff = en.tx_energy_j(l_full, decision.dist_m, cfg.channel, cfg.energy)
+            e_ff = jnp.where(decision.cooperates & fog_active, e_ff, 0.0)
+            e_f2f = jnp.sum(e_ff)
 
-        e_fg = en.tx_energy_j(
-            l_full, fa.fog_gateway_dist_m, cfg.channel, cfg.energy
-        )
-        e_fg = jnp.where(fog_active & fa.fog_gateway_feasible, e_fg, 0.0)
-        e_f2g = jnp.sum(e_fg)
+            e_fg = en.tx_energy_j(
+                l_full, fa.fog_gateway_dist_m, cfg.channel, cfg.energy
+            )
+            e_fg = jnp.where(fog_active & fa.fog_gateway_feasible, e_fg, 0.0)
+            e_f2g = jnp.sum(e_fg)
 
-        # Latency (Eq. 21): slowest parallel link per tier + compute time.
-        lat_comm = comm_latency_s(
-            l_u, l_full, active, fa.dist_m, decision, fog_active,
-            fa.fog_gateway_dist_m, cfg.channel,
-        )
-        flops = en.autoencoder_flops(
-            ds.train.shape[-1], (16, 8, 16), ds.train.shape[1], cfg.local_epochs
-        )
-        lat_comp = flops / cfg.compute_rate_flops
-        latency = lat_comm + lat_comp
+            # Latency (Eq. 21): slowest parallel link per tier + compute time.
+            lat_comm = comm_latency_s(
+                l_u, l_full, active, fa.dist_m, decision, fog_active,
+                fa.fog_gateway_dist_m, cfg.channel,
+            )
+            flops = en.autoencoder_flops(
+                ds.train.shape[-1], (16, 8, 16), ds.train.shape[1], cfg.local_epochs
+            )
+            lat_comp = flops / cfg.compute_rate_flops
+            latency = lat_comm + lat_comp
 
-        e_comp = en.compute_energy_j(jnp.float32(flops), cfg.energy)
-        spent = e_up + jnp.where(active, e_comp, 0.0)
-        battery, _ = en.battery_step(state.battery, spent, cfg.energy)
+            e_comp = en.compute_energy_j(jnp.float32(flops), cfg.energy)
+            spent = e_up + jnp.where(active, e_comp, 0.0)
+            battery, _ = en.battery_step(state.battery, spent, cfg.energy)
 
         metrics = RoundMetrics(
             loss=jnp.sum(losses * active_f) / jnp.maximum(jnp.sum(active_f), 1.0),

@@ -74,13 +74,13 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import math
-import time
 from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import async_fl, hfl
 from repro.core import compression as comp
 from repro.core import drift as drf
@@ -414,10 +414,10 @@ class Engine:
         """Run ``fn`` to completion; remember it for :meth:`compiled`
         (``append``: one more program of the same call, a sweep class)."""
         self._last_calls = (self._last_calls if append else []) + [(fn, args)]
-        t0 = time.perf_counter()
-        out = fn(*args)
-        out = jax.tree_util.tree_map(jax.block_until_ready, out)
-        return out, time.perf_counter() - t0
+        with telemetry.span("engine.execute") as call:
+            out = fn(*args)
+            out = jax.tree_util.tree_map(jax.block_until_ready, out)
+        return out, call.seconds
 
     def _log(self, **entry) -> None:
         self.call_log.append(entry)
@@ -435,12 +435,6 @@ class Engine:
         """Drain the per-call log (benchmarks snapshot this into JSON)."""
         entries, self.call_log = self.call_log, []
         return entries
-
-    def stats(self) -> dict:
-        return {
-            "compiled_programs": self.compile_count,
-            "cached_programs": len(self._programs),
-        }
 
     # ------------------------------------------------------------------
     # the three families
@@ -468,54 +462,56 @@ class Engine:
         ``publish_step`` (default ``cfg.rounds``), the hand-off point to
         the serving path (``serving/service.ScoringService``).
         """
-        cfg = self.resolve_config(cfg)
-        seeds = tuple(int(s) for s in seeds)
-        stacked = self._as_stacked(ds, seeds)
-        s_n, p_n = len(seeds), n_deployments
-        keys = self._trial_keys(seeds, p_n)           # (S, P)
-        client_mesh = self._client_mesh(method, stacked)
-        return_params = store is not None
-        shapes = tuple(
-            (x.shape, str(x.dtype)) for x in jax.tree_util.tree_leaves(stacked)
-        )
-        cache_key = ("run", method, _cfg_key(cfg), s_n, p_n, shapes,
-                     self.hidden, self.percentile, self.point_adjusted,
-                     client_mesh.size if client_mesh is not None else 0,
-                     return_params)
-
-        def build():
-            def trial(key, one_ds):
-                return exp.trial_metrics(
-                    method, key, one_ds, cfg,
-                    percentile=self.percentile,
-                    point_adjusted=self.point_adjusted,
-                    hidden=self.hidden,
-                    client_mesh=client_mesh,
-                    return_params=return_params,
-                )
-
-            # Inner vmap broadcasts the seed's dataset over the deployment
-            # columns (no device-side duplication); outer vmap pairs each
-            # seed with its dataset.  Output leaves lead with (S, P).
-            return jax.vmap(jax.vmap(trial, in_axes=(0, None)))
-
-        fn, fresh = self._get_program(cache_key, build)
-        if client_mesh is None:
-            keys, stacked = self._place(keys, s_n), self._place(stacked, s_n)
-        else:
-            # Sensor axis (axis 1 of every stacked leaf) over the client
-            # mesh: the in-loop shard_map then reads local slices as-is.
-            on_mesh = jax.sharding.NamedSharding(
-                client_mesh, jax.sharding.PartitionSpec(None, "data")
+        with telemetry.span("engine.prepare"):
+            cfg = self.resolve_config(cfg)
+            seeds = tuple(int(s) for s in seeds)
+            stacked = self._as_stacked(ds, seeds)
+            s_n, p_n = len(seeds), n_deployments
+            keys = self._trial_keys(seeds, p_n)           # (S, P)
+            client_mesh = self._client_mesh(method, stacked)
+            return_params = store is not None
+            shapes = tuple(
+                (x.shape, str(x.dtype)) for x in jax.tree_util.tree_leaves(stacked)
             )
-            stacked = jax.device_put(stacked, on_mesh)
+            cache_key = ("run", method, _cfg_key(cfg), s_n, p_n, shapes,
+                         self.hidden, self.percentile, self.point_adjusted,
+                         client_mesh.size if client_mesh is not None else 0,
+                         return_params)
+
+            def build():
+                def trial(key, one_ds):
+                    return exp.trial_metrics(
+                        method, key, one_ds, cfg,
+                        percentile=self.percentile,
+                        point_adjusted=self.point_adjusted,
+                        hidden=self.hidden,
+                        client_mesh=client_mesh,
+                        return_params=return_params,
+                    )
+
+                # Inner vmap broadcasts the seed's dataset over the deployment
+                # columns (no device-side duplication); outer vmap pairs each
+                # seed with its dataset.  Output leaves lead with (S, P).
+                return jax.vmap(jax.vmap(trial, in_axes=(0, None)))
+
+            fn, fresh = self._get_program(cache_key, build)
+            if client_mesh is None:
+                keys, stacked = self._place(keys, s_n), self._place(stacked, s_n)
+            else:
+                # Sensor axis (axis 1 of every stacked leaf) over the client
+                # mesh: the in-loop shard_map then reads local slices as-is.
+                on_mesh = jax.sharding.NamedSharding(
+                    client_mesh, jax.sharding.PartitionSpec(None, "data")
+                )
+                stacked = jax.device_put(stacked, on_mesh)
         out, wall = self._timed_call(fn, keys, stacked)
         if store is not None:
-            params0 = jax.tree_util.tree_map(lambda a: a[0, 0], out.pop("params"))
-            store.publish(
-                _base_cfg(cfg).rounds if publish_step is None else publish_step,
-                params0,
-            )
+            with telemetry.span("engine.publish"):
+                params0 = jax.tree_util.tree_map(lambda a: a[0, 0], out.pop("params"))
+                store.publish(
+                    _base_cfg(cfg).rounds if publish_step is None else publish_step,
+                    params0,
+                )
         self._log(kind="run", method=method, label=label or method,
                   n_trials=s_n * p_n, wall_s=wall, fresh_compile=fresh,
                   compressor=_describe_compressor(_base_cfg(cfg).compressor),

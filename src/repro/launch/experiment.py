@@ -194,7 +194,8 @@ def trial_metrics(
             ),
         }
 
-    f1 = _detector_eval(params, ds, percentile, point_adjusted)
+    with jax.named_scope("eval.detector"):
+        f1 = _detector_eval(params, ds, percentile, point_adjusted)
     out.update(f1=f1.f1, precision=f1.precision, recall=f1.recall)
     if return_params:
         out["params"] = params

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import CheckpointStore
 from repro.serving import calibrate as cal
 # Import the functions, not the submodule: the package __init__ re-exports
@@ -117,7 +118,9 @@ class ServiceStats:
     partial_flushes: int = 0  # batches flushed below the chosen bucket fill
     dropped: int = 0          # submissions rejected by the max_queue cap
     psi: float = 0.0          # calibration drift signal (last ingest)
-    busy_s: float = 0.0       # cumulative scoring wall time (all steps)
+    # Cumulative upload + launch + device + download time of all steps
+    # (the ``serve.transfer`` span of each).
+    busy_s: float = 0.0
     # Trace counts per row bucket — shared with (and written by) the
     # ScorePrograms cache, so under multi-tenancy every tenant sees the
     # same per-bucket counts (one compiled program per bucket, period).
@@ -144,8 +147,9 @@ class ServiceStats:
         return float(np.percentile(np.asarray(window), pct))
 
     def step_latency(self, pct: float) -> float:
-        """Percentile of the per-micro-batch DEVICE wall latency (recent
-        window) — batch execution time, not what a request experiences."""
+        """Percentile of one micro-batch's upload + launch + device +
+        download wall time (recent window): neither batch assembly nor the
+        queue wait that a request also experiences."""
         return self._pct(self.step_latency_s, pct)
 
     def e2e_latency(self, pct: float) -> float:
@@ -164,8 +168,8 @@ class ServiceStats:
             "compiles": self.compiles,
             "compiles_by_bucket": dict(self.compiles_by_bucket),
             "partial_flushes": self.partial_flushes,
-            # Device-step percentiles, named for what they are.  The old
-            # "p50_ms"/"p99_ms" keys reported these as request latency.
+            # Percentiles of a step's upload + launch + device + download
+            # time: no queue wait, no batch assembly.
             "step_p50_ms": self.step_latency(50.0) * 1e3,
             "step_p99_ms": self.step_latency(99.0) * 1e3,
             # What a caller actually waits: submit -> completed result.
@@ -337,22 +341,23 @@ class ScoringService:
         bound.  A rejected submit returns ``None`` and bumps
         ``stats.dropped``; nothing else changes.
         """
-        arr = np.asarray(x, np.float32)
-        if arr.shape[-1] != self.d:
-            raise ValueError(f"expected feature dim {self.d}, got {arr.shape}")
-        if self.max_queue is not None and len(self._queue) >= self.max_queue:
-            self.stats.dropped += 1
-            return None
-        lead = arr.shape[:-1]
-        rid = self._next_rid
-        self._next_rid += 1
-        now = self._clock()
-        req = _Request(rid, arr.reshape(-1, self.d), fog, lead, now)
-        self._queue.append(req)
-        self._pending_rows += req.rows.shape[0]
-        self.stats.requests += 1
-        self._maybe_poll(now)
-        return rid
+        with telemetry.span("serve.submit"):
+            arr = np.asarray(x, np.float32)
+            if arr.shape[-1] != self.d:
+                raise ValueError(f"expected feature dim {self.d}, got {arr.shape}")
+            if self.max_queue is not None and len(self._queue) >= self.max_queue:
+                self.stats.dropped += 1
+                return None
+            lead = arr.shape[:-1]
+            rid = self._next_rid
+            self._next_rid += 1
+            now = self._clock()
+            req = _Request(rid, arr.reshape(-1, self.d), fog, lead, now)
+            self._queue.append(req)
+            self._pending_rows += req.rows.shape[0]
+            self.stats.requests += 1
+            self._maybe_poll(now)
+            return rid
 
     def pending_rows(self) -> int:
         """Telemetry rows queued but not yet scheduled into a batch."""
@@ -409,54 +414,65 @@ class ScoringService:
         of real rows scored (0 when idle)."""
         if not self._queue:
             return 0
-        taus = self._taus()
-        bucket = self._pick_bucket()
-        batch = np.zeros((bucket, self.d), np.float32)
-        tau = np.full((bucket,), np.inf, np.float32)
-        taken: list[tuple[_Request, int, int]] = []  # req, start, n
-        fill = 0
-        while self._queue and fill < bucket:
-            req = self._queue[0]
-            n = min(req.rows.shape[0] - req.taken, bucket - fill)
-            batch[fill : fill + n] = req.rows[req.taken : req.taken + n]
-            tau[fill : fill + n] = self._row_tau(req, taus)
-            taken.append((req, fill, n))
-            req.taken += n
-            fill += n
-            if req.taken == req.rows.shape[0]:
-                self._queue.popleft()
-        self._pending_rows -= fill
-        if fill < bucket:
-            self.stats.partial_flushes += 1
+        # Per-request queue waits are worked out only while a profiler
+        # session records (``repro.telemetry``).
+        waits = [] if telemetry.recording() else None
+        t_start = self._clock() if waits is not None else 0.0
+        with telemetry.span("serve.assemble"):
+            taus = self._taus()
+            bucket = self._pick_bucket()
+            batch = np.zeros((bucket, self.d), np.float32)
+            tau = np.full((bucket,), np.inf, np.float32)
+            taken: list[tuple[_Request, int, int]] = []  # req, start, n
+            fill = 0
+            while self._queue and fill < bucket:
+                req = self._queue[0]
+                n = min(req.rows.shape[0] - req.taken, bucket - fill)
+                batch[fill : fill + n] = req.rows[req.taken : req.taken + n]
+                tau[fill : fill + n] = self._row_tau(req, taus)
+                taken.append((req, fill, n))
+                req.taken += n
+                fill += n
+                if req.taken == req.rows.shape[0]:
+                    self._queue.popleft()
+                    if waits is not None:
+                        waits.append(t_start - req.t_submit)
+            self._pending_rows -= fill
+            if fill < bucket:
+                self.stats.partial_flushes += 1
+            fn = self.programs.fn(bucket)
 
-        fn = self.programs.fn(bucket)
-        t0 = time.perf_counter()
-        err, flag = fn(self.params, jnp.asarray(batch), jnp.asarray(tau))
-        err, flag = np.asarray(err), np.asarray(flag)
-        lat = time.perf_counter() - t0
-        # A virtual clock (load replay) advances by the measured device
-        # time, so completion timestamps — and therefore e2e latency —
-        # include it on both the real and the simulated clock.
+        with telemetry.span("serve.transfer") as transfer:
+            err, flag = fn(self.params, jnp.asarray(batch), jnp.asarray(tau))
+            err, flag = np.asarray(err), np.asarray(flag)
+        lat = transfer.seconds
+        # A virtual clock (load replay) advances by the measured transfer
+        # time (upload, device, download), so completion timestamps — and
+        # therefore e2e latency — include it on both the real and the
+        # simulated clock.
         advance = getattr(self._clock, "advance", None)
         if advance is not None:
             advance(lat)
         t_done = self._clock()
 
-        for req, start, n in taken:
-            req.parts_err.append(err[start : start + n])
-            req.parts_flag.append(flag[start : start + n])
-            if req.taken == req.rows.shape[0] and sum(
-                p.shape[0] for p in req.parts_err
-            ) == req.rows.shape[0]:
-                self._done[req.rid] = ScoreResult(
-                    np.concatenate(req.parts_err).reshape(req.lead),
-                    np.concatenate(req.parts_flag).reshape(req.lead),
-                )
-                self.stats.e2e_latency_s.append(t_done - req.t_submit)
-        self.stats.steps += 1
-        self.stats.samples += fill
-        self.stats.step_latency_s.append(lat)
-        self.stats.busy_s += lat
+        with telemetry.span("serve.complete"):
+            for req, start, n in taken:
+                req.parts_err.append(err[start : start + n])
+                req.parts_flag.append(flag[start : start + n])
+                if req.taken == req.rows.shape[0] and sum(
+                    p.shape[0] for p in req.parts_err
+                ) == req.rows.shape[0]:
+                    self._done[req.rid] = ScoreResult(
+                        np.concatenate(req.parts_err).reshape(req.lead),
+                        np.concatenate(req.parts_flag).reshape(req.lead),
+                    )
+                    self.stats.e2e_latency_s.append(t_done - req.t_submit)
+            self.stats.steps += 1
+            self.stats.samples += fill
+            self.stats.step_latency_s.append(lat)
+            self.stats.busy_s += lat
+        if waits is not None:
+            telemetry.observe("serve.queue_wait_s", waits)
         if self.stats.steps % self.poll_every == 0:
             self.poll()
         else:
