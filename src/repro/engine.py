@@ -86,6 +86,7 @@ from repro.core import compression as comp
 from repro.core import drift as drf
 from repro.core import faults as flt
 from repro.data.synthetic import SensorDataset
+from repro.kernels import ops as kops
 from repro.launch import experiment as exp
 from repro.launch import sharding as shard_rules
 from repro.optim.sgd import LocalTrainConfig
@@ -95,6 +96,10 @@ def default_use_pallas() -> bool:
     """Compiled Pallas kernels need a real TPU; elsewhere the engine falls
     back to the pure-jnp oracle in :mod:`repro.kernels.ref`."""
     return jax.default_backend() == "tpu"
+
+
+# Methods whose client phase bypasses ``optim/sgd.make_client_solver``.
+_UNFUSED_CLIENT_PHASE = ("centralised", "scaffold")
 
 
 def _base_cfg(cfg) -> hfl.HFLConfig:
@@ -363,7 +368,7 @@ class Engine:
         divide is an error, not a silent drop to one device.
         """
         if not self.shard_clients or method in (
-            "centralised", "scaffold", "hfl-async"
+            *_UNFUSED_CLIENT_PHASE, "hfl-async"
         ):
             return None
         devices = jax.devices()
@@ -467,6 +472,12 @@ class Engine:
             seeds = tuple(int(s) for s in seeds)
             stacked = self._as_stacked(ds, seeds)
             s_n, p_n = len(seeds), n_deployments
+            solver = _base_cfg(cfg).local_solver
+            if (solver.fused and solver.use_pallas
+                    and method not in _UNFUSED_CLIENT_PHASE):
+                dim = stacked.train.shape[-1]
+                telemetry.observe("engine.local_train_pack",
+                                  kops.local_train_pack((dim, *self.hidden, dim)))
             keys = self._trial_keys(seeds, p_n)           # (S, P)
             client_mesh = self._client_mesh(method, stacked)
             return_params = store is not None

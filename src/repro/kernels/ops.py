@@ -586,6 +586,48 @@ def _local_train_ref(params, data, idx, lr, prox_mu, use_prox: bool):
     return _ravel_deltas(dws, dbs, n), losses
 
 
+def local_train_pack(dims: tuple[int, ...]) -> int:
+    """Clients the local-train kernel packs side by side into each 128-lane
+    tile, from the autoencoder's layer widths ``(D, *hidden, D)``: the
+    paper AE (widths <= 32) packs 4, a D = 38 detector 3, D = 55 two, any
+    width over 64 one."""
+    return max(1, _flt.LANES // max(dims))
+
+
+def _round_up(n: int, unit: int) -> int:
+    return max(1, -(-n // unit)) * unit
+
+
+def _side_by_side(a: jax.Array, pack: int, width: int) -> jax.Array:
+    """(N_packs * pack, rows, d) -> (N_packs, rows, pack * width): client c
+    of each pack at lanes [c * width, c * width + d), zeros between."""
+    m, rows, d = a.shape
+    if pack == 1:
+        return a
+    a = jnp.pad(a, ((0, 0), (0, 0), (0, width - d)))
+    return (a.reshape(m // pack, pack, rows, width).transpose(0, 2, 1, 3)
+            .reshape(m // pack, rows, pack * width))
+
+
+def _diag_copies(a, pack, width, rows, cols, row_step):
+    """``pack`` copies of 2-D ``a`` at (c * row_step, c * width), zeros
+    elsewhere, in a (rows, cols) f32 tile."""
+    out = jnp.zeros((rows, cols), jnp.float32)
+    for c in range(pack):
+        r, l = c * row_step, c * width
+        out = out.at[r:r + a.shape[0], l:l + a.shape[1]].set(a.astype(jnp.float32))
+    return out
+
+
+def _diag_blocks(a, pack, width, shape, row_step, n):
+    """Inverse of :func:`_diag_copies` over packs: (N_packs, R, C) ->
+    (n, *shape), client c of each pack from (c * row_step, c * width)."""
+    rows, cols = shape
+    parts = [a[:, c * row_step:c * row_step + rows, c * width:c * width + cols]
+             for c in range(pack)]
+    return jnp.stack(parts, axis=1).reshape(-1, rows, cols)[:n]
+
+
 @functools.partial(
     jax.jit, static_argnames=("lr", "prox_mu", "interpret")
 )
@@ -594,37 +636,44 @@ def _local_train_pallas(
 ):
     ws = tuple(layer["w"] for layer in params)
     bs = tuple(layer["b"] for layer in params)
-    n, _, d = data.shape
+    n, window, d = data.shape
     steps, bsz = idx.shape[1], idx.shape[2]
     lanes, sub = _flt.LANES, _flt.SUBLANES
     dims = (d,) + tuple(w.shape[1] for w in ws)
-    dims_pad = tuple(max(1, -(-dd // lanes)) * lanes for dd in dims)
-    w_pad = max(1, -(-data.shape[1] // lanes)) * lanes
-    b_pad = max(1, -(-bsz // sub)) * sub
-    s_pad = max(1, -(-steps // sub)) * sub
-    x_pad = (
-        jnp.zeros((n, w_pad, dims_pad[0]), jnp.float32)
-        .at[:, : data.shape[1], :d].set(data.astype(jnp.float32))
-    )
-    idx_pad = (
-        jnp.full((n, s_pad, b_pad), -1, jnp.int32)
-        .at[:, :steps, :bsz].set(idx.astype(jnp.int32))
+    pack, width = local_train_pack(dims), max(dims)
+    n_all = _round_up(n, pack)          # pad clients fill the last pack
+    # Client c of a pack owns lanes [c * width, c * width + d_l) of layer l.
+    dims_pad = tuple(_round_up((pack - 1) * width + dd, lanes) for dd in dims)
+    w_pad = _round_up(window, lanes)
+    b_pad = _round_up(bsz, sub)
+    s_pad = _round_up(steps, sub)
+    x = jnp.zeros((n_all, window, d), jnp.float32).at[:n].set(
+        data.astype(jnp.float32))
+    x = _side_by_side(x, pack, width)
+    x_pad = jnp.pad(x, ((0, 0), (0, w_pad - window), (0, dims_pad[0] - x.shape[2])))
+    # The P index tables side by side: row s of a pack is its clients' step s.
+    idx_pad = _side_by_side(
+        jnp.full((n_all, s_pad, b_pad), -1, jnp.int32)
+        .at[:n, :steps, :bsz].set(idx.astype(jnp.int32)),
+        pack, b_pad,
     )
     ws_pad = tuple(
-        _pad2(w.astype(jnp.float32), dims_pad[i], dims_pad[i + 1])
+        _diag_copies(w, pack, width, dims_pad[i], dims_pad[i + 1], width)
         for i, w in enumerate(ws)
     )
     bs_pad = tuple(
-        _pad2(b.astype(jnp.float32)[None, :], 1, dims_pad[i + 1])
+        _diag_copies(b[None, :], pack, width, 1, dims_pad[i + 1], 0)
         for i, b in enumerate(bs)
     )
     dws_p, dbs_p, loss = _flt.local_train_blocks(
         x_pad, idx_pad, ws_pad, bs_pad, steps, bsz, lr, prox_mu,
-        interpret,
+        interpret, pack=pack, width=width,
     )
-    dws = [dw[:, : w.shape[0], : w.shape[1]] for dw, w in zip(dws_p, ws)]
-    dbs = [db[:, :, : b.shape[0]] for db, b in zip(dbs_p, bs)]
-    return _ravel_deltas(dws, dbs, n), loss[:, 0, 0]
+    dws = [_diag_blocks(dw, pack, width, w.shape, width, n)
+           for dw, w in zip(dws_p, ws)]
+    dbs = [_diag_blocks(db, pack, width, (1, b.shape[0]), 0, n)
+           for db, b in zip(dbs_p, bs)]
+    return _ravel_deltas(dws, dbs, n), loss[:, 0, :pack].reshape(-1)[:n]
 
 
 def local_train(
@@ -639,10 +688,13 @@ def local_train(
     """Fused E-epoch local training for a batch of clients (the client
     phase of a federated round in ONE operator).
 
-    Layout owner for :mod:`repro.kernels.fused_local_train`: windows and
-    every layer dimension are zero-padded to LANES multiples, and the
-    (steps, bsz) index table to SUBLANES multiples on both axes, -1-filled
-    so padded rows select nothing.  ``idx`` comes from
+    Layout owner for :mod:`repro.kernels.fused_local_train`: it packs
+    :func:`local_train_pack` clients side by side into each 128-lane tile
+    (windows and biases in lane blocks, weights as diagonal copies),
+    zero-pads windows and every layer dimension to LANES multiples and the
+    (steps, bsz) index tables to SUBLANES multiples on both axes, -1-filled
+    so padded rows select nothing, then cuts each client's diagonal block
+    back out.  ``idx`` comes from
     :func:`repro.data.pipeline.multi_epoch_indices`, which makes this
     batch-for-batch identical to ``local_sgd`` over
     ``multi_epoch_batches`` — without the dense (steps, bsz, D) stream.

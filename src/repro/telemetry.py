@@ -27,6 +27,7 @@ not locked: record from one thread.
 | ``engine.prepare`` | span, ns | ``Engine.run``: config, data, keys, placement |
 | ``engine.execute`` | span, ns | ``Engine._timed_call``: launch and wait |
 | ``engine.publish`` | span, ns | ``Engine.run``: hand-off of trial (0, 0) to a store |
+| ``engine.local_train_pack`` | counter | ``Engine.run``, on the Pallas local-train path: clients per kernel tile |
 """
 from __future__ import annotations
 

@@ -77,25 +77,34 @@ def test_fused_ref_matches_scan(window, batch_size, epochs, mu):
     np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_leg), rtol=1e-6)
 
 
-@pytest.mark.parametrize(
-    "window,batch_size,epochs,mu",
-    [
-        (64, 32, 1, 0.0),
-        (64, 32, 5, 0.0),
-        (70, 32, 3, 0.01),
-        (40, 16, 2, 0.0),
-    ],
-)
-def test_pallas_interpret_matches_oracle(window, batch_size, epochs, mu):
-    """The kernel body (interpret mode) must agree with the jnp oracle:
-    identical batch assembly from the resident window, manual backward ==
-    autodiff to float tolerance."""
-    params = _params()
-    data = _clients(3, window, seed=window)
-    keys = jax.random.split(jax.random.key(4), 3)
-    idx = jax.vmap(
+def _idx(n, window, batch_size, epochs, seed=4):
+    keys = jax.random.split(jax.random.key(seed), n)
+    return jax.vmap(
         lambda k: multi_epoch_indices(k, window, batch_size, epochs)
     )(keys)
+
+
+@pytest.mark.parametrize(
+    "window,batch_size,epochs,mu,dim,n",
+    [
+        pytest.param(64, 32, 1, 0.0, D, 3, id="64-32-1-0.0"),
+        pytest.param(64, 32, 5, 0.0, D, 3, id="64-32-5-0.0"),
+        pytest.param(70, 32, 3, 0.01, D, 3, id="70-32-3-0.01"),
+        pytest.param(40, 16, 2, 0.0, D, 3, id="40-16-2-0.0"),
+    ] + [
+        # D = 32 / 38 / 55 / 100 packs P = 4 / 3 / 2 / 1 clients a tile;
+        # N = 1 and 7 leave pad clients in the last pack.
+        pytest.param(64, 32, 1, mu, dim, n, id=f"D{dim}-N{n}-mu{mu}")
+        for dim in (32, 38, 55, 100) for n in (1, 7, 8) for mu in (0.0, 0.01)
+    ],
+)
+def test_pallas_interpret_matches_oracle(window, batch_size, epochs, mu, dim, n):
+    """The kernel body (interpret mode) must agree with the jnp oracle:
+    identical batch assembly from the resident window, manual backward ==
+    autodiff to float tolerance, whatever the packing."""
+    params = _params(dim=dim)
+    data = jax.random.normal(jax.random.key(window), (n, window, dim))
+    idx = _idx(n, window, batch_size, epochs)
     d_ref, l_ref = ops.local_train(
         params, data, idx, 0.05, mu, use_pallas=False
     )
@@ -107,6 +116,50 @@ def test_pallas_interpret_matches_oracle(window, batch_size, epochs, mu):
     )
     np.testing.assert_allclose(
         np.asarray(l_pl), np.asarray(l_ref), rtol=1e-5, atol=1e-7
+    )
+
+
+def test_packed_clients_equal_one_client_per_tile():
+    """Off-block weights never leak: 7 paper clients packed 4 to a tile
+    (one pad client in the second pack), FedProx on, give exactly the
+    deltas of the one-client-per-tile layout.  That layout is reached by
+    zero-padding the detector's input to 65 features (widths over 64 pack
+    one client a tile); the padding adds exact zeros only, so the kernel
+    tiles are those of the 32-feature detector at P = 1."""
+    wide = 65
+    n, window = 7, 64
+    params = _params()
+    data = _clients(n, window)
+    idx = _idx(n, window, 32, 2)
+    assert ops.local_train_pack((D, *HIDDEN, D)) == 4
+    assert ops.local_train_pack((wide, *HIDDEN, wide)) == 1
+    d_packed, l_packed = ops.local_train(
+        params, data, idx, 0.05, 0.01, use_pallas=True, interpret=True
+    )
+
+    params_wide = [dict(layer) for layer in params]
+    params_wide[0]["w"] = jnp.pad(params[0]["w"], ((0, wide - D), (0, 0)))
+    params_wide[-1]["w"] = jnp.pad(params[-1]["w"], ((0, 0), (0, wide - D)))
+    params_wide[-1]["b"] = jnp.pad(params[-1]["b"], (0, wide - D))
+    data_wide = jnp.pad(data, ((0, 0), (0, 0), (0, wide - D)))
+    d_one, l_one = ops.local_train(
+        params_wide, data_wide, idx, 0.05, 0.01, use_pallas=True,
+        interpret=True,
+    )
+    unravel = ravel_pytree(params_wide)[1]
+    got = [unravel(row) for row in d_one]
+    for i in range(n):
+        # the padded features' deltas are exact zeros
+        assert not np.any(np.asarray(got[i][0]["w"][D:]))
+        assert not np.any(np.asarray(got[i][-1]["w"][:, D:]))
+        assert not np.any(np.asarray(got[i][-1]["b"][D:]))
+        got[i][0]["w"] = got[i][0]["w"][:D]
+        got[i][-1]["w"] = got[i][-1]["w"][:, :D]
+        got[i][-1]["b"] = got[i][-1]["b"][:D]
+    d_one_narrow = jnp.stack([ravel_pytree(g)[0] for g in got])
+    np.testing.assert_array_equal(np.asarray(d_packed), np.asarray(d_one_narrow))
+    np.testing.assert_allclose(
+        np.asarray(l_packed), np.asarray(l_one), rtol=1e-6
     )
 
 

@@ -13,6 +13,7 @@ from repro import engine as eng_mod
 from repro import telemetry
 from repro.checkpoint import CheckpointStore
 from repro.data.synthetic import SyntheticConfig, generate, normalize
+from repro.kernels import ops
 from repro.launch import experiment as exp
 from repro.loadgen import VirtualClock
 from repro.models import autoencoder as ae
@@ -155,3 +156,25 @@ def test_round_phases_are_named_scopes_of_the_compiled_program(engine_run):
              for p in re.split(r"[/()]", name)}
     for scope in ROUND_SCOPES:
         assert scope in parts, scope
+
+
+def test_the_oracle_path_records_no_local_train_pack(engine_run):
+    _, s = engine_run
+    assert "engine.local_train_pack" not in s
+
+
+def test_the_pallas_path_records_the_local_train_pack_once_per_job(tmp_path, monkeypatch):
+    data = normalize(generate(jax.random.key(0), SyntheticConfig(
+        n_sensors=12, train_len=48, val_len=24, test_len=48)))
+    cfg = exp.make_config(n_sensors=12, n_fog=3, rounds=1, local_epochs=1)
+    # The Pallas local-train path, its kernel body interpreted on this host.
+    monkeypatch.setattr(eng_mod.Engine, "resolve_local_solver",
+                        lambda self, ls: ls.replace(use_pallas=True, interpret=True))
+    eng = eng_mod.Engine()
+    with jax.profiler.trace(str(tmp_path)):
+        for seeds in ((1,), (2,)):
+            eng.run("hfl-selective", cfg, seeds, data)
+    dim = data.train.shape[-1]
+    pack = ops.local_train_pack((dim, *eng.hidden, dim))
+    assert pack == 128 // max(dim, *eng.hidden) > 1
+    np.testing.assert_array_equal(telemetry.records("engine.local_train_pack"), [pack, pack])

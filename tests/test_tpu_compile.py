@@ -15,6 +15,7 @@ so every xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,16 +77,37 @@ def _ae_params(sh, quantized=False):
             for a, b in zip(DIMS, DIMS[1:])]
 
 
+def _kernel_names(fn, *args) -> list[str]:
+    """Compile ``fn`` for the described chip; for each Mosaic kernel, the
+    innermost ``jit(<name>)`` before ``pallas_call`` in its op_name (the
+    name a profile of the chip attributes the kernel's time to)."""
+    names = []
+    for line in jax.jit(fn).lower(*args).compile().as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        parts = op_name.group(1).split("/") if op_name else []
+        if "pallas_call" in parts:
+            jits = [m.group(1) for p in parts[:parts.index("pallas_call")]
+                    if (m := re.fullmatch(r"jit\((.*)\)", p))]
+            names.append(jits[-1] if jits else "")
+    return names
+
+
 def test_local_train_compiles(one_chip):
+    """The packed kernel (the paper AE puts 4 clients in each 128-lane
+    tile) compiles at N = 200 and 2,000 and keeps its wrapper's name."""
     window, batch, epochs = 256, 32, 5
     steps = epochs * (window // batch)
-    n_kernels = _kernel_count(
-        lambda p, x, idx: ops.local_train(p, x, idx, 0.01, 0.0, **PALLAS),
-        _ae_params(one_chip),
-        _spec(one_chip, (N, window, DIM)),
-        _spec(one_chip, (N, steps, batch), jnp.int32),
-    )
-    assert n_kernels >= 1
+    assert ops.local_train_pack(DIMS) == 4
+    for n in (N, 2000):
+        names = _kernel_names(
+            lambda p, x, idx: ops.local_train(p, x, idx, 0.01, 0.0, **PALLAS),
+            _ae_params(one_chip),
+            _spec(one_chip, (n, window, DIM)),
+            _spec(one_chip, (n, steps, batch), jnp.int32),
+        )
+        assert names == ["local_train_blocks"], n
 
 
 @pytest.mark.parametrize("n_fog", [N_FOG, N], ids=["fogs", "identity"])
