@@ -92,34 +92,54 @@ def _wire_k_frac(d: int, cfg: comp.CompressorConfig):
 def _chunked_compress_and_accumulate(
     deltas, err, fog_id, weights, n_fog: int, cfg, chunk: int
 ):
+    """:func:`client_chunk_scan` over deltas already trained: each chunk
+    is a slice of ``deltas``."""
+    fog_sum, fog_weight, new_err, _ = client_chunk_scan(
+        lambda start: (jax.lax.dynamic_slice_in_dim(deltas, start, chunk), {}),
+        err, fog_id, weights, None, n_fog, cfg, chunk,
+    )
+    return fog_sum, fog_weight, new_err
+
+
+def client_chunk_scan(
+    chunk_deltas, err, fog_id, weights, keep, n_fog: int, cfg, chunk: int
+):
     """``lax.scan`` over client chunks carrying the (n_fog, d) buffers.
 
-    Each scan step compresses and accumulates one chunk of clients, so the
-    transient footprint (blocked tiles, masks, wire slots) is O(chunk * d)
-    instead of O(N * d) — the peak high-water mark scales with the chunk
-    knob, not the fleet.  EF state is still (N, d) round state: it is
-    emitted chunk-at-a-time as stacked scan outputs.
+    ``chunk_deltas(start)`` gives the flat deltas (chunk, d) of clients
+    ``start .. start + chunk`` and a pytree of per-client outputs (leaves
+    (chunk, ...)); the round loop trains the chunk there, so that only
+    the error-feedback state is ever (N, d).  Each scan step then
+    compresses and accumulates that chunk, so the transient footprint
+    (deltas, blocked tiles, masks, wire slots, training activations) is
+    O(chunk) instead of O(N) — the peak high-water mark scales with the
+    chunk knob, not the fleet.
 
     Inside each chunk, a concrete-``rho_s`` fused blockwise config takes
     the sparse wire (emit + scatter-accumulate, no dense per-chunk
     reconstruction); anything else falls back to the dense per-chunk path
     (still chunk-bounded).  Chunks are addressed with clamped
-    ``dynamic_slice`` starts (:func:`_chunk_starts`) and the EF output is
-    written in place into a carried (N, d) buffer, so neither padded input
-    copies nor a stacked scan-output staging buffer ever materialise.
+    ``dynamic_slice`` starts (:func:`_chunk_starts`), and the EF state is
+    the scan's carry, updated in place: a client's row is rewritten only
+    by its own (nominal) chunk and only where ``keep`` (None: every
+    client) holds, so rows of non-participants keep their buffer and the
+    rows the clamped last chunk re-reads keep what their chunk wrote.
     Float summation order differs from the unchunked pass, which is why
     the equivalence pins are bitwise only at ``chunk >= N`` (where this
     function is never entered).
+
+    Returns (fog_sum, fog_weight, new_err, outputs) with the per-client
+    outputs back in client order (leaves (N, ...)).
     """
-    n, d = deltas.shape
+    n, d = err.shape
     starts, nominal = _chunk_starts(n, chunk)
     k_frac = _wire_k_frac(d, cfg)
 
     def body(carry, x):
         fog_sum, fog_weight, err_out = carry
         start, nom = x
-        dc = jax.lax.dynamic_slice_in_dim(deltas, start, chunk)
-        ec = jax.lax.dynamic_slice_in_dim(err, start, chunk)
+        dc, outputs = chunk_deltas(start)
+        ec = jax.lax.dynamic_slice_in_dim(err_out, start, chunk)
         fc = jax.lax.dynamic_slice_in_dim(fog_id, start, chunk)
         wc = jax.lax.dynamic_slice_in_dim(weights, start, chunk)
         # Rows the clamped last chunk re-reads were already accumulated;
@@ -132,11 +152,11 @@ def _chunked_compress_and_accumulate(
                 jnp.isfinite(ec), axis=-1
             )
             dc = jnp.where(finite[:, None], dc, 0.0)
-            ec = jnp.where(finite[:, None], ec, 0.0)
             wc = wc * finite.astype(wc.dtype)
             part_w = jax.ops.segment_sum(wc, fc, num_segments=n_fog)
+            # ``ec`` itself stays: rows not rewritten below keep it as is.
             part, new_err_c = kops.compress_aggregate_wire(
-                dc, ec, fc, wc, n_fog, k_frac,
+                dc, jnp.where(finite[:, None], ec, 0.0), fc, wc, n_fog, k_frac,
                 quantize=cfg.quant_bits < 32,
                 use_pallas=cfg.use_pallas,
                 interpret=cfg.interpret,
@@ -145,21 +165,27 @@ def _chunked_compress_and_accumulate(
             part, part_w, new_err_c = compress_and_accumulate(
                 dc, ec, fc, wc, n_fog, cfg
             )
-        # Overlap rows rewrite bit-identical values (per-row determinism).
+        write = fresh
+        if keep is not None:
+            write = write & jax.lax.dynamic_slice_in_dim(keep, start, chunk)
         err_out = jax.lax.dynamic_update_slice_in_dim(
-            err_out, new_err_c, start, 0
+            err_out, jnp.where(write[:, None], new_err_c, ec), start, 0
         )
-        return (fog_sum + part, fog_weight + part_w, err_out), None
+        return (fog_sum + part, fog_weight + part_w, err_out), outputs
 
     carry0 = (
         jnp.zeros((n_fog, d), jnp.float32),
         jnp.zeros((n_fog,), jnp.float32),
-        jnp.zeros((n, d), deltas.dtype),
+        err,
     )
-    (fog_sum, fog_weight, new_err), _ = jax.lax.scan(
+    (fog_sum, fog_weight, new_err), outputs = jax.lax.scan(
         body, carry0, (starts, nominal)
     )
-    return fog_sum, fog_weight, new_err
+    # Client i's outputs come from its nominal chunk.
+    c = jnp.minimum(jnp.arange(n) // chunk, starts.shape[0] - 1)
+    at = jnp.arange(n) - starts[c]
+    outputs = jax.tree_util.tree_map(lambda o: o[c, at], outputs)
+    return fog_sum, fog_weight, new_err, outputs
 
 
 def compress_and_accumulate(

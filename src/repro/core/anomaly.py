@@ -88,10 +88,33 @@ def evaluate_detector(
     percentile: float = 99.0,
     point_adjusted: bool = False,
 ) -> F1Result:
-    """Full paper protocol: calibrate on normal-only val, score test, F1."""
-    val_err = reconstruction_errors(apply_fn, params, x_val_normal)
+    """Full paper protocol: calibrate on normal-only val, score test, F1.
+
+    ``apply_fn(params, x)`` reconstructs rows; the score of a row is its
+    squared-L2 reconstruction error."""
+    return evaluate_scores(
+        lambda p, x: reconstruction_errors(apply_fn, p, x), params,
+        x_val_normal, x_test, y_test,
+        percentile=percentile, point_adjusted=point_adjusted,
+    )
+
+
+def evaluate_scores(
+    score_fn: Callable[[Any, jax.Array], jax.Array],
+    params: Any,
+    x_val_normal: jax.Array,
+    x_test: jax.Array,
+    y_test: jax.Array,
+    percentile: float = 99.0,
+    point_adjusted: bool = False,
+) -> F1Result:
+    """The protocol for any per-point score (higher is more anomalous):
+    threshold at the ``percentile`` of the normal validation scores
+    (Eq. 32), flag test points above it, and score them against the labels
+    (point-wise, or point-adjusted)."""
+    val_err = score_fn(params, x_val_normal)
     tau = calibrate_threshold(val_err, percentile)
-    test_err = reconstruction_errors(apply_fn, params, x_test)
+    test_err = score_fn(params, x_test)
     pred = flag_anomalies(test_err, tau)
     if point_adjusted:
         return point_adjusted_f1(pred, y_test)

@@ -65,6 +65,7 @@ from repro.core import hfl
 from repro.core import topology as topo
 from repro.data.synthetic import SensorDataset
 from repro.kernels import ops as kops
+from repro.models.detector import as_detector
 from repro.optim import server as srv
 
 Params = Any
@@ -347,7 +348,8 @@ def make_event_fn(
         train = ds.train
         if drift_on:
             train = train * (1.0 + dr.covariate_shift * t_f)
-        deltas, losses = clients_fn(state.params, train, keys)
+        deltas, stats = clients_fn(state.params, train, keys)
+        losses = stats["loss"]
         if fault_on:
             # Byzantine corruption hits the raw delta before compression —
             # the attacker controls what leaves the sensor.
@@ -371,9 +373,8 @@ def make_event_fn(
         # Transmission: the update lands after compute + uplink latency.
         l_u = comp.payload_bits(d, cfg.compressor)
         l_full = 32.0 * d
-        flops = en.autoencoder_flops(
-            ds.train.shape[-1], (16, 8, 16), ds.train.shape[1],
-            cfg.local_epochs,
+        flops = as_detector(loss_fn).train_flops(
+            state.params, ds.train.shape[1], cfg.batch_size, cfg.local_epochs
         )
         lat_comp = jnp.float32(flops) / cfg.compute_rate_flops
         up_lat = en.link_latency_s(l_u, fa.dist_m, cfg.channel)

@@ -32,6 +32,7 @@ from repro.kernels import ops as kops
 from repro.data.pipeline import multi_epoch_batches
 from repro.data.synthetic import SensorDataset
 from repro.launch.mesh import shard_map_compat
+from repro.models.detector import as_detector
 from repro.optim import scaffold as scf
 from repro.optim import server as srv
 from repro.optim.sgd import local_sgd
@@ -139,7 +140,8 @@ def make_flat_round_fn(
         gateway_id = jnp.zeros((ds.train.shape[0],), jnp.int32)
 
         if client_mesh is None:
-            deltas, losses = clients_fn(state.params, train, keys)
+            deltas, stats = clients_fn(state.params, train, keys)
+            losses = stats["loss"]
             if fault_on:
                 deltas = flt.corrupt_deltas(
                     k_byz, deltas, fl, prev_delta=state.prev_delta
@@ -170,9 +172,10 @@ def make_flat_round_fn(
                           P("data"), P("data")),
                 out_specs=(P(), P(), P("data"), P("data")),
             )
-            fog_delta, _, new_err, losses = sharded(
+            fog_delta, _, new_err, stats = sharded(
                 state.params, train, keys, state.err, weights, gateway_id
             )
+            losses = stats["loss"]
             n_nonfinite = jnp.int32(0)
         new_err = jnp.where(active[:, None], new_err, state.err)
         mean_delta = fog_delta[0]
@@ -193,8 +196,8 @@ def make_flat_round_fn(
         lat_up = jnp.max(
             jnp.where(active, en.link_latency_s(l_u, fa.dist_m, cfg.channel), 0.0)
         )
-        flops = en.autoencoder_flops(
-            ds.train.shape[-1], (16, 8, 16), ds.train.shape[1], cfg.local_epochs
+        flops = as_detector(loss_fn).train_flops(
+            state.params, ds.train.shape[1], cfg.batch_size, cfg.local_epochs
         )
         e_comp = en.compute_energy_j(jnp.float32(flops), cfg.energy)
         spent = e_up + jnp.where(active, e_comp, 0.0)

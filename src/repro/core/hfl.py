@@ -19,6 +19,16 @@ run as the second fused operator —
 :func:`repro.core.aggregation.compress_and_aggregate` — so the dense
 per-client reconstructions never materialise either; set
 ``CompressorConfig.fused=False`` for the legacy two-pass pipeline.
+``loss_fn`` is a plain loss or a :class:`repro.models.detector.Detector`
+(the Anomaly Transformer trains on stride-1 windows); the compute term of
+the energy and latency model counts that detector's own operations.
+
+With ``HFLConfig.client_chunk`` below N (and no fault layer, mean fog
+reduce, one device) the client side is ONE phase per chunk inside a scan:
+train the chunk, emit its wire, accumulate into the fog buffers, update
+its rows of the error-feedback state in place
+(:func:`repro.core.aggregation.client_chunk_scan`), so only that state is
+(N, d).
 
 Pass ``client_mesh`` (a 1-D ``("data",)`` mesh, see
 ``launch/sharding.client_mesh``) to :func:`train` / :func:`make_round_fn`
@@ -48,6 +58,7 @@ from repro.core import faults as flt
 from repro.core import topology as topo
 from repro.data.synthetic import SensorDataset
 from repro.launch.mesh import shard_map_compat
+from repro.models.detector import Detector, as_detector
 from repro.optim import server as srv
 from repro.optim.sgd import LocalTrainConfig, make_client_solver
 
@@ -182,6 +193,14 @@ class RoundMetrics(NamedTuple):
     global_finite: jax.Array  # bool — global params finite after the round
 
 
+# A detector with per-round stats beyond the loss (the Anomaly Transformer's
+# ``assdis``) gets them averaged over active clients in ``detector_stats``.
+DetectorRoundMetrics = NamedTuple(
+    "DetectorRoundMetrics",
+    [*RoundMetrics.__annotations__.items(), ("detector_stats", dict)],
+)
+
+
 class HFLState(NamedTuple):
     params: Params            # global model theta^t
     err: jax.Array            # (N, d) error-feedback buffers
@@ -218,12 +237,13 @@ def init_state(
     )
 
 
-def _client_train_fn(loss_fn: LossFn, cfg: HFLConfig):
+def _client_train_fn(loss_fn: LossFn | Detector, cfg: HFLConfig):
     """Batched client phase: E-epoch local SGD from the broadcast params
-    for EVERY client at once, returning flat deltas (fused kernel path by
+    for every client it is given, returning flat deltas and the
+    detector's per-client stats (fused kernel path for the paper AE by
     default; see :func:`repro.optim.sgd.make_client_solver`)."""
     return make_client_solver(
-        loss_fn,
+        as_detector(loss_fn),
         batch_size=cfg.batch_size,
         epochs=cfg.local_epochs,
         lr=cfg.lr,
@@ -246,13 +266,13 @@ def _clients_round(
     slice of the client axis and contributes partial fog sums; the psum
     pair is the sensor->fog hop (cf. aggregation.hierarchical_mean).
     Returns (fog_delta (n_fog, d) — Eq. 13 cluster means — fog_weight,
-    new_err (N_local, d), losses (N_local,)).
+    new_err (N_local, d), per-client stats {"loss": (N_local,), ...}).
     """
-    deltas, losses = clients_fn(params, data, keys)
+    deltas, stats = clients_fn(params, data, keys)
     fog_delta, fog_weight, new_err = agg.compress_and_aggregate(
         deltas, err, fog_id, weights, n_fog, cc, axis=axis, chunk=chunk
     )
-    return fog_delta, fog_weight, new_err, losses
+    return fog_delta, fog_weight, new_err, stats
 
 
 def comm_latency_s(
@@ -297,7 +317,7 @@ def comm_latency_s(
 
 
 def make_round_fn(
-    loss_fn: LossFn,
+    loss_fn: LossFn | Detector,
     ds: SensorDataset,
     cfg: HFLConfig,
     *,
@@ -312,7 +332,8 @@ def make_round_fn(
     """
 
     n_fog = cfg.deployment.n_fog
-    clients_fn = _client_train_fn(loss_fn, cfg)
+    detector = as_detector(loss_fn)
+    clients_fn = _client_train_fn(detector, cfg)
     if cfg.robust not in ("mean", "trimmed", "median"):
         raise ValueError(
             f"robust must be 'mean', 'trimmed' or 'median', got "
@@ -417,9 +438,29 @@ def make_round_fn(
         delivered = active & ~erased
         weights = ds.n_samples * delivered.astype(jnp.float32)
 
-        if client_mesh is None:
+        chunk = cfg.client_chunk
+        if (client_mesh is None and not fault_on and cfg.robust == "mean"
+                and chunk is not None and 0 < chunk < n):
+            # One client phase per chunk: train, emit the wire, accumulate.
+            # Only the EF state is (N, d); it is updated in place.
+            def chunk_deltas(start):
+                sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
+                with jax.named_scope("round.local_train"):
+                    deltas_c, stats_c = clients_fn(state.params, sl(train), sl(keys))
+                return deltas_c, (stats_c, flt.nonfinite_rows(deltas_c))
+
+            with jax.named_scope("round.aggregate"):
+                fog_sum, fog_weight, new_err, (stats, nonfinite) = (
+                    agg.client_chunk_scan(
+                        chunk_deltas, state.err, fa.fog_id, weights, active,
+                        n_fog, cfg.compressor, chunk,
+                    )
+                )
+            fog_delta = fog_sum / jnp.maximum(fog_weight, 1e-12)[:, None]
+            n_nonfinite = jnp.sum((delivered & nonfinite).astype(jnp.int32))
+        elif client_mesh is None:
             with jax.named_scope("round.local_train"):
-                deltas, losses = clients_fn(state.params, train, keys)
+                deltas, stats = clients_fn(state.params, train, keys)
             if fault_on:
                 deltas = flt.corrupt_deltas(
                     k_byz, deltas, fl, prev_delta=state.prev_delta
@@ -431,7 +472,7 @@ def make_round_fn(
                 if cfg.robust == "mean":
                     fog_sum, fog_weight, new_err = agg.compress_and_accumulate(
                         deltas, state.err, fa.fog_id, weights, n_fog,
-                        cfg.compressor, chunk=cfg.client_chunk,
+                        cfg.compressor, chunk=chunk,
                     )
                     fog_delta = fog_sum / jnp.maximum(fog_weight, 1e-12)[:, None]
                 else:
@@ -439,14 +480,16 @@ def make_round_fn(
                         agg.robust_compress_and_aggregate(
                             deltas, state.err, fa.fog_id, weights, n_fog,
                             cfg.compressor, cfg.trim_frac, cfg.robust,
-                            chunk=cfg.client_chunk,
+                            chunk=chunk,
                         )
                     )
+            # Non-participants keep their error buffer and contribute nothing.
+            new_err = jnp.where(active[:, None], new_err, state.err)
         else:
             sharded = shard_map_compat(
                 lambda p, dat, kk, e, w, fid: _clients_round(
                     clients_fn, p, dat, kk, e, w, fid, n_fog,
-                    cfg.compressor, axis="data", chunk=cfg.client_chunk,
+                    cfg.compressor, axis="data", chunk=chunk,
                 ),
                 mesh=client_mesh,
                 in_specs=(P(), P("data"), P("data"), P("data"),
@@ -454,15 +497,15 @@ def make_round_fn(
                 out_specs=(P(), P(), P("data"), P("data")),
             )
             with jax.named_scope("round.local_train_aggregate"):
-                fog_delta, fog_weight, new_err, losses = sharded(
+                fog_delta, fog_weight, new_err, stats = sharded(
                     state.params, train, keys, state.err, weights, fa.fog_id
                 )
             # Sharded deltas never leave their shard: the isfinite guard
             # inside compress_and_accumulate still protects, only the
             # counter is unavailable there.
             n_nonfinite = jnp.int32(0)
-        # Non-participants keep their error buffer and contribute nothing.
-        new_err = jnp.where(active[:, None], new_err, state.err)
+            new_err = jnp.where(active[:, None], new_err, state.err)
+        losses = stats["loss"]
 
         with jax.named_scope("round.global"):
             fog_model = fog_delta + flat0[None, :]          # theta_m^{t+1/2}
@@ -506,8 +549,8 @@ def make_round_fn(
                 l_u, l_full, active, fa.dist_m, decision, fog_active,
                 fa.fog_gateway_dist_m, cfg.channel,
             )
-            flops = en.autoencoder_flops(
-                ds.train.shape[-1], (16, 8, 16), ds.train.shape[1], cfg.local_epochs
+            flops = detector.train_flops(
+                state.params, ds.train.shape[1], cfg.batch_size, cfg.local_epochs
             )
             lat_comp = flops / cfg.compute_rate_flops
             latency = lat_comm + lat_comp
@@ -530,6 +573,12 @@ def make_round_fn(
             n_erased=jnp.sum(erased.astype(jnp.int32)),
             global_finite=jnp.all(jnp.isfinite(new_flat)),
         )
+        extra = {
+            k: jnp.sum(v * active_f) / jnp.maximum(jnp.sum(active_f), 1.0)
+            for k, v in stats.items() if k != "loss"
+        }
+        if extra:
+            metrics = DetectorRoundMetrics(*metrics, detector_stats=extra)
         # Adaptive colluders observe the realised global movement; other
         # modes leave the carried delta untouched (identical graph).
         prev_delta = new_flat - flat0 if adaptive else state.prev_delta
@@ -547,7 +596,7 @@ def make_round_fn(
 def train(
     key: jax.Array,
     init_params: Params,
-    loss_fn: LossFn,
+    loss_fn: LossFn | Detector,
     ds: SensorDataset,
     cfg: HFLConfig,
     *,

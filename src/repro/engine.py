@@ -43,6 +43,15 @@ segment-sum.  Opt out per config with
 ``CompressorConfig(fused=False)`` — the legacy two-pass pipeline, kept as
 the equivalence baseline.
 
+Detectors
+---------
+``Engine(detector=...)`` picks the model every cell trains and evaluates
+(``models/detector``); the default is the paper autoencoder at
+``hidden`` widths (one argument or the other, not both).
+``models/anomaly_transformer.detector`` trains the Anomaly Transformer on
+stride-1 windows inside the hierarchical round's client-chunk scan and
+scores non-overlapping windows.
+
 Sharding
 --------
 With more than one device, input leaves are placed with the
@@ -89,6 +98,8 @@ from repro.data.synthetic import SensorDataset
 from repro.kernels import ops as kops
 from repro.launch import experiment as exp
 from repro.launch import sharding as shard_rules
+from repro.models import autoencoder as ae
+from repro.models.detector import Detector, choose
 from repro.optim.sgd import LocalTrainConfig
 
 
@@ -234,7 +245,8 @@ class Engine:
         shard_trials: bool = True,
         shard_clients: bool = False,
         client_chunk: int | None = None,
-        hidden: tuple[int, ...] = (16, 8, 16),
+        hidden: tuple[int, ...] | None = None,
+        detector: Detector | None = None,
         percentile: float = 99.0,
         point_adjusted: bool = False,
     ) -> None:
@@ -251,7 +263,10 @@ class Engine:
         self.shard_trials = shard_trials
         self.shard_clients = shard_clients
         self.client_chunk = client_chunk
-        self.hidden = hidden
+        # The model every cell trains: the paper AE at ``hidden`` widths
+        # unless a detector is given (``models/detector``), never both.
+        self.detector = choose(detector, hidden)
+        self.hidden = (16, 8, 16) if hidden is None else hidden
         self.percentile = percentile
         self.point_adjusted = point_adjusted
         self._programs: dict[Any, Callable] = {}
@@ -472,12 +487,20 @@ class Engine:
             seeds = tuple(int(s) for s in seeds)
             stacked = self._as_stacked(ds, seeds)
             s_n, p_n = len(seeds), n_deployments
-            solver = _base_cfg(cfg).local_solver
-            if (solver.fused and solver.use_pallas
-                    and method not in _UNFUSED_CLIENT_PHASE):
-                dim = stacked.train.shape[-1]
-                telemetry.observe("engine.local_train_pack",
-                                  kops.local_train_pack((dim, *self.hidden, dim)))
+            base = _base_cfg(cfg)
+            dim = stacked.train.shape[-1]
+            det = self.detector
+            if telemetry.recording():
+                shapes = jax.eval_shape(lambda k: det.init(k, dim), jax.random.key(0))
+                if (det.fusable and base.local_solver.fused
+                        and base.local_solver.use_pallas
+                        and method not in _UNFUSED_CLIENT_PHASE):
+                    telemetry.observe("engine.local_train_pack",
+                                      kops.local_train_pack(ae.widths(shapes)))
+                telemetry.observe("engine.detector_params", sum(
+                    x.size for x in jax.tree_util.tree_leaves(shapes)))
+                telemetry.observe("engine.local_windows", det.trained_per_round(
+                    stacked.train.shape[-2], base.batch_size, base.local_epochs))
             keys = self._trial_keys(seeds, p_n)           # (S, P)
             client_mesh = self._client_mesh(method, stacked)
             return_params = store is not None
@@ -485,7 +508,7 @@ class Engine:
                 (x.shape, str(x.dtype)) for x in jax.tree_util.tree_leaves(stacked)
             )
             cache_key = ("run", method, _cfg_key(cfg), s_n, p_n, shapes,
-                         self.hidden, self.percentile, self.point_adjusted,
+                         self.detector, self.percentile, self.point_adjusted,
                          client_mesh.size if client_mesh is not None else 0,
                          return_params)
 
@@ -495,7 +518,7 @@ class Engine:
                         method, key, one_ds, cfg,
                         percentile=self.percentile,
                         point_adjusted=self.point_adjusted,
-                        hidden=self.hidden,
+                        detector=self.detector,
                         client_mesh=client_mesh,
                         return_params=return_params,
                     )
@@ -745,7 +768,7 @@ class Engine:
             rep = rcfgs[idxs[0]]
             knobs = dict(self._kernel_static_knobs(rep))
             cache_key = ("sweep", family, uniq, sig, len(idxs), s_n, p_n,
-                         d, self.hidden, self.percentile, self.point_adjusted)
+                         d, self.detector, self.percentile, self.point_adjusted)
 
             if family == "run":
                 shared_cell_ds = all(
@@ -789,7 +812,7 @@ class Engine:
                             uniq[0], key, one_ds, cfg_,
                             percentile=self.percentile,
                             point_adjusted=self.point_adjusted,
-                            hidden=self.hidden,
+                            detector=self.detector,
                         )
 
                     dep_v = jax.vmap(trial, in_axes=(None, 0, None))

@@ -448,3 +448,96 @@ def sliding_window_decode_attention_ref(
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("hgs,shd->hgd", p, v_cache.astype(jnp.float32))
     return out.reshape(hq, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Anomaly Transformer (models/anomaly_transformer): plain float32 reference.
+# ---------------------------------------------------------------------------
+
+def _at_layer_norm_ref(x, g, b):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean((x - mu) ** 2, axis=-1, keepdims=True)
+    return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
+
+
+def anomaly_transformer_window_ref(params, x, n_heads: int):
+    """One window x (L, D) through the Anomaly Transformer, written out per
+    head in float32: returns (x_hat (L, D), [S (H, L, L)], [P (H, L, L)],
+    AssDis (L,)), where AssDis is the mean over layers and heads of
+    KL(P || S) + KL(S || P) with 1e-4 in the logs."""
+    import math
+
+    length, _ = x.shape
+    w = params["embed"]
+    dm = w.shape[-1]
+    h = jnp.zeros((length, dm))
+    for t in range(length):
+        for i in range(3):
+            h = h.at[t].add(x[(t + i - 1) % length] @ w[i])
+    pos = jnp.arange(length, dtype=jnp.float32)
+    for c in range(dm):
+        freq = math.exp(-(c - c % 2) * math.log(10000.0) / dm)
+        h = h.at[:, c].add(jnp.sin(pos * freq) if c % 2 == 0 else jnp.cos(pos * freq))
+    e = dm // n_heads
+    dist = jnp.abs(pos[:, None] - pos[None, :])
+    series_all, prior_all = [], []
+    n_layers = params["layers"]["wq"].shape[0]
+    for i in range(n_layers):
+        lp = {name: leaf[i] for name, leaf in params["layers"].items()}
+        outs, series, priors = [], [], []
+        sig_all = h @ lp["ws"] + lp["bs"]
+        for hd in range(n_heads):
+            cols = slice(hd * e, (hd + 1) * e)
+            q = h @ lp["wq"][:, cols] + lp["bq"][cols]
+            k = h @ lp["wk"][:, cols] + lp["bk"][cols]
+            v = h @ lp["wv"][:, cols] + lp["bv"][cols]
+            s = jax.nn.softmax(q @ k.T / math.sqrt(e), axis=-1)
+            sig = 3.0 ** (jax.nn.sigmoid(5.0 * sig_all[:, hd]) + 1e-5) - 1.0
+            p = (1.0 / (math.sqrt(2.0 * math.pi) * sig[:, None])
+                 * jnp.exp(-dist ** 2 / 2.0 / sig[:, None] ** 2))
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
+            outs.append(s @ v)
+            series.append(s)
+            priors.append(p)
+        a = jnp.concatenate(outs, axis=-1) @ lp["wo"] + lp["bo"]
+        h = _at_layer_norm_ref(h + a, lp["ln1_g"], lp["ln1_b"])
+        y = jax.nn.gelu(h @ lp["w1"] + lp["b1"], approximate=False) @ lp["w2"] + lp["b2"]
+        h = _at_layer_norm_ref(h + y, lp["ln2_g"], lp["ln2_b"])
+        series_all.append(jnp.stack(series))
+        prior_all.append(jnp.stack(priors))
+    h = _at_layer_norm_ref(h, params["norm_g"], params["norm_b"])
+    x_hat = h @ params["proj_w"] + params["proj_b"]
+    assdis = jnp.mean(jnp.stack([
+        _at_kl_ref(p, s) + _at_kl_ref(s, p) for s, p in zip(series_all, prior_all)
+    ]), axis=0)
+    return x_hat, series_all, prior_all, assdis
+
+
+def _at_kl_ref(p, q):
+    """KL over keys with 1e-4 in the logs, mean over heads: (H, L, L) -> (L,)."""
+    return jnp.mean(jnp.sum(p * (jnp.log(p + 1e-4) - jnp.log(q + 1e-4)), axis=-1), axis=0)
+
+
+def anomaly_transformer_grads_ref(params, batch, n_heads: int, k: float):
+    """The released code's minimax step on a batch (B, L, D): the gradient
+    of rec - k AssDis(sg P, S) plus, separately taken, the gradient of
+    rec + k AssDis(P, sg S).  Returns (gradient sum, rec - k AssDis)."""
+    sg = jax.lax.stop_gradient
+
+    def phase(p, sign):
+        rec, dis = 0.0, 0.0
+        for x in batch:
+            x_hat, series, priors, _ = anomaly_transformer_window_ref(p, x, n_heads)
+            rec = rec + jnp.mean((x_hat - x) ** 2)
+            if sign < 0:
+                pairs = [(s, sg(pr)) for s, pr in zip(series, priors)]
+            else:
+                pairs = [(sg(s), pr) for s, pr in zip(series, priors)]
+            dis = dis + jnp.mean(jnp.stack([
+                jnp.mean(_at_kl_ref(s, pr) + _at_kl_ref(pr, s)) for s, pr in pairs]))
+        n = len(batch)
+        return (rec + sign * k * dis) / n, (rec - k * dis) / n
+
+    g1, loss1 = jax.grad(lambda p: phase(p, -1.0), has_aux=True)(params)
+    g2 = jax.grad(lambda p: phase(p, 1.0)[0])(params)
+    return jax.tree_util.tree_map(lambda a, b: a + b, g1, g2), loss1

@@ -18,6 +18,7 @@ from repro.core import cooperation as coop
 from repro.core import topology as topo
 from repro.data.synthetic import SensorDataset
 from repro.models import autoencoder as ae
+from repro.models.detector import Detector, choose
 
 METHODS = (
     "centralised",
@@ -41,6 +42,8 @@ _RULES = {
 
 # FedProx proximal coefficient (paper uses mu ~ 0.01 scale defaults).
 PROX_MU = 0.01
+# Windows a window detector scores at once in evaluation.
+EVAL_WINDOWS = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,21 +62,38 @@ class ExperimentResult:
 
 
 def _detector_eval(
-    params: Any, ds: SensorDataset, percentile: float, point_adjusted: bool
+    det: Detector, params: Any, ds: SensorDataset, percentile: float,
+    point_adjusted: bool,
 ) -> anomaly.F1Result:
-    """Paper protocol with the GLOBAL threshold variant (Sec. V-D)."""
-    d = ds.val.shape[-1]
-    val = ds.val.reshape(-1, d)
-    test = ds.test.reshape(-1, d)
-    label = ds.test_label.reshape(-1)
-    return anomaly.evaluate_detector(
-        lambda p, x: ae.apply(p, x),
-        params,
-        val,
-        test,
-        label,
-        percentile=percentile,
-        point_adjusted=point_adjusted,
+    """Paper protocol with the GLOBAL threshold variant (Sec. V-D): tau is
+    the ``percentile`` of the validation scores (Eq. 32).  A row detector
+    scores every row; a window detector scores each sensor's validation
+    and test series in non-overlapping windows of its length (a remainder
+    shorter than a window is left out), one window batch at a time."""
+    if det.window is None:
+        d = ds.val.shape[-1]
+        val = ds.val.reshape(-1, d)
+        test = ds.test.reshape(-1, d)
+        label = ds.test_label.reshape(-1)
+        return anomaly.evaluate_scores(
+            det.score, params, val, test, label,
+            percentile=percentile, point_adjusted=point_adjusted,
+        )
+    length = det.window
+
+    def windows(x):
+        n_win = x.shape[1] // length
+        return x[:, :n_win * length].reshape(-1, length, *x.shape[2:])
+
+    def scores(p, w):
+        return jax.lax.map(
+            lambda one: det.score(p, one[None])[0], w, batch_size=EVAL_WINDOWS
+        ).reshape(-1)
+
+    return anomaly.evaluate_scores(
+        scores, params, windows(ds.val), windows(ds.test),
+        windows(ds.test_label).reshape(-1),
+        percentile=percentile, point_adjusted=point_adjusted,
     )
 
 
@@ -85,7 +105,8 @@ def trial_metrics(
     *,
     percentile: float = 99.0,
     point_adjusted: bool = False,
-    hidden: tuple[int, ...] = (16, 8, 16),
+    hidden: tuple[int, ...] | None = None,
+    detector: Detector | None = None,
     client_mesh=None,
     return_params: bool = False,
 ) -> dict[str, jax.Array]:
@@ -103,6 +124,12 @@ def trial_metrics(
     ``return_params``: include the trained model under ``"params"`` (used
     by ``Engine.run(store=...)`` to publish rounds for the serving path).
 
+    ``detector``: the model (``models/detector``); None is the paper
+    autoencoder with ``hidden`` widths (default (16, 8, 16)); not both.
+    A window detector (the Anomaly Transformer) trains only in the
+    hierarchical families, and reports its extra per-round stats
+    (``assdis``) beside the losses.
+
     ``method="hfl-async"`` runs the event-driven staleness-aware family
     (``core/async_fl``); ``cfg`` may then be an
     :class:`repro.core.async_fl.AsyncFLConfig` (a plain ``HFLConfig`` is
@@ -113,9 +140,15 @@ def trial_metrics(
     """
     if method not in METHODS:
         raise ValueError(f"unknown method {method!r}; one of {METHODS}")
+    det = choose(detector, hidden)
+    if det.window is not None and method not in _RULES:
+        raise ValueError(
+            f"window detector {det.name!r} trains only in the hierarchical "
+            f"families {tuple(_RULES)}, not {method!r}"
+        )
     k_init, k_train = jax.random.split(key)
     dim = ds.train.shape[-1]
-    params0 = ae.init(k_init, dim, hidden)
+    params0 = det.init(k_init, dim)
 
     zero = jnp.zeros(())
     if method == "centralised":
@@ -175,7 +208,7 @@ def trial_metrics(
                 server_opt="adam" if method == "hfl-adam" else cfg.server_opt,
             )
             params, m = hfl.train(
-                k_train, params0, ae.loss, ds, run_cfg,
+                k_train, params0, det, ds, run_cfg,
                 client_mesh=client_mesh,
             )
         out = {
@@ -192,10 +225,11 @@ def trial_metrics(
             "nonfinite_rounds": jnp.sum(
                 1.0 - m.global_finite.astype(jnp.float32)
             ),
+            **getattr(m, "detector_stats", {}),
         }
 
     with jax.named_scope("eval.detector"):
-        f1 = _detector_eval(params, ds, percentile, point_adjusted)
+        f1 = _detector_eval(det, params, ds, percentile, point_adjusted)
     out.update(f1=f1.f1, precision=f1.precision, recall=f1.recall)
     if return_params:
         out["params"] = params

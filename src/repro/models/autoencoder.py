@@ -47,3 +47,37 @@ def loss(params: Params, batch: jax.Array) -> jax.Array:
 def param_count(feature_dim: int = 32, hidden: tuple[int, ...] = (16, 8, 16)) -> int:
     dims = (feature_dim, *hidden, feature_dim)
     return sum(a * b + b for a, b in zip(dims[:-1], dims[1:]))
+
+
+def loss_and_stats(params: Params, batch: jax.Array):
+    value = loss(params, batch)
+    return value, {"loss": value}
+
+
+def errors(params: Params, x: jax.Array) -> jax.Array:
+    """Squared-L2 reconstruction error per row (paper Sec. V-D)."""
+    return jnp.sum(jnp.square(x - apply(params, x)), axis=-1)
+
+
+def forward_flops(params: Params) -> int:
+    """Multiply-adds of one row's forward pass, two operations each."""
+    return sum(2 * layer["w"].shape[0] * layer["w"].shape[1] for layer in params)
+
+
+def widths(params: Params) -> tuple[int, ...]:
+    """(D, *hidden, D) of MLP parameters (or of their shapes)."""
+    return (params[0]["w"].shape[0], *(layer["w"].shape[1] for layer in params))
+
+
+def detector(hidden: tuple[int, ...] = (16, 8, 16)):
+    """The paper autoencoder as a row detector (``models/detector``)."""
+    from repro.models.detector import Detector
+
+    return Detector(
+        name="autoencoder",
+        init=lambda key, dim: init(key, dim, hidden),
+        loss=loss_and_stats,
+        score=errors,
+        forward_flops=forward_flops,
+        fusable=True,
+    )

@@ -10,11 +10,11 @@ autoencoder it dispatches to the fused local-train operator
 (``kernels/ops.local_train``: the whole E-epoch SGD phase in one
 VMEM-resident kernel launch, Pallas on TPU / the ``kernels/ref`` oracle
 elsewhere) — the dense per-client ``(E * nb, bs, D)`` batch stream of the
-legacy path never materialises.  Non-AE models (anything that is not the
-``models/autoencoder`` MLP trained with its MSE loss) automatically fall
+legacy path never materialises.  Other row models automatically fall
 back to the legacy per-client ``local_sgd`` scan, which
 ``LocalTrainConfig(fused=False)`` also forces — kept as the equivalence
-baseline.
+baseline — and window detectors (``models/detector``, e.g. the Anomaly
+Transformer) train on minibatches of their stride-1 windows.
 """
 from __future__ import annotations
 
@@ -119,30 +119,38 @@ def fusable_params(params: Any) -> bool:
 
 
 def make_client_solver(
-    loss_fn: LossFn,
+    loss_fn: LossFn | Any,
     *,
     batch_size: int,
     epochs: int,
     lr: float,
     prox_mu: float = 0.0,
     solver: LocalTrainConfig = LocalTrainConfig(),
-) -> Callable[[Params, jax.Array, jax.Array], tuple[jax.Array, jax.Array]]:
+) -> Callable[[Params, jax.Array, jax.Array], tuple[jax.Array, Any]]:
     """Build the batched client phase used by the round loops.
 
-    Returns ``clients_fn(params, data (N, window, D), keys (N,)) ->
-    (flat_deltas (N, d), mean_losses (N,))`` where the deltas are
-    ``ravel_pytree(theta_i^E - theta^t)`` — ready to chain into the fused
-    compress-and-aggregate operator.
+    ``loss_fn`` is a plain loss function or a
+    :class:`repro.models.detector.Detector`.  Returns ``clients_fn(params,
+    data (N, T, D), keys (N,)) -> (flat_deltas (N, d), stats)`` where the
+    deltas are ``ravel_pytree(theta_i^E - theta^t)`` — ready to chain into
+    the fused compress-and-aggregate operator — and ``stats`` is the mean
+    minibatch loss (N,) for a plain loss function, or the detector's
+    per-client mean stats ``{"loss": (N,), ...}`` for a detector.
 
     Dispatch happens per call: when ``solver.fused`` and the params are
-    the paper autoencoder trained with its own loss
-    (``models/autoencoder.loss``), the whole phase runs as ONE fused
-    operator over all clients; otherwise it falls back to the legacy
-    vmapped ``local_sgd`` / ``proximal_local_sgd`` scan.
+    the paper autoencoder trained with its own loss, the whole phase runs
+    as ONE fused operator over all clients; a row detector otherwise takes
+    the legacy vmapped ``local_sgd`` / ``proximal_local_sgd`` scan over
+    gathered minibatches; a window detector (``Detector.window`` = L)
+    trains each client on minibatches of its stride-1 windows, ``epochs``
+    shuffles of the T - L + 1 window starts with the remainder dropped.
     """
     from repro.data.pipeline import multi_epoch_batches, multi_epoch_indices
     from repro.kernels import ops as kops
-    from repro.models import autoencoder as ae
+    from repro.models.detector import Detector, as_detector
+
+    det = as_detector(loss_fn)
+    objective = det.loss
 
     # STATIC proximal switch: ``prox_mu`` may be a tracer inside a
     # config-axis sweep, where the proximal term always runs (a runtime mu
@@ -151,30 +159,63 @@ def make_client_solver(
     use_prox = not (isinstance(prox_mu, (int, float)) and prox_mu == 0.0)
 
     def scan_path(params, data, keys):
+        plain = lambda p, b: objective(p, b)[0]  # noqa: E731
+
         def one(dd, kk):
             batches = multi_epoch_batches(kk, dd, batch_size, epochs)
             if use_prox:
                 p1, loss = proximal_local_sgd(
-                    loss_fn, params, batches, lr, prox_mu
+                    plain, params, batches, lr, prox_mu
                 )
             else:
-                p1, loss = local_sgd(loss_fn, params, batches, lr)
+                p1, loss = local_sgd(plain, params, batches, lr)
             delta = jax.tree_util.tree_map(lambda a, b: a - b, p1, params)
-            return ravel_pytree(delta)[0], loss
+            return ravel_pytree(delta)[0], {"loss": loss}
 
         return jax.vmap(one)(data, keys)
 
-    def clients_fn(params, data, keys):
-        if solver.fused and loss_fn is ae.loss and fusable_params(params):
+    def window_path(params, data, keys):
+        grad_fn = jax.value_and_grad(objective, has_aux=True)
+        offsets = jnp.arange(det.window)
+
+        def one(dd, kk):
+            idx = multi_epoch_indices(
+                kk, det.samples(dd.shape[0]), batch_size, epochs
+            )
+
+            def step(p, starts):
+                (_, stats), g = grad_fn(p, dd[starts[:, None] + offsets])
+                if use_prox:
+                    g = proximal_grad(p, params, g, prox_mu)
+                return sgd(p, g, lr), stats
+
+            p1, stats = jax.lax.scan(step, params, idx)
+            delta = jax.tree_util.tree_map(lambda a, b: a - b, p1, params)
+            return ravel_pytree(delta)[0], jax.tree_util.tree_map(jnp.mean, stats)
+
+        return jax.vmap(one)(data, keys)
+
+    def detector_fn(params, data, keys):
+        if det.window is not None:
+            return window_path(params, data, keys)
+        if solver.fused and det.fusable and fusable_params(params):
             window = data.shape[1]
             idx = jax.vmap(
                 lambda k: multi_epoch_indices(k, window, batch_size, epochs)
             )(keys)
-            return kops.local_train(
+            deltas, losses = kops.local_train(
                 params, data, idx, lr, prox_mu,
                 use_pallas=solver.use_pallas, interpret=solver.interpret,
             )
+            return deltas, {"loss": losses}
         return scan_path(params, data, keys)
+
+    if isinstance(loss_fn, Detector):
+        return detector_fn
+
+    def clients_fn(params, data, keys):
+        deltas, stats = detector_fn(params, data, keys)
+        return deltas, stats["loss"]
 
     return clients_fn
 

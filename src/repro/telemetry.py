@@ -28,6 +28,8 @@ not locked: record from one thread.
 | ``engine.execute`` | span, ns | ``Engine._timed_call``: launch and wait |
 | ``engine.publish`` | span, ns | ``Engine.run``: hand-off of trial (0, 0) to a store |
 | ``engine.local_train_pack`` | counter | ``Engine.run``, on the Pallas local-train path: clients per kernel tile |
+| ``engine.detector_params`` | counter | ``Engine.run``, once a job: the detector's d |
+| ``engine.local_windows`` | counter | ``Engine.run``, once a job: samples a sensor trains a round |
 """
 from __future__ import annotations
 
