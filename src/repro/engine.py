@@ -270,6 +270,9 @@ class Engine:
         self.percentile = percentile
         self.point_adjusted = point_adjusted
         self._programs: dict[Any, Callable] = {}
+        # Tiles per grid step of each program's compress kernels, as its
+        # tracing call chose them (``engine.compress_tiles_per_step``).
+        self._compress_tiles: dict[Any, list[int]] = {}
         self._last_calls: list[tuple[Callable, tuple]] = []
         self.compile_count = 0
         self.call_log: list[dict] = []
@@ -538,7 +541,13 @@ class Engine:
                     client_mesh, jax.sharding.PartitionSpec(None, "data")
                 )
                 stacked = jax.device_put(stacked, on_mesh)
-        out, wall = self._timed_call(fn, keys, stacked)
+        with kops.compress_tiles_traced() as tiles:
+            out, wall = self._timed_call(fn, keys, stacked)
+        if tiles:
+            self._compress_tiles[cache_key] = list(dict.fromkeys(tiles))
+        if telemetry.recording() and cache_key in self._compress_tiles:
+            telemetry.observe("engine.compress_tiles_per_step",
+                              self._compress_tiles[cache_key])
         if store is not None:
             with telemetry.span("engine.publish"):
                 params0 = jax.tree_util.tree_map(lambda a: a[0, 0], out.pop("params"))

@@ -11,15 +11,19 @@ accumulator — the dense (N, d) reconstruction never exists in HBM, only the
 (n_fog, d) weighted sums and the (N, d) error buffer (which is round state
 and has to be written regardless).
 
-Grid layout: ``(nb, N)`` with the client axis INNERMOST, so the fog
-accumulator block for column ``j`` stays resident in VMEM across all N
+Grid layout: ``(nb, N / T)`` with the client axis INNERMOST, so the fog
+accumulator block for column ``j`` stays resident in VMEM across all
 sequential client steps (zeroed at ``i == 0``, flushed when ``j``
-advances).  ``fog_id`` / ``weights`` ride in as scalar-prefetch operands
-(SMEM), which is what lets the kernel scatter into a dynamic fog row with
-``pl.dslice`` — no sorting of clients by cluster required.  The per-fog
-block is (n_fog, BLOCK_ROWS, BLOCK_LANES) f32: at the paper's M = N/10
-(n_fog <= 20) that is ~640 KiB, comfortably inside VMEM next to the three
-32 KiB client tiles.
+advances).  Each step takes T clients and bisects their Top-K thresholds
+together (:func:`_select_and_quantize`): the bisection is a serial chain
+of reduce-to-scalar steps, so one tile a step leaves the chip waiting on
+that chain, while T tiles share it.  ``fog_id`` / ``weights`` ride in as
+scalar-prefetch operands (SMEM), which is what lets the kernel scatter
+into a dynamic fog row — no sorting of clients by cluster required.  The
+per-fog block is (n_fog, BLOCK_ROWS, BLOCK_LANES) f32: at the paper's M =
+N/10 (n_fog <= 20) that is ~640 KiB, comfortably inside VMEM next to the
+client tiles.  The wire emitter steps over its (client, block) tiles T at
+a time the same way.
 """
 from __future__ import annotations
 
@@ -39,6 +43,14 @@ from repro.kernels.topk_ef import BLOCK_LANES, BLOCK_ROWS
 DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 _VMEM_HEADROOM = 4 * 1024 * 1024
 TILE_BYTES = BLOCK_ROWS * BLOCK_LANES * 4
+# Tiles per grid step of the compress kernels: each step bisects up to
+# MAX_TILES_PER_STEP tiles together, within a VMEM budget of half a v5e's
+# 128 MiB.  A tile in a step costs its double-buffered input and output
+# tiles and the body's slab temporaries (about 11 tiles' worth by the
+# compiler's scoped allocation on a v5e, rounded up to 14).
+MAX_TILES_PER_STEP = 16
+VMEM_BUDGET = 64 * 1024 * 1024
+STEP_TILE_BYTES = 14 * TILE_BYTES
 _EXACT = jax.lax.Precision.HIGHEST   # one-hot dots must move f32 values exactly
 
 
@@ -50,26 +62,43 @@ def vmem_params(need_bytes: int) -> pltpu.CompilerParams | None:
     return pltpu.CompilerParams(vmem_limit_bytes=need_bytes + _VMEM_HEADROOM)
 
 
-def _select_and_quantize(v, k: int, quantize: bool):
-    """EF Top-K selection + int8 round trip of one (R, L) tile, exactly the
-    :func:`repro.kernels.ref.compress_aggregate_ref` rules.
+def _per_tile(reduce, x):
+    """(T, R, L) -> (T, 1, 1): rows first (mostly vector adds), then the
+    lanes of one row per tile.  Mosaic lowers one axis at a time."""
+    return reduce(reduce(x, axis=1, keepdims=True), axis=2, keepdims=True)
 
-    Returns (survive mask, recon tile, scale) where ``scale`` is the block
-    max / 127 (1.0 without quantisation): whenever anything survives, the
-    block max survives too, so it equals max|sparse|.
+
+def _select_and_quantize(v, k: int, quantize: bool):
+    """EF Top-K selection + int8 round trip of a (T, R, L) stack of tiles,
+    each tile exactly by the :func:`repro.kernels.ref.compress_aggregate_ref`
+    rules.
+
+    The T bisections run together: the carries are (T, 1, 1), and each of
+    the ``BISECT_ITERS`` steps compares the whole slab once and ends in T
+    per-tile counts, so the tiles share one serial chain of reductions
+    instead of T.  Every tile keeps its own max, midpoints and ``count >
+    k`` rule, so it gets the threshold it would get alone.
+
+    Returns (recon tiles, scale (T, 1, 1), threshold (T, 1, 1)): a
+    coordinate survives where its magnitude exceeds the threshold, and
+    ``scale`` is the block max / 127 (1.0 without quantisation): whenever
+    anything survives, the block max survives too, so it equals
+    max|sparse|.
     """
     absv = jnp.abs(v)
-    amax = jnp.max(absv)
+    amax = _per_tile(jnp.max, absv)
 
     # Threshold bisection, identical to ref.bisect_threshold: invariant
     # count(> hi) <= k <= count(> lo).
     def body(_, lohi):
         lo, hi = lohi
         mid = 0.5 * (lo + hi)
-        take = jnp.sum((absv > mid).astype(jnp.int32)) > k
+        take = _per_tile(jnp.sum, (absv > mid).astype(jnp.int32)) > k
         return jnp.where(take, mid, lo), jnp.where(take, hi, mid)
 
-    _, hi = jax.lax.fori_loop(0, BISECT_ITERS, body, (jnp.float32(-1.0), amax))
+    _, hi = jax.lax.fori_loop(
+        0, BISECT_ITERS, body, (jnp.full_like(amax, -1.0), amax)
+    )
     survive = absv > hi
     if quantize:
         scale = amax / 127.0
@@ -77,69 +106,52 @@ def _select_and_quantize(v, k: int, quantize: bool):
         q = jnp.clip(jnp.round(v / safe), -127.0, 127.0)
         recon = jnp.where(survive & (scale > 0), q * scale, 0.0)
     else:
-        scale = jnp.float32(1.0)
+        scale = jnp.ones_like(amax)
         recon = jnp.where(survive, v, 0.0)
-    return survive, recon, scale
+    return recon, scale, hi
 
 
 def _fused_agg_kernel(
     fog_id_ref,   # (N,) int32  scalar prefetch
     w_ref,        # (N,) f32    scalar prefetch
-    delta_ref,    # (1, 1, R, L)
-    err_ref,      # (1, 1, R, L)
+    delta_ref,    # (T, 1, R, L)
+    err_ref,      # (T, 1, R, L)
     fog_ref,      # (n_fog, 1, R, L) accumulator, resident across clients
-    new_err_ref,  # (1, 1, R, L)
+    new_err_ref,  # (T, 1, R, L)
     *,
     k: int,
     quantize: bool,
 ):
-    i = pl.program_id(1)  # client index (innermost grid axis)
+    i = pl.program_id(1)  # client step (innermost grid axis)
+    tiles = delta_ref.shape[0]
 
     @pl.when(i == 0)
     def _():
         fog_ref[...] = jnp.zeros_like(fog_ref)
 
-    v = delta_ref[0, 0] + err_ref[0, 0]
-    _, recon, _ = _select_and_quantize(v, k, quantize)
-    new_err_ref[0, 0] = v - recon
-    # Scatter-accumulate into this client's fog row (data-dependent index
-    # from the prefetched cluster assignment).
-    f = fog_id_ref[i]
-    fog_ref[f, 0] = fog_ref[f, 0] + w_ref[i] * recon
+    v = delta_ref[:, 0] + err_ref[:, 0]                  # (T, R, L)
+    recon, _, _ = _select_and_quantize(v, k, quantize)
+    new_err_ref[:, 0] = v - recon
+    # Scatter-accumulate each client into its fog row (data-dependent
+    # index from the prefetched cluster assignment), in client order.
+    for t in range(tiles):
+        c = i * tiles + t
+        f = fog_id_ref[c]
+        fog_ref[f, 0] = fog_ref[f, 0] + w_ref[c] * recon[t]
 
 
-def _wire_emit_kernel(
-    delta_ref,    # (1, 1, R, L)
-    err_ref,      # (1, 1, R, L)
-    idx_ref,      # (1, 1, 1, KP) int32 slots
-    q_ref,        # (1, 1, 1, KP) f32 codes (int8-valued when quantizing)
-    scale_ref,    # (1, 1, 1, L) f32, the block scale broadcast along lanes
-    new_err_ref,  # (1, 1, R, L)
-    *,
-    k: int,
-    quantize: bool,
-):
-    """Emit the sparse wire for one (client, block) tile.
-
-    Identical selection to :func:`_fused_agg_kernel`, but the survivors are
-    packed into slots in ascending coordinate order (slots past the
-    survivor count carry index 0 and code 0) instead of a dense masked
-    tile — the rho_s-sized object the acoustic link carries.
+def _compact(survive, v, scale, kp: int, quantize: bool):
+    """Pack one tile's survivors into ``kp`` slots in ascending coordinate
+    order (slots past the survivor count carry index 0 and code 0).
 
     The packing is a stream compaction built from masks and one-hot
     matmuls, all in the lane-major ``(., KP)`` slot layout so nothing is
     transposed: a triangular matmul gives each survivor its rank, each
     slot finds the tile row holding its rank, a one-hot matmul gathers
     that row into the slot's column, and a lane match inside the row picks
-    the coordinate.  Codes are f32 holding exact int8 values.
+    the coordinate.  Returns (idx (1, KP) int32, codes (1, KP) f32).
     """
-    rows, lanes = delta_ref.shape[2], delta_ref.shape[3]
-    kp = idx_ref.shape[3]
-    v = delta_ref[0, 0] + err_ref[0, 0]                  # (R, L)
-    survive, recon, scale = _select_and_quantize(v, k, quantize)
-    new_err_ref[0, 0] = v - recon
-    scale_ref[0, 0] = jnp.full((1, lanes), scale, jnp.float32)
-
+    rows, lanes = v.shape
     sf = survive.astype(jnp.float32)
     # rank_in_row[r, l] = survivors left of l in row r (0/1 sums: exact).
     upper = (
@@ -180,11 +192,49 @@ def _wire_emit_kernel(
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (lanes, kp), 0)
     slot_lane = jnp.sum(jnp.where(hit, lane_iota, 0), axis=0, keepdims=True)
     vals = jnp.sum(jnp.where(hit, row_val, 0.0), axis=0, keepdims=True)
-    idx_ref[0, 0] = jnp.where(valid, slot_row * lanes + slot_lane, 0)
+    idx = jnp.where(valid, slot_row * lanes + slot_lane, 0)
     if quantize:
         safe = jnp.where(scale > 0, scale, 1.0)
         vals = jnp.clip(jnp.round(vals / safe), -127.0, 127.0)
-    q_ref[0, 0] = vals
+    return idx, vals
+
+
+def _wire_emit_kernel(
+    delta_ref,    # (T, R, L)
+    err_ref,      # (T, R, L)
+    idx_ref,      # (T, 1, KP) int32 slots
+    q_ref,        # (T, 1, KP) f32 codes (int8-valued when quantizing)
+    scale_ref,    # (T, 1, L) f32, the block scale broadcast along lanes
+    new_err_ref,  # (T, R, L)
+    thr_ref,      # (T, 1, L) f32 scratch: each tile's threshold
+    *,
+    k: int,
+    quantize: bool,
+):
+    """Emit the sparse wire for T (client, block) tiles.
+
+    Identical selection to :func:`_fused_agg_kernel`, but the survivors are
+    packed into slots (:func:`_compact`) instead of a dense masked tile —
+    the rho_s-sized object the acoustic link carries.  The compaction runs
+    one tile at a time in a loop, which keeps its (L, KP) operands in VMEM
+    once rather than T times.  Codes are f32 holding exact int8 values.
+    """
+    tiles, lanes = delta_ref.shape[0], delta_ref.shape[2]
+    kp = idx_ref.shape[2]
+    v = delta_ref[...] + err_ref[...]                    # (T, R, L)
+    recon, scale, hi = _select_and_quantize(v, k, quantize)
+    new_err_ref[...] = v - recon
+    scale_ref[...] = jnp.broadcast_to(scale, (tiles, 1, lanes))
+    thr_ref[...] = jnp.broadcast_to(hi, (tiles, 1, lanes))
+
+    def compact(t, carry):
+        vt = delta_ref[t] + err_ref[t]
+        survive = jnp.abs(vt) > thr_ref[t]
+        scale_t = jnp.max(scale_ref[t], axis=1, keepdims=True)
+        idx_ref[t], q_ref[t] = _compact(survive, vt, scale_t, kp, quantize)
+        return carry
+
+    jax.lax.fori_loop(0, tiles, compact, 0)
 
 
 def _wire_agg_kernel(
@@ -238,8 +288,46 @@ def slot_pad(k: int) -> int:
     return -(-k // BLOCK_LANES) * BLOCK_LANES
 
 
+def tiles_per_step(n_tiles: int, resident_bytes: int,
+                   tile_bytes: int = STEP_TILE_BYTES) -> int:
+    """Tiles each grid step of a compress kernel bisects together.
+
+    At most :data:`MAX_TILES_PER_STEP`, and no more than fit the VMEM
+    budget beside ``resident_bytes`` at ``tile_bytes`` a tile.  Among the
+    counts from that cap down to half of it, the largest that divides
+    ``n_tiles`` is taken, so that no step is ragged; where none divides,
+    the cap is taken and the last step is ragged.
+    """
+    fit = (VMEM_BUDGET - resident_bytes) // tile_bytes
+    cap = max(1, min(MAX_TILES_PER_STEP, fit, n_tiles))
+    for t in range(cap, cap // 2, -1):
+        if n_tiles % t == 0:
+            return t
+    return cap
+
+
+def _wire_vmem(kp: int) -> tuple[int, int]:
+    """(resident, per-tile) VMEM bytes of the wire emitter at ``kp``
+    slots: the compaction's (L, KP) f32 operands, held once, and per tile
+    its slab plus double-buffered int32 and f32 slot rows."""
+    return 4 * BLOCK_LANES * kp * 4, STEP_TILE_BYTES + 4 * kp * 4
+
+
+def wire_tiles_per_step(n: int, nb: int, kp: int) -> int:
+    """Tiles per grid step of :func:`compress_wire_blocks` on ``n``
+    clients of ``nb`` blocks at ``kp`` slots a block."""
+    return tiles_per_step(n * nb, *_wire_vmem(kp))
+
+
+def dense_tiles_per_step(n: int, n_fog: int) -> int:
+    """Clients per grid step of :func:`compress_aggregate_blocks`: the
+    resident fog accumulator is double-buffered."""
+    return tiles_per_step(n, 2 * n_fog * TILE_BYTES)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("k_per_block", "quantize", "interpret")
+    jax.jit,
+    static_argnames=("k_per_block", "quantize", "interpret", "tiles"),
 )
 def compress_wire_blocks(
     delta: jax.Array,     # (N, nb, BLOCK_ROWS, BLOCK_LANES) f32
@@ -247,6 +335,7 @@ def compress_wire_blocks(
     k_per_block: int,
     quantize: bool = True,
     interpret: bool = True,
+    tiles: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Emit the sparse wire for every (client, block) tile.
 
@@ -254,28 +343,48 @@ def compress_wire_blocks(
     codes, scale (N, nb, 1, L) f32 lane-broadcast, new_err like
     ``delta``), with ``KP = slot_pad(k)``: every per-tile output block
     spans its array's last two dims, the layout Mosaic accepts.
+
+    The N x nb tiles are independent, so the grid steps over them in
+    their row-major order, ``tiles`` at a time (default
+    :func:`wire_tiles_per_step`); a ragged last step reads pad tiles
+    whose outputs are dropped.  The slot rows stay per tile, so the wire
+    is the same at any ``tiles``.
     """
     n, nb = delta.shape[:2]
     assert delta.shape == (n, nb, BLOCK_ROWS, BLOCK_LANES), delta.shape
     k = min(int(k_per_block), BLOCK_ROWS * BLOCK_LANES)
     kp = slot_pad(k)
-    tile = pl.BlockSpec((1, 1, BLOCK_ROWS, BLOCK_LANES),
-                        lambda i, j: (i, j, 0, 0))
-    slot = pl.BlockSpec((1, 1, 1, kp), lambda i, j: (i, j, 0, 0))
-    sc = pl.BlockSpec((1, 1, 1, BLOCK_LANES), lambda i, j: (i, j, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_wire_emit_kernel, k=k, quantize=quantize),
-        grid=(n, nb),
+    t = tiles or wire_tiles_per_step(n, nb, kp)
+    resident, per_tile = _wire_vmem(kp)
+    flat = (n * nb, BLOCK_ROWS, BLOCK_LANES)
+    tile = pl.BlockSpec((t, BLOCK_ROWS, BLOCK_LANES), lambda s: (s, 0, 0))
+    slot = pl.BlockSpec((t, 1, kp), lambda s: (s, 0, 0))
+    sc = pl.BlockSpec((t, 1, BLOCK_LANES), lambda s: (s, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0,
+        grid=(pl.cdiv(n * nb, t),),
         in_specs=[tile, tile],
         out_specs=[slot, slot, sc, tile],
+        scratch_shapes=[pltpu.VMEM((t, 1, BLOCK_LANES), jnp.float32)],
+    )
+    # The barrier keeps the caller's (N, d) -> (N, nb, R, L) relayout a
+    # copy: merged with the flattening below, XLA emits one reshape
+    # instead, which a v5e runs several times slower.
+    delta, err = jax.lax.optimization_barrier((delta, err))
+    idx, q, scale, new_err = pl.pallas_call(
+        functools.partial(_wire_emit_kernel, k=k, quantize=quantize),
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n, nb, 1, kp), jnp.int32),
-            jax.ShapeDtypeStruct((n, nb, 1, kp), jnp.float32),
-            jax.ShapeDtypeStruct((n, nb, 1, BLOCK_LANES), jnp.float32),
-            jax.ShapeDtypeStruct(delta.shape, delta.dtype),
+            jax.ShapeDtypeStruct((n * nb, 1, kp), jnp.int32),
+            jax.ShapeDtypeStruct((n * nb, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((n * nb, 1, BLOCK_LANES), jnp.float32),
+            jax.ShapeDtypeStruct(flat, delta.dtype),
         ],
+        compiler_params=vmem_params(resident + t * per_tile),
         interpret=interpret,
-    )(delta, err)
+    )(delta.reshape(flat), err.reshape(flat))
+    return (idx.reshape(n, nb, 1, kp), q.reshape(n, nb, 1, kp),
+            scale.reshape(n, nb, 1, BLOCK_LANES), new_err.reshape(delta.shape))
 
 
 @functools.partial(jax.jit, static_argnames=("n_fog", "interpret"))
@@ -315,7 +424,8 @@ def wire_aggregate_blocks(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n_fog", "k_per_block", "quantize", "interpret")
+    jax.jit,
+    static_argnames=("n_fog", "k_per_block", "quantize", "interpret", "tiles"),
 )
 def compress_aggregate_blocks(
     delta: jax.Array,     # (N, nb, BLOCK_ROWS, BLOCK_LANES) f32
@@ -326,23 +436,32 @@ def compress_aggregate_blocks(
     k_per_block: int,
     quantize: bool = True,
     interpret: bool = True,
+    tiles: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Run the fused kernel over blocked input.
 
     Returns (fog_sum (n_fog, nb, R, L) f32 — unnormalised weighted sums —
-    and new_err, same shape/dtype as ``delta``).  The fog accumulator is
-    resident and double-buffered, so the robust path's identity segments
-    (``n_fog`` = clients per call) raise the scoped-VMEM limit.
+    and new_err, same shape/dtype as ``delta``).  Each grid step takes
+    ``tiles`` clients of one block column (default
+    :func:`dense_tiles_per_step`); a ragged last step's pad clients add
+    nothing.  The fog accumulator is resident and double-buffered, so the
+    robust path's identity segments (``n_fog`` = clients per call) raise
+    the scoped-VMEM limit.
     """
     n, nb = delta.shape[:2]
     assert delta.shape == (n, nb, BLOCK_ROWS, BLOCK_LANES), delta.shape
-    tile = pl.BlockSpec((1, 1, BLOCK_ROWS, BLOCK_LANES),
+    t = tiles or dense_tiles_per_step(n, n_fog)
+    # A ragged last step's pad clients get weight 0.  Whatever a pad tile
+    # reads, its reconstruction is finite (NaN and inf never survive the
+    # selection, codes are clipped), so each adds an exact zero.
+    pad = -n % t
+    tile = pl.BlockSpec((t, 1, BLOCK_ROWS, BLOCK_LANES),
                         lambda j, i, *_: (i, j, 0, 0))
     fog_spec = pl.BlockSpec((n_fog, 1, BLOCK_ROWS, BLOCK_LANES),
                             lambda j, i, *_: (0, j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nb, n),
+        grid=(nb, pl.cdiv(n, t)),
         in_specs=[tile, tile],
         out_specs=[fog_spec, tile],
     )
@@ -354,6 +473,9 @@ def compress_aggregate_blocks(
                                  jnp.float32),
             jax.ShapeDtypeStruct(delta.shape, delta.dtype),
         ],
-        compiler_params=vmem_params((2 * n_fog + 6) * TILE_BYTES),
+        compiler_params=vmem_params(
+            2 * n_fog * TILE_BYTES + t * STEP_TILE_BYTES
+        ),
         interpret=interpret,
-    )(fog_id.astype(jnp.int32), weights.astype(jnp.float32), delta, err)
+    )(jnp.pad(fog_id.astype(jnp.int32), (0, pad)),
+      jnp.pad(weights.astype(jnp.float32), (0, pad)), delta, err)

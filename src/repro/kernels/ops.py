@@ -17,8 +17,9 @@ driver keeps kernel-bound knobs static per shape-class on TPU.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Any
+from typing import Any, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,31 @@ def _pad_blocks_batch(x: jax.Array) -> tuple[jax.Array, int]:
     nb = max(1, -(-d // BLOCK_ELEMS))
     padded = jnp.zeros((n_rows, nb * BLOCK_ELEMS), x.dtype).at[:, :d].set(x)
     return padded.reshape(n_rows, nb, _tk.BLOCK_ROWS, _tk.BLOCK_LANES), d
+
+
+# The tiles per grid step of each compress kernel a program calls, noted
+# while :func:`compress_tiles_traced` collects.
+_traced_tiles: list[int] | None = None
+
+
+@contextlib.contextmanager
+def compress_tiles_traced() -> Iterator[list[int]]:
+    """Collect the tiles per grid step of every compress kernel that a
+    program tracing inside the block calls, in call order.  A program
+    traces once: a later call of it notes nothing, so the caller keeps
+    what the tracing call collected."""
+    global _traced_tiles
+    outer, _traced_tiles = _traced_tiles, []
+    try:
+        yield _traced_tiles
+    finally:
+        _traced_tiles = outer
+
+
+def _note_tiles(tiles: int) -> int:
+    if _traced_tiles is not None:
+        _traced_tiles.append(tiles)
+    return tiles
 
 
 def _unpad(x: jax.Array, n: int) -> jax.Array:
@@ -191,16 +217,17 @@ def compress(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n_fog", "k", "quantize", "interpret")
+    jax.jit, static_argnames=("n_fog", "k", "quantize", "interpret", "tiles")
 )
 def _compress_aggregate_pallas(
     deltas, err, fog_id, weights, n_fog: int, k: int, quantize: bool,
-    interpret: bool,
+    interpret: bool, tiles: int,
 ):
     blocks, d = _pad_blocks_batch(deltas)
     err_blocks, _ = _pad_blocks_batch(err)
     fog_blocks, new_err = _fa.compress_aggregate_blocks(
-        blocks, err_blocks, fog_id, weights, n_fog, k, quantize, interpret
+        blocks, err_blocks, fog_id, weights, n_fog, k, quantize, interpret,
+        tiles,
     )
     fog_sum = fog_blocks.reshape(n_fog, -1)[:, :d]
     return fog_sum, new_err.reshape(deltas.shape[0], -1)[:, :d]
@@ -251,8 +278,10 @@ def compress_aggregate(
     """
     if use_pallas:
         k = max(1, int(round(_static_scalar(k_frac, "k_frac") * BLOCK_ELEMS)))
+        tiles = _note_tiles(_fa.dense_tiles_per_step(deltas.shape[0], n_fog))
         return _compress_aggregate_pallas(
-            deltas, err, fog_id, weights, n_fog, k, quantize, interpret
+            deltas, err, fog_id, weights, n_fog, k, quantize, interpret,
+            tiles=tiles,
         )
     return _compress_aggregate_ref(
         deltas, err, fog_id, weights, _block_k(k_frac), n_fog, quantize
@@ -270,13 +299,15 @@ def wire_k(k_frac) -> int:
     return min(k, BLOCK_ELEMS)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "quantize", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("k", "quantize", "interpret", "tiles")
+)
 def _compress_wire_pallas(deltas, err, k: int, quantize: bool,
-                          interpret: bool):
+                          interpret: bool, tiles: int):
     blocks, d = _pad_blocks_batch(deltas)
     err_blocks, _ = _pad_blocks_batch(err)
     idx, q, scale, new_err = _fa.compress_wire_blocks(
-        blocks, err_blocks, k, quantize, interpret
+        blocks, err_blocks, k, quantize, interpret, tiles
     )
     return (idx[:, :, 0, :k], q[:, :, 0, :k], scale[:, :, 0, 0],
             new_err.reshape(deltas.shape[0], -1)[:, :d])
@@ -316,7 +347,11 @@ def compress_wire(
     """
     k = wire_k(k_frac)
     if use_pallas:
-        return _compress_wire_pallas(deltas, err, k, quantize, interpret)
+        n, d = deltas.shape
+        nb = max(1, -(-d // BLOCK_ELEMS))
+        tiles = _note_tiles(_fa.wire_tiles_per_step(n, nb, _fa.slot_pad(k)))
+        return _compress_wire_pallas(deltas, err, k, quantize, interpret,
+                                     tiles)
     return _compress_wire_ref(deltas, err, k, quantize)
 
 

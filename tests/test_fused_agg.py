@@ -20,7 +20,7 @@ import pytest
 
 from repro.core import aggregation as agg
 from repro.core import compression as comp
-from repro.kernels import ops
+from repro.kernels import fused_agg, ops
 
 N_FOG = 4
 
@@ -187,6 +187,49 @@ def test_wire_pallas_interpret_matches_ref(d, k_frac, quantize):
         *pal[:3], fog_id, weights, N_FOG, d, use_pallas=True, interpret=True
     )
     np.testing.assert_array_equal(np.asarray(fog_p), np.asarray(fog_r))
+
+
+@pytest.mark.parametrize(
+    "n,d,n_fog,tiles,quantize",
+    [
+        (13, 3 * 8192 + 17, N_FOG, 8, True),      # ragged last step, both kernels
+        (13, 3 * 8192 + 17, N_FOG, None, True),   # the tiles chosen from shapes
+        (10, 1352, 10, None, True),               # robust path: identity segments
+        (10, 1352, 10, 4, True),                  # identity, ragged
+        (7, 9000, N_FOG, 3, False),               # sparsify-only, ragged
+    ],
+    ids=["ragged", "chosen", "identity", "identity-ragged", "topk-only"],
+)
+def test_tiles_per_step_are_bit_identical_to_one_tile_a_step(
+    n, d, n_fog, tiles, quantize
+):
+    """Bisecting T tiles together in one grid step gives every tile the
+    threshold, codes, EF row and fog contribution it gets alone, and the
+    fog sums keep their client order.  Client 1 is all-zero."""
+    deltas, err, fog_id, weights = _inputs(n, d, seed=n + d)
+    if n_fog == n:
+        fog_id = jnp.arange(n, dtype=jnp.int32)
+    deltas, err = deltas.at[1].set(0.0), err.at[1].set(0.0)
+    blocks, _ = ops._pad_blocks_batch(deltas)
+    err_blocks, _ = ops._pad_blocks_batch(err)
+    k = ops.wire_k(0.05)
+    if tiles is None:
+        assert fused_agg.dense_tiles_per_step(n, n_fog) > 1
+        assert fused_agg.wire_tiles_per_step(
+            n, blocks.shape[1], fused_agg.slot_pad(k)) > 1
+
+    def dense(t):
+        return fused_agg.compress_aggregate_blocks(
+            blocks, err_blocks, fog_id, weights, n_fog, k, quantize,
+            tiles=t)
+
+    def wire(t):
+        return fused_agg.compress_wire_blocks(
+            blocks, err_blocks, k, quantize, tiles=t)
+
+    for kernel in (dense, wire):
+        for got, want in zip(kernel(tiles), kernel(1)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_round_loop_fused_matches_unfused():

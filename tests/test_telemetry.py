@@ -13,7 +13,7 @@ from repro import engine as eng_mod
 from repro import telemetry
 from repro.checkpoint import CheckpointStore
 from repro.data.synthetic import SyntheticConfig, generate, normalize
-from repro.kernels import ops
+from repro.kernels import fused_agg, ops
 from repro.launch import experiment as exp
 from repro.loadgen import VirtualClock
 from repro.models import autoencoder as ae
@@ -161,6 +161,7 @@ def test_round_phases_are_named_scopes_of_the_compiled_program(engine_run):
 def test_the_oracle_path_records_no_local_train_pack(engine_run):
     _, s = engine_run
     assert "engine.local_train_pack" not in s
+    assert "engine.compress_tiles_per_step" not in s
 
 
 def test_the_pallas_path_records_the_local_train_pack_once_per_job(tmp_path, monkeypatch):
@@ -178,3 +179,32 @@ def test_the_pallas_path_records_the_local_train_pack_once_per_job(tmp_path, mon
     pack = ops.local_train_pack((dim, *eng.hidden, dim))
     assert pack == 128 // max(dim, *eng.hidden) > 1
     np.testing.assert_array_equal(telemetry.records("engine.local_train_pack"), [pack, pack])
+
+
+def test_the_pallas_path_records_the_compress_tiles_once_per_job(tmp_path, monkeypatch):
+    """The counter holds the tiles per grid step that the job's compress
+    kernel was handed, on the call that traced the program and on a later
+    call that reuses it."""
+    data = normalize(generate(jax.random.key(0), SyntheticConfig(
+        n_sensors=12, train_len=48, val_len=24, test_len=48)))
+    cfg = exp.make_config(n_sensors=12, n_fog=3, rounds=1, local_epochs=1)
+    cfg = cfg.replace(compressor=cfg.compressor.replace(mode="blockwise"))
+    # The Pallas compress path, its kernel body interpreted on this host.
+    monkeypatch.setattr(eng_mod.Engine, "resolve_compressor",
+                        lambda self, cc: cc.replace(use_pallas=True, interpret=True))
+    handed = []
+    kernel = ops._compress_aggregate_pallas
+
+    def spy(*args, tiles, **kw):
+        handed.append(tiles)
+        return kernel(*args, tiles=tiles, **kw)
+
+    monkeypatch.setattr(ops, "_compress_aggregate_pallas", spy)
+    eng = eng_mod.Engine()
+    with jax.profiler.trace(str(tmp_path)):
+        for seeds in ((1,), (2,)):
+            eng.run("hfl-selective", cfg, seeds, data)
+    tiles = fused_agg.dense_tiles_per_step(12, 3)
+    assert tiles > 1 and set(handed) == {tiles}
+    np.testing.assert_array_equal(
+        telemetry.records("engine.compress_tiles_per_step"), [tiles, tiles])

@@ -23,7 +23,7 @@ import pytest
 
 from repro.core import aggregation as agg
 from repro.core import compression as comp
-from repro.kernels import ops
+from repro.kernels import fused_agg, ops
 
 N, D_MODEL, N_FOG, DIM = 200, 1352, 20, 32
 DIMS = (DIM, 16, 8, 16, DIM)
@@ -130,6 +130,38 @@ def test_wire_emit_compiles(one_chip):
         _spec(one_chip, (N, D_MODEL)), _spec(one_chip, (N, D_MODEL)),
     )
     assert n_kernels >= 1
+
+
+@pytest.mark.parametrize(
+    "n,d",
+    [(512, D_MODEL), (8, 4_825_150)],
+    ids=["fleet-chunk", "at-chunk"],
+)
+def test_wire_emit_compiles_at_the_cells_chunks(one_chip, n, d):
+    """The fleet cell's 512-client chunk of one block and the Anomaly
+    Transformer cell's 8-client chunk of 590 blocks compile with more than
+    one tile bisected in each grid step."""
+    k_frac = comp.blockwise_k_frac(d, RHO_S)
+    with ops.compress_tiles_traced() as tiles:
+        names = _kernel_names(
+            lambda dl, e: ops.compress_wire(dl, e, k_frac, **PALLAS),
+            _spec(one_chip, (n, d)), _spec(one_chip, (n, d)),
+        )
+    assert names == ["compress_wire_blocks"]
+    assert len(tiles) == 1 and tiles[0] > 1, tiles
+
+
+def test_the_cells_compress_shapes_bisect_many_tiles_a_step():
+    """train-paper-n200's dense call (200 clients, 20 fogs), its robust
+    identity segments, and the wire calls of train-fleet-n50k (512 x 1
+    block) and train-at-smd-n200 (8 x 590 blocks) never take one tile a
+    step."""
+    kp_ae = fused_agg.slot_pad(ops.wire_k(K_FRAC))
+    kp_at = fused_agg.slot_pad(ops.wire_k(comp.blockwise_k_frac(4_825_150, RHO_S)))
+    assert fused_agg.dense_tiles_per_step(N, N_FOG) > 1
+    assert fused_agg.dense_tiles_per_step(N, N) > 1
+    assert fused_agg.wire_tiles_per_step(512, 1, kp_ae) > 1
+    assert fused_agg.wire_tiles_per_step(8, 590, kp_at) > 1
 
 
 def test_wire_aggregate_compiles(one_chip):
