@@ -7,8 +7,9 @@ baseline (``experiments/bench/kernel_micro.json``) and exits non-zero when
 any jnp-ref row regressed by more than THRESHOLD (default 3x — generous on
 purpose: shared CI runners are noisy, and the gate exists to catch
 *structural* regressions such as an accidentally de-jitted hot path, not
-scheduling jitter).  Checked per matching row: ``us_ref`` in the compress
-table and ``us_fused_ref`` in the fused-aggregate table.
+scheduling jitter).  Checked per matching row: ``us_fused_ref`` and
+``us_wire_ref`` in the fused-aggregate table and ``us_fused_ref`` in the
+local-train table.
 
 ``compare``/``gate_main`` are table-agnostic so sibling gates (e.g.
 ``benchmarks/check_serve_bench``) reuse them with their own row specs.
@@ -23,7 +24,6 @@ THRESHOLD = 3.0
 
 # (table name, row-key fields, timed field) triples this gate checks.
 CHECKS = (
-    ("rows", ("n",), "us_ref"),
     ("agg_rows", ("n_clients", "d"), "us_fused_ref"),
     ("agg_rows", ("n_clients", "d"), "us_wire_ref"),
     ("local_train_rows", ("n_clients", "window"), "us_fused_ref"),

@@ -1,24 +1,24 @@
-"""Microbenchmark of the compression kernels (CPU interpret mode): wall
-time per call + payload accounting.  On CPU the numbers establish
-correctness-path cost only; the TPU roofline for these ops is in
-EXPERIMENTS.md (they are HBM-bandwidth-bound single-pass kernels).
+"""Microbenchmark of the fused kernels' jnp-oracle programs on the CPU:
+wall time per call and compiled intermediate memory.  On CPU the numbers
+establish correctness-path cost only; device times come from the chip
+benchmark (``bench/``).
 
-Three tables:
+Two tables:
 
-* ``rows``      — the per-client compress op at flat-vector sizes;
 * ``agg_rows``  — the fused compress-and-aggregate op (one program:
-  EF Top-K + int8 + weighted fog accumulation) against the unfused
-  compress -> segment-sum baseline (two programs with the dense (N, d)
-  reconstruction materialised between them);
+  EF Top-K + int8 + weighted fog accumulation) against its sparse-wire
+  twin (emit + scatter-accumulate);
 * ``local_train_rows`` — the fused local-train solver (the whole E-epoch
   client phase indexing each client's resident window;
-  ``optim/sgd.make_client_solver`` default) against the legacy per-client
-  ``local_sgd`` scan over a gathered (E * nb, bs, D) batch stream, across
+  ``optim/sgd.make_client_solver`` for a fusable detector) against the
+  per-client ``local_sgd`` scan over a gathered (E * nb, bs, D) batch
+  stream (the same autoencoder as a detector that is not fusable), across
   client counts.  The committed JSON is the perf-trend baseline CI
   compares against (benchmarks/check_kernel_micro).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import jax
@@ -27,9 +27,7 @@ import jax.numpy as jnp
 from benchmarks import common
 from repro.kernels import ops
 from repro.models import autoencoder as ae
-from repro.optim.sgd import LocalTrainConfig, make_client_solver
-
-SIZES = (1352, 65536, 1048576)
+from repro.optim.sgd import make_client_solver
 
 # (n_clients, d) cells for the fused aggregate op; n_fog = n_clients // 4.
 # The last cell is the 1M-element size (16 * 65536 = 1 048 576).
@@ -42,29 +40,11 @@ LT_SIZES = ((16, 256), (64, 256), (256, 256))
 LT_D, LT_BS, LT_EPOCHS, LT_LR = 32, 32, 5, 0.01
 
 
-def _time(fn, *args, reps=5):
-    """Min over ``reps`` individually blocked calls — the min estimator is
-    what the CI perf-trend gate compares, and unlike an async-smeared mean
-    it is stable on noisy shared runners."""
-    fn(*args)  # compile
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.time()
-        out = fn(*args)
-        jax.tree_util.tree_map(
-            lambda x: x.block_until_ready()
-            if hasattr(x, "block_until_ready") else x,
-            out,
-        )
-        best = min(best, time.time() - t0)
-    return best * 1e6
-
-
 def _paired_time(pair, args, reps: int = 16) -> dict[str, float]:
     """Paired-ratio timing for two pipelines over the same inputs: warm
     (compile) both, then time INTERLEAVED single blocked calls with
-    alternating within-pair order, and report the MIN of each — the same
-    estimator as :func:`_time` and the CI perf-trend gate.  On a shared
+    alternating within-pair order, and report the MIN of each — the
+    estimator the CI perf-trend gate compares.  On a shared
     runner the min is the uncontended cost; means/medians get corrupted
     by multi-call contention storms that hit whichever pipeline is
     unlucky.  ``pair`` is ((name, fn), (name, fn)); fns return a tuple
@@ -92,38 +72,7 @@ def _agg_inputs(n_clients: int, d: int):
     return deltas, errs, fog_id, weights, n_fog
 
 
-def _unfused_baseline(n_fog: int):
-    """The legacy two-program pipeline: batched compress, then a separate
-    jitted weighted segment-sum over the dense reconstructions."""
-    compress = jax.jit(
-        jax.vmap(lambda dd, ee: ops.compress(dd, ee, K_FRAC, False)[:2])
-    )
-    aggregate = jax.jit(
-        lambda recon, fid, w: jax.ops.segment_sum(
-            recon * w[:, None], fid, num_segments=n_fog
-        )
-    )
-
-    def run(deltas, errs, fog_id, weights):
-        recon, new_err = compress(deltas, errs)
-        return aggregate(recon, fog_id, weights), new_err
-
-    return run
-
-
 def run(scale: common.Scale) -> dict:
-    rows = []
-    for n in SIZES:
-        delta = jax.random.normal(jax.random.key(n), (n,))
-        err = jnp.zeros((n,))
-        us_ref = _time(lambda d, e: ops.compress(d, e, K_FRAC, False), delta, err)
-        us_pl = _time(lambda d, e: ops.compress(d, e, K_FRAC, True, True), delta, err)
-        _, _, bits = ops.compress(delta, err, K_FRAC, False)
-        rows.append(
-            dict(n=n, us_ref=us_ref, us_pallas_interpret=us_pl,
-                 payload_bits=float(bits), dense_bits=32.0 * n)
-        )
-
     agg_rows = []
     for n_clients, d in AGG_SIZES:
         deltas, errs, fog_id, weights, n_fog = _agg_inputs(n_clients, d)
@@ -136,10 +85,7 @@ def run(scale: common.Scale) -> dict:
         wire = lambda D, E, F, W: ops.compress_aggregate_wire(  # noqa: E731
             D, E, F, W, n_fog, K_FRAC, use_pallas=False
         )
-        unfused = _unfused_baseline(n_fog)
-        best = _paired_time((("fused", fused), ("unfused", unfused)), args)
-        us_fused, us_unfused = best["fused"], best["unfused"]
-        us_wire = _paired_time((("wire", wire), ("fused", fused)), args)["wire"]
+        best = _paired_time((("wire", wire), ("fused", fused)), args)
 
         def _temp_bytes(fn):
             """Peak device memory of the compiled program's INTERMEDIATES
@@ -150,14 +96,9 @@ def run(scale: common.Scale) -> dict:
 
         agg_rows.append(
             dict(n_clients=n_clients, d=d, elems=n_clients * d, n_fog=n_fog,
-                 us_fused_ref=us_fused, us_unfused_ref=us_unfused,
-                 us_wire_ref=us_wire,
-                 speedup=us_unfused / us_fused,
+                 us_fused_ref=best["fused"], us_wire_ref=best["wire"],
                  temp_fused_bytes=_temp_bytes(fused),
-                 temp_wire_bytes=_temp_bytes(wire),
-                 temp_unfused_bytes=_temp_bytes(
-                     lambda D, E, F, W: unfused(D, E, F, W)
-                 ))
+                 temp_wire_bytes=_temp_bytes(wire))
         )
 
     lt_rows = []
@@ -171,8 +112,8 @@ def run(scale: common.Scale) -> dict:
             ae.loss, batch_size=LT_BS, epochs=LT_EPOCHS, lr=LT_LR
         ))
         scan = jax.jit(make_client_solver(
-            ae.loss, batch_size=LT_BS, epochs=LT_EPOCHS, lr=LT_LR,
-            solver=LocalTrainConfig(fused=False),
+            dataclasses.replace(ae.detector(), fusable=False),
+            batch_size=LT_BS, epochs=LT_EPOCHS, lr=LT_LR,
         ))
         best = _paired_time(
             (("fused", fused), ("scan", scan)), (params, data, keys)
@@ -186,36 +127,22 @@ def run(scale: common.Scale) -> dict:
                  us_fused_ref=us_fused, us_scan_ref=us_scan,
                  speedup=us_scan / us_fused)
         )
-    return {"rows": rows, "agg_rows": agg_rows, "local_train_rows": lt_rows}
+    return {"agg_rows": agg_rows, "local_train_rows": lt_rows}
 
 
 def report(res: dict) -> str:
-    lines = ["kernel_micro (compress = EF + blockwise topk + int8)"]
-    lines.append(
-        f"{'n':>9} {'jnp-ref us':>12} {'pallas(interp) us':>18} {'ratio':>7} {'payload':>10}"
-    )
-    for r in res["rows"]:
-        lines.append(
-            f"{r['n']:>9} {r['us_ref']:>12.0f} {r['us_pallas_interpret']:>18.0f} "
-            f"{r['payload_bits'] / r['dense_bits']:>7.3f} "
-            f"{r['payload_bits']:>10.0f}"
-        )
-    lines.append("fused compress-and-aggregate vs sparse-wire vs unfused"
-                 " compress->segment-sum (jnp ref path; temp = compiled peak"
-                 " intermediate memory)")
+    lines = ["kernel_micro: fused compress-and-aggregate vs sparse wire"
+             " (jnp ref path; temp = compiled peak intermediate memory)"]
     lines.append(
         f"{'NxD':>14} {'elems':>9} {'fused us':>10} {'wire us':>9} "
-        f"{'unfused us':>11} {'speedup':>8} {'tmp f MB':>9} {'tmp w MB':>9} "
-        f"{'tmp u MB':>9}"
+        f"{'tmp f MB':>9} {'tmp w MB':>9}"
     )
     for r in res["agg_rows"]:
         lines.append(
             f"{r['n_clients']:>5}x{r['d']:<8} {r['elems']:>9} "
-            f"{r['us_fused_ref']:>10.0f} {r.get('us_wire_ref', 0):>9.0f} "
-            f"{r['us_unfused_ref']:>11.0f} {r['speedup']:>8.2f} "
-            f"{r.get('temp_fused_bytes', 0) / 1e6:>9.2f} "
-            f"{r.get('temp_wire_bytes', 0) / 1e6:>9.2f} "
-            f"{r.get('temp_unfused_bytes', 0) / 1e6:>9.2f}"
+            f"{r['us_fused_ref']:>10.0f} {r['us_wire_ref']:>9.0f} "
+            f"{r['temp_fused_bytes'] / 1e6:>9.2f} "
+            f"{r['temp_wire_bytes'] / 1e6:>9.2f}"
         )
     lines.append("fused local-train (resident window) vs per-client scan over"
                  " a gathered batch stream (jnp ref path)")

@@ -77,10 +77,7 @@ def _wire_k_frac(d: int, cfg: comp.CompressorConfig):
     ``rho_s``; config-axis sweeps trace it and must keep the dense oracle.
     Returns a float, or None when the config doesn't qualify.
     """
-    if not (
-        cfg.enabled and cfg.is_sparse and cfg.fused
-        and cfg.mode == "blockwise"
-    ):
+    if not (cfg.enabled and cfg.is_sparse and cfg.mode == "blockwise"):
         return None
     k_frac = comp.blockwise_k_frac(d, cfg.rho_s)
     if not isinstance(k_frac, (int, float)):
@@ -115,7 +112,7 @@ def client_chunk_scan(
     O(chunk) instead of O(N) — the peak high-water mark scales with the
     chunk knob, not the fleet.
 
-    Inside each chunk, a concrete-``rho_s`` fused blockwise config takes
+    Inside each chunk, a concrete-``rho_s`` sparse blockwise config takes
     the sparse wire (emit + scatter-accumulate, no dense per-chunk
     reconstruction); anything else falls back to the dense per-chunk path
     (still chunk-bounded).  Chunks are addressed with clamped
@@ -230,13 +227,11 @@ def compress_and_accumulate(
     weights = weights * finite.astype(weights.dtype)
     fog_weight = jax.ops.segment_sum(weights, fog_id, num_segments=n_fog)
 
-    # ``is_sparse`` is the STATIC sparsity predicate: rho_s itself may be a
-    # tracer inside a config-axis sweep, where the shape-class guarantees a
-    # uniform branch.
-    if cfg.enabled and cfg.is_sparse and cfg.fused and cfg.mode == "blockwise":
+    if cfg.enabled and cfg.mode == "blockwise":
         # The fused kernel path: EF Top-K + int8 + weighted accumulation
         # directly into the (n_fog, d) buffers — the dense per-client
-        # reconstruction never materialises.
+        # reconstruction never materialises.  A dense rho_s >= 1 keeps
+        # every real coordinate (quantise-only).
         comp.validate_blockwise_bits(cfg.quant_bits)
         fog_sum, new_err = kops.compress_aggregate(
             deltas, err, fog_id, weights, n_fog,
@@ -247,9 +242,8 @@ def compress_and_accumulate(
         )
         return fog_sum, fog_weight, new_err
 
-    # Unfused fallback (compression off, dense rho_s == 1 quantise-only,
-    # mode="global", or cfg.fused=False): per-client reconstruction then a
-    # dense segment-sum — the legacy two-pass pipeline.
+    # Compression off or mode="global": per-client reconstruction, then a
+    # dense segment-sum.
     if cfg.enabled:
         recon, new_err = jax.vmap(
             lambda d_, e_: comp.compress_update(d_, e_, cfg)

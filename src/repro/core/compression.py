@@ -6,7 +6,8 @@ Two modes:
   semantics for the ~1 352-parameter autoencoder (rho_s = 0.05 -> K ~ 68).
 - ``blockwise``: the TPU-native blocked kernel path (Deep-Gradient-
   Compression-style per-block selection) for LLM-scale updates, backed by
-  the fused Pallas kernel in :mod:`repro.kernels`.
+  the compress-and-aggregate kernel of :mod:`repro.kernels.fused_agg` —
+  a single update is one client in one fog at unit weight.
 
 Both apply error feedback (Eq. 30) — the local error buffer absorbs the
 sparsification *and* quantisation residuals — and report the acoustic
@@ -50,9 +51,6 @@ class CompressorConfig:
     mode: str = "global"         # "global" | "blockwise"
     use_pallas: bool = False     # blockwise only: route through the kernel
     interpret: bool = True       # pallas interpret mode (CPU)
-    fused: bool = True           # fuse compression into fog aggregation
-    # (core/aggregation.compress_and_aggregate); False = legacy per-client
-    # compress_update + dense segment-sum, kept as the equivalence baseline.
     sparse: bool | None = None   # static rho_s < 1 predicate (None = derive)
 
     def replace(self, **kw: Any) -> "CompressorConfig":
@@ -75,17 +73,15 @@ class CompressorConfig:
 
 
 def _cc_flatten(c: CompressorConfig):
-    aux = (c.quant_bits, c.mode, c.use_pallas, c.interpret, c.fused,
-           c.is_sparse)
+    aux = (c.quant_bits, c.mode, c.use_pallas, c.interpret, c.is_sparse)
     return (c.rho_s,), aux
 
 
 def _cc_unflatten(aux, children) -> CompressorConfig:
-    quant_bits, mode, use_pallas, interpret, fused, sparse = aux
+    quant_bits, mode, use_pallas, interpret, sparse = aux
     return CompressorConfig(
         rho_s=children[0], quant_bits=quant_bits, mode=mode,
-        use_pallas=use_pallas, interpret=interpret, fused=fused,
-        sparse=sparse,
+        use_pallas=use_pallas, interpret=interpret, sparse=sparse,
     )
 
 
@@ -226,17 +222,17 @@ def compress_update(
         return unravel(recon), new_err
 
     if cfg.mode == "blockwise":
+        # One client in one fog at unit weight: the fog sum is the client's
+        # reconstruction.
         validate_blockwise_bits(cfg.quant_bits)
-        k_frac = blockwise_k_frac(flat.shape[0], cfg.rho_s)
-        if cfg.quant_bits < 32:
-            recon, new_err, _ = kops.compress(
-                flat, err, k_frac, cfg.use_pallas, cfg.interpret
-            )
-        else:
-            recon, new_err = kops.topk_ef(
-                flat, err, k_frac, cfg.use_pallas, cfg.interpret
-            )
-        return unravel(recon), new_err
+        recon, new_err = kops.compress_aggregate(
+            flat[None], err[None], jnp.zeros((1,), jnp.int32),
+            jnp.ones((1,), jnp.float32), 1,
+            blockwise_k_frac(flat.shape[0], cfg.rho_s),
+            quantize=cfg.quant_bits < 32,
+            use_pallas=cfg.use_pallas, interpret=cfg.interpret,
+        )
+        return unravel(recon[0]), new_err[0]
 
     raise ValueError(f"unknown compression mode: {cfg.mode}")
 

@@ -6,19 +6,17 @@ parallel), fog clusters are segment-sum groups, and the three cooperation
 rules from Sec. V-B drive the mixing step.  Per-round energy (Eqs. 17-20),
 latency (Eq. 21), participation, and battery dynamics are all recorded.
 
-The sensor side of a round is TWO fused operators by default.  Local
-training (Eq. 12) runs through :func:`repro.optim.sgd.make_client_solver`:
-for the paper autoencoder the whole E-epoch SGD phase of every client is
-one VMEM-resident kernel launch (``kernels/fused_local_train``, jnp oracle
+The sensor side of a round is TWO fused operators.  Local training
+(Eq. 12) runs through :func:`repro.optim.sgd.make_client_solver`: for the
+paper autoencoder the whole E-epoch SGD phase of every client is one
+VMEM-resident kernel launch (``kernels/fused_local_train``, jnp oracle
 ``kernels/ref.local_train_ref``) that indexes each client's resident
 window per minibatch instead of gathering a dense ``(E * nb, bs, D)``
-batch stream — set ``HFLConfig.local_solver = LocalTrainConfig(
-fused=False)`` for the legacy per-client scan (non-AE models fall back
-automatically).  Compression (Eq. 30) and fog aggregation (Eq. 13) then
-run as the second fused operator —
+batch stream (detectors that are not ``fusable`` take a per-client
+scan).  Compression (Eq. 30) and fog aggregation (Eq. 13) then run as
+the second fused operator —
 :func:`repro.core.aggregation.compress_and_aggregate` — so the dense
-per-client reconstructions never materialise either; set
-``CompressorConfig.fused=False`` for the legacy two-pass pipeline.
+per-client reconstructions never materialise either.
 ``loss_fn`` is a plain loss or a :class:`repro.models.detector.Detector`
 (the Anomaly Transformer trains on stride-1 windows); the compute term of
 the energy and latency model counts that detector's own operations.

@@ -33,21 +33,21 @@ only a correctness tool, so CPU/GPU fall back automatically.
 ``Engine.resolve_config`` exposes the rewrite so sequential comparisons
 can run the identical numerics.
 
-Inside the round loops, compression and fog aggregation run FUSED by
-default: ``core/aggregation.compress_and_aggregate`` dispatches to the
+Inside the round loops, a blockwise compressor and fog aggregation run
+FUSED: ``core/aggregation.compress_and_aggregate`` dispatches to the
 one-HBM-pass compress-and-aggregate kernel (``kernels/fused_agg``, jnp
 oracle ``kernels/ref.compress_aggregate_ref``) which accumulates each
 client's reconstruction straight into the (n_fog, d) fog buffers instead
 of materialising dense (N, d) reconstructions and re-reading them in a
-segment-sum.  Opt out per config with
-``CompressorConfig(fused=False)`` — the legacy two-pass pipeline, kept as
-the equivalence baseline.
+segment-sum.
 
 Detectors
 ---------
 ``Engine(detector=...)`` picks the model every cell trains and evaluates
 (``models/detector``); the default is the paper autoencoder at
-``hidden`` widths (one argument or the other, not both).
+``hidden`` widths (one argument or the other, not both).  The detector
+also picks the client phase: a ``fusable`` one (the paper autoencoder)
+trains in the fused local-train kernel, any other on the scan path.
 ``models/anomaly_transformer.detector`` trains the Anomaly Transformer on
 stride-1 windows inside the hierarchical round's client-chunk scan and
 scores non-overlapping windows.
@@ -96,17 +96,12 @@ from repro.core import drift as drf
 from repro.core import faults as flt
 from repro.data.synthetic import SensorDataset
 from repro.kernels import ops as kops
+from repro.kernels.ops import default_use_pallas
 from repro.launch import experiment as exp
 from repro.launch import sharding as shard_rules
 from repro.models import autoencoder as ae
 from repro.models.detector import Detector, choose
 from repro.optim.sgd import LocalTrainConfig
-
-
-def default_use_pallas() -> bool:
-    """Compiled Pallas kernels need a real TPU; elsewhere the engine falls
-    back to the pure-jnp oracle in :mod:`repro.kernels.ref`."""
-    return jax.default_backend() == "tpu"
 
 
 # Methods whose client phase bypasses ``optim/sgd.make_client_solver``.
@@ -300,11 +295,8 @@ class Engine:
     def resolve_local_solver(
         self, ls: LocalTrainConfig
     ) -> LocalTrainConfig:
-        """The engine's local-train default: the fused kernel, Pallas on
-        TPU, the ``kernels/ref`` oracle elsewhere.  ``fused=False`` (the
-        legacy per-client scan) is respected as an explicit opt-out."""
-        if not ls.fused:
-            return ls
+        """The engine's local-train backend: Pallas on TPU, the
+        ``kernels/ref`` oracle elsewhere."""
         use_pallas = default_use_pallas()
         if ls.use_pallas == use_pallas and ls.interpret == (not use_pallas):
             return ls
@@ -495,8 +487,7 @@ class Engine:
             det = self.detector
             if telemetry.recording():
                 shapes = jax.eval_shape(lambda k: det.init(k, dim), jax.random.key(0))
-                if (det.fusable and base.local_solver.fused
-                        and base.local_solver.use_pallas
+                if (det.fusable and base.local_solver.use_pallas
                         and method not in _UNFUSED_CLIENT_PHASE):
                     telemetry.observe("engine.local_train_pack",
                                       kops.local_train_pack(ae.widths(shapes)))
@@ -647,7 +638,7 @@ class Engine:
         cc = base.compressor
         if cc.enabled and cc.is_sparse and cc.mode == "blockwise" and cc.use_pallas:
             knobs["rho_s"] = float(cc.rho_s)
-        if base.local_solver.fused and base.local_solver.use_pallas:
+        if base.local_solver.use_pallas:
             knobs["lr"] = float(base.lr)
             knobs["prox_mu"] = float(base.prox_mu)
         if base.robust != "mean" and cc.use_pallas:
@@ -955,7 +946,6 @@ class Engine:
         tau: jax.Array | float,
         *,
         n_trial_axes: int = 0,
-        fused: bool = True,
         label: str | None = None,
     ):
         """Batched fused anomaly scoring — the serving family (ISSUE 3).
@@ -983,13 +973,13 @@ class Engine:
             for leaf in jax.tree_util.tree_leaves(params)
         )
         cache_key = ("score", treedef, p_shapes, x.shape, str(x.dtype),
-                     n_trial_axes, fused)
+                     n_trial_axes)
 
         def build():
             def one(p, xx, tt):
                 return serving_score_fn(
                     p, xx, tt, use_pallas=use_pallas,
-                    interpret=not use_pallas, fused=fused,
+                    interpret=not use_pallas,
                 )
 
             fn = one
@@ -1004,7 +994,7 @@ class Engine:
         n_rows = math.prod(x.shape[:-1])
         self._log(kind="score", method="score", label=label or "score",
                   n_trials=n_rows, wall_s=wall, fresh_compile=fresh,
-                  compressor="fused" if fused else "unfused")
+                  compressor="n/a")
         return out
 
     def pod_train_step(

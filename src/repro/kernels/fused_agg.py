@@ -1,9 +1,9 @@
 """Pallas TPU kernel: fused compress-and-aggregate (EF Top-K + int8 +
 weighted fog accumulation) — the federated round's hot path in ONE pass.
 
-The unfused pipeline makes three HBM round-trips per round: the compress
-kernel writes a dense reconstruction per client, the error buffer, and the
-fog segment-sum then re-reads every reconstruction.  This kernel loads each
+Compressing each client and then segment-summing would make three HBM
+round-trips per round: a dense reconstruction per client, the error
+buffer, and a re-read of every reconstruction.  This kernel loads each
 (client, block) tile once, runs the identical sparsify-quantise-residual
 computation in VMEM (bit-for-bit the :func:`repro.kernels.ref.compress_ref`
 semantics), and accumulates ``w_i * recon_i`` straight into a per-fog VMEM
@@ -35,7 +35,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import BISECT_ITERS
-from repro.kernels.topk_ef import BLOCK_LANES, BLOCK_ROWS
+
+# The kernels' unit of work: a flat update vector is zero-padded and
+# reshaped (by ops.py) into (BLOCK_ROWS, BLOCK_LANES) tiles.  BLOCK_LANES =
+# 128 matches the VPU lane count; 64 rows make a tile 8,192 f32 = 32 KiB.
+BLOCK_ROWS = 64
+BLOCK_LANES = 128
+BLOCK_ELEMS = BLOCK_ROWS * BLOCK_LANES
 
 # Mosaic's default scoped-VMEM limit on v5e.  Kernels whose resident blocks
 # outgrow it (the identity-segment fog accumulator of the robust path holds

@@ -27,21 +27,17 @@ import jax.numpy as jnp
 from repro.kernels import fused_agg as _fa
 from repro.kernels import fused_local_train as _flt
 from repro.kernels import fused_score as _fs
-from repro.kernels import quant8 as _q8
 from repro.kernels import ref as _ref
 from repro.kernels import robust_agg as _ra
 from repro.kernels import swa_attention as _swa
-from repro.kernels import topk_ef as _tk
 
-BLOCK_ELEMS = _tk.BLOCK_ELEMS
+BLOCK_ELEMS = _fa.BLOCK_ELEMS
 
 
-def _pad_blocks(x: jax.Array) -> tuple[jax.Array, int]:
-    """Zero-pad flat (n,) to (nb, ROWS, LANES); return original length."""
-    n = x.shape[0]
-    nb = max(1, -(-n // BLOCK_ELEMS))
-    padded = jnp.zeros((nb * BLOCK_ELEMS,), x.dtype).at[:n].set(x)
-    return padded.reshape(nb, _tk.BLOCK_ROWS, _tk.BLOCK_LANES), n
+def default_use_pallas() -> bool:
+    """Compiled Pallas kernels need a real TPU; elsewhere callers fall back
+    to the pure-jnp oracle in :mod:`repro.kernels.ref`."""
+    return jax.default_backend() == "tpu"
 
 
 def _pad_blocks_batch(x: jax.Array) -> tuple[jax.Array, int]:
@@ -49,7 +45,7 @@ def _pad_blocks_batch(x: jax.Array) -> tuple[jax.Array, int]:
     n_rows, d = x.shape
     nb = max(1, -(-d // BLOCK_ELEMS))
     padded = jnp.zeros((n_rows, nb * BLOCK_ELEMS), x.dtype).at[:, :d].set(x)
-    return padded.reshape(n_rows, nb, _tk.BLOCK_ROWS, _tk.BLOCK_LANES), d
+    return padded.reshape(n_rows, nb, _fa.BLOCK_ROWS, _fa.BLOCK_LANES), d
 
 
 # The tiles per grid step of each compress kernel a program calls, noted
@@ -77,10 +73,6 @@ def _note_tiles(tiles: int) -> int:
     return tiles
 
 
-def _unpad(x: jax.Array, n: int) -> jax.Array:
-    return x.reshape(-1)[:n]
-
-
 def _static_scalar(x, name: str) -> float:
     """Concretise a kernel-bound scalar for the Pallas branch.
 
@@ -106,114 +98,6 @@ def _block_k(k_frac) -> jax.Array | int:
     return jnp.maximum(
         1.0, jnp.round(jnp.asarray(k_frac, jnp.float32) * BLOCK_ELEMS)
     )
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _topk_ef_pallas(delta, err, k: int, interpret: bool):
-    blocks, n = _pad_blocks(delta)
-    err_blocks, _ = _pad_blocks(err)
-    sparse, new_err = _tk.topk_ef_blocks(blocks, err_blocks, k, interpret)
-    return _unpad(sparse, n), _unpad(new_err, n)
-
-
-@jax.jit
-def _topk_ef_ref(delta, err, k):
-    blocks, n = _pad_blocks(delta)
-    err_blocks, _ = _pad_blocks(err)
-    flat = blocks.reshape(blocks.shape[0], -1)
-    eflat = err_blocks.reshape(blocks.shape[0], -1)
-    sparse, new_err = _ref.blockwise_topk_ef_ref(flat, eflat, k)
-    return _unpad(sparse, n), _unpad(new_err, n)
-
-
-def topk_ef(
-    delta: jax.Array,
-    err: jax.Array,
-    k_frac: float | jax.Array,
-    use_pallas: bool = False,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """Blockwise EF Top-K on a flat vector.  Keeps ~k_frac of each block.
-
-    ``k_frac`` may be traced on the oracle path (``use_pallas=False``).
-    """
-    if use_pallas:
-        k = max(1, int(round(_static_scalar(k_frac, "k_frac") * BLOCK_ELEMS)))
-        return _topk_ef_pallas(delta, err, k, interpret)
-    return _topk_ef_ref(delta, err, _block_k(k_frac))
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def quant8(
-    x: jax.Array, use_pallas: bool = False, interpret: bool = True
-) -> tuple[jax.Array, jax.Array, int]:
-    """Blockwise int8 quantise a flat vector -> (q blocks, scales, n)."""
-    blocks, n = _pad_blocks(x)
-    if use_pallas:
-        q, scale = _q8.quant8_blocks(blocks, interpret)
-        scale = scale.reshape(-1, 1)
-        q = q.reshape(q.shape[0], -1)
-    else:
-        q, scale = _ref.quant8_ref(blocks.reshape(blocks.shape[0], -1))
-    return q, scale, n
-
-
-@jax.jit
-def dequant8(q: jax.Array, scale: jax.Array, n: int) -> jax.Array:
-    """Inverse of :func:`quant8`; returns the flat (n,) vector."""
-    return _ref.dequant8_ref(q, scale).reshape(-1)[:n]
-
-
-def _compress_payload(qf, scale, new_err, n):
-    recon = _ref.dequant8_ref(qf, scale)
-    nnz = jnp.sum(qf != 0)
-    d = jnp.maximum(n, 2)
-    b_idx = jnp.ceil(jnp.log2(d.astype(jnp.float32)))
-    payload_bits = nnz.astype(jnp.float32) * (8.0 + b_idx)
-    return _unpad(recon, n), _unpad(new_err, n), payload_bits
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _compress_pallas(delta, err, k: int, interpret: bool):
-    blocks, n = _pad_blocks(delta)
-    err_blocks, _ = _pad_blocks(err)
-    q, scale, new_err = _q8.compress_blocks(blocks, err_blocks, k, interpret)
-    qf = q.reshape(q.shape[0], -1)
-    scale = scale.reshape(-1, 1)
-    return _compress_payload(qf, scale, new_err, n)
-
-
-@jax.jit
-def _compress_ref(delta, err, k):
-    blocks, n = _pad_blocks(delta)
-    err_blocks, _ = _pad_blocks(err)
-    qf, scale, new_err = _ref.compress_ref(
-        blocks.reshape(blocks.shape[0], -1),
-        err_blocks.reshape(blocks.shape[0], -1),
-        k,
-    )
-    return _compress_payload(qf, scale, new_err, n)
-
-
-def compress(
-    delta: jax.Array,
-    err: jax.Array,
-    k_frac: float | jax.Array,
-    use_pallas: bool = False,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused EF + blockwise Top-K + int8 for a flat update vector.
-
-    Returns (recon, new_err, payload_bits) where ``recon`` is the
-    dequantised sparse update the receiver reconstructs (same length as
-    ``delta``) and ``payload_bits`` is the acoustic payload size per the
-    paper's accounting (Eq. 31): kept coords * (8 + ceil(log2 d)) bits.
-    ``k_frac`` may be traced on the oracle path.
-    """
-    if use_pallas:
-        k = max(1, int(round(_static_scalar(k_frac, "k_frac") * BLOCK_ELEMS)))
-        return _compress_pallas(delta, err, k, interpret)
-    return _compress_ref(delta, err, _block_k(k_frac))
 
 
 @functools.partial(
@@ -365,7 +249,7 @@ def _wire_aggregate_pallas(idx, q, scale, fog_id, weights, n_fog: int,
     fog_blocks = _fa.wire_aggregate_blocks(
         jnp.pad(idx, pad)[:, :, None, :],
         jnp.pad(q.astype(jnp.float32), pad)[:, :, None, :],
-        jnp.broadcast_to(scale[:, :, None, None], (n, nb, 1, _tk.BLOCK_LANES)),
+        jnp.broadcast_to(scale[:, :, None, None], (n, nb, 1, _fa.BLOCK_LANES)),
         fog_id, weights, n_fog, interpret,
     )
     return fog_blocks.reshape(n_fog, -1)[:, :d]
@@ -522,28 +406,43 @@ def fused_score(
     Returns (err (R,) f32, flags (R,) bool); the dense reconstruction is
     never materialised in HBM on the kernel path.
     """
-    r, d = x.shape
+    r = x.shape[0]
     ws = tuple(layer["w"] for layer in params)
     bs = tuple(layer["b"] for layer in params)
     tau_rows = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (r,))
     if not use_pallas:
         return _ref.fused_score_ref(x, ws, bs, tau_rows)
+    return _score_pallas(
+        _fs.score_blocks, x, tau_rows,
+        tuple(w.astype(jnp.float32) for w in ws), None, bs, interpret,
+    )
 
+
+def _score_pallas(kernel, x, tau_rows, ws, sws, bs, interpret):
+    """Lay out one score kernel's call: rows zero-padded to whole
+    SCORE_ROWS tiles (padded-row thresholds +inf, so their flags stay
+    False) and every layer dimension to a LANES multiple; ``sws`` are the
+    int8 kernel's per-channel scales (None for f32 weights)."""
+    r, d = x.shape
     rows_pad = max(1, -(-r // _fs.SCORE_ROWS)) * _fs.SCORE_ROWS
     dims = (d,) + tuple(w.shape[1] for w in ws)     # layer output dims
     dims_pad = tuple(max(1, -(-dd // _fs.LANES)) * _fs.LANES for dd in dims)
     x_pad = _pad2(x.astype(jnp.float32), rows_pad, dims_pad[0])
-    ws_pad = tuple(
-        _pad2(w.astype(jnp.float32), dims_pad[i], dims_pad[i + 1])
-        for i, w in enumerate(ws)
-    )
-    bs_pad = tuple(
+    layers = [tuple(
+        _pad2(w, dims_pad[i], dims_pad[i + 1]) for i, w in enumerate(ws)
+    )]
+    if sws is not None:
+        layers.append(tuple(
+            _pad2(s.astype(jnp.float32).reshape(1, -1), 1, dims_pad[i + 1])
+            for i, s in enumerate(sws)
+        ))
+    layers.append(tuple(
         _pad2(b.astype(jnp.float32)[None, :], 1, dims_pad[i + 1])
         for i, b in enumerate(bs)
-    )
+    ))
     tau_pad = jnp.full((rows_pad,), jnp.inf, jnp.float32).at[:r].set(tau_rows)
-    err, flag = _fs.score_blocks(
-        x_pad, tau_pad.reshape(-1, 1, _fs.SCORE_ROWS), ws_pad, bs_pad, interpret
+    err, flag = kernel(
+        x_pad, tau_pad.reshape(-1, 1, _fs.SCORE_ROWS), *layers, interpret
     )
     return err.reshape(-1)[:r], flag.reshape(-1)[:r] > 0.0
 
@@ -564,35 +463,14 @@ def fused_score_q8(
     resident weight buffers stay int8.  Same padding contract as
     :func:`fused_score` — int8 zero padding dequantises to exact zeros.
     """
-    r, d = x.shape
+    r = x.shape[0]
     qws = tuple(layer["qw"] for layer in qparams)
     sws = tuple(layer["sw"] for layer in qparams)
     bs = tuple(layer["b"] for layer in qparams)
     tau_rows = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (r,))
     if not use_pallas:
         return _ref.fused_score_q8_ref(x, qws, sws, bs, tau_rows)
-
-    rows_pad = max(1, -(-r // _fs.SCORE_ROWS)) * _fs.SCORE_ROWS
-    dims = (d,) + tuple(q.shape[1] for q in qws)    # layer output dims
-    dims_pad = tuple(max(1, -(-dd // _fs.LANES)) * _fs.LANES for dd in dims)
-    x_pad = _pad2(x.astype(jnp.float32), rows_pad, dims_pad[0])
-    qws_pad = tuple(
-        _pad2(q, dims_pad[i], dims_pad[i + 1]) for i, q in enumerate(qws)
-    )
-    sws_pad = tuple(
-        _pad2(s.astype(jnp.float32).reshape(1, -1), 1, dims_pad[i + 1])
-        for i, s in enumerate(sws)
-    )
-    bs_pad = tuple(
-        _pad2(b.astype(jnp.float32)[None, :], 1, dims_pad[i + 1])
-        for i, b in enumerate(bs)
-    )
-    tau_pad = jnp.full((rows_pad,), jnp.inf, jnp.float32).at[:r].set(tau_rows)
-    err, flag = _fs.score_blocks_q8(
-        x_pad, tau_pad.reshape(-1, 1, _fs.SCORE_ROWS), qws_pad, sws_pad, bs_pad,
-        interpret,
-    )
-    return err.reshape(-1)[:r], flag.reshape(-1)[:r] > 0.0
+    return _score_pallas(_fs.score_blocks_q8, x, tau_rows, qws, sws, bs, interpret)
 
 
 def _ravel_deltas(dws, dbs, n):

@@ -43,8 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_agg import vmem_params
-from repro.kernels.topk_ef import BLOCK_LANES, BLOCK_ROWS
+from repro.kernels.fused_agg import BLOCK_LANES, BLOCK_ROWS, vmem_params
 
 # Double-buffered bytes the resident client column may take before rows
 # are tiled finer (half the default scoped VMEM, leaving the rest for the

@@ -6,15 +6,14 @@ the E-epoch local-training drivers used by the federated round (Eq. 12).
 
 The round loops obtain their client phase from :func:`make_client_solver`,
 which returns a BATCHED solver (all clients at once).  For the paper
-autoencoder it dispatches to the fused local-train operator
-(``kernels/ops.local_train``: the whole E-epoch SGD phase in one
-VMEM-resident kernel launch, Pallas on TPU / the ``kernels/ref`` oracle
-elsewhere) — the dense per-client ``(E * nb, bs, D)`` batch stream of the
-legacy path never materialises.  Other row models automatically fall
-back to the legacy per-client ``local_sgd`` scan, which
-``LocalTrainConfig(fused=False)`` also forces — kept as the equivalence
-baseline — and window detectors (``models/detector``, e.g. the Anomaly
-Transformer) train on minibatches of their stride-1 windows.
+autoencoder (a ``Detector.fusable`` model) it dispatches to the fused
+local-train operator (``kernels/ops.local_train``: the whole E-epoch SGD
+phase in one VMEM-resident kernel launch, Pallas on TPU / the
+``kernels/ref`` oracle elsewhere) — no dense per-client ``(E * nb, bs,
+D)`` batch stream materialises.  Other row models take the per-client
+``local_sgd`` scan over gathered minibatches, and window detectors
+(``models/detector``, e.g. the Anomaly Transformer) train on minibatches
+of their stride-1 windows.
 """
 from __future__ import annotations
 
@@ -79,17 +78,13 @@ def proximal_local_sgd(
 
 @dataclasses.dataclass(frozen=True)
 class LocalTrainConfig:
-    """How the round loops run the client phase (Eq. 12).
-
-    ``fused=True`` routes AE clients through the fused local-train kernel
-    (``kernels/fused_local_train``; ``use_pallas``/``interpret`` pick the
-    backend exactly like ``CompressorConfig``).  ``fused=False`` is the
-    legacy per-client ``local_sgd`` scan over a gathered batch stream —
-    the equivalence baseline.  Models the kernel cannot express (anything
-    but the paper's MLP autoencoder + MSE loss) fall back automatically.
+    """The backend of the fused local-train kernel
+    (``kernels/fused_local_train``) in the client phase (Eq. 12):
+    ``use_pallas``/``interpret`` pick it exactly like ``CompressorConfig``.
+    Whether a model trains in the kernel is the detector's to say
+    (``Detector.fusable``); the others take the scan path.
     """
 
-    fused: bool = True
     use_pallas: bool = False
     interpret: bool = True
 
@@ -137,10 +132,10 @@ def make_client_solver(
     minibatch loss (N,) for a plain loss function, or the detector's
     per-client mean stats ``{"loss": (N,), ...}`` for a detector.
 
-    Dispatch happens per call: when ``solver.fused`` and the params are
-    the paper autoencoder trained with its own loss, the whole phase runs
-    as ONE fused operator over all clients; a row detector otherwise takes
-    the legacy vmapped ``local_sgd`` / ``proximal_local_sgd`` scan over
+    Dispatch happens per call: when the detector is ``fusable`` and the
+    params are the AE-style MLP, the whole phase runs as ONE fused
+    operator over all clients; a row detector otherwise takes the
+    vmapped ``local_sgd`` / ``proximal_local_sgd`` scan over
     gathered minibatches; a window detector (``Detector.window`` = L)
     trains each client on minibatches of its stride-1 windows, ``epochs``
     shuffles of the T - L + 1 window starts with the remainder dropped.
@@ -198,7 +193,7 @@ def make_client_solver(
     def detector_fn(params, data, keys):
         if det.window is not None:
             return window_path(params, data, keys)
-        if solver.fused and det.fusable and fusable_params(params):
+        if det.fusable and fusable_params(params):
             window = data.shape[1]
             idx = jax.vmap(
                 lambda k: multi_epoch_indices(k, window, batch_size, epochs)

@@ -8,12 +8,9 @@ fused operator (``kernels/fused_score``, jnp oracle
 ``kernels/ref.fused_score_ref``) over a ``(fleet, window, d)`` telemetry
 batch — compiled Pallas on TPU, the oracle on CPU/GPU, mirroring the
 compressor dispatch.  The dense reconstruction never materialises in HBM
-on the kernel path.
-
-``fused=False`` keeps the legacy three-program pipeline
-(``core/anomaly.reconstruction_errors`` + ``flag_anomalies``) as the
-equivalence baseline, exactly like ``CompressorConfig(fused=False)`` does
-for the training hot path.
+on the kernel path.  ``core/anomaly.reconstruction_errors`` +
+``flag_anomalies`` (on :func:`dequantize_params` for int8 weights) are the
+oracle the tests compare it with.
 """
 from __future__ import annotations
 
@@ -22,15 +19,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import anomaly
 from repro.kernels import ops as kops
-from repro.models import autoencoder as ae
-
-
-def default_use_pallas() -> bool:
-    """Compiled Pallas kernels need a real TPU; elsewhere the serving path
-    falls back to the pure-jnp oracle (same rule as ``repro.engine``)."""
-    return jax.default_backend() == "tpu"
+from repro.kernels.ops import default_use_pallas
 
 
 class ScoreResult(NamedTuple):
@@ -47,7 +37,6 @@ def score(
     *,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-    fused: bool = True,
 ) -> ScoreResult:
     """Score telemetry ``x`` of shape (..., d) against threshold(s) ``tau``.
 
@@ -56,6 +45,10 @@ def score(
     axes are flattened into one row axis for the kernel and restored on the
     way out, so (fleet, window, d) batches score as a single sweep.
     """
+    return _score(kops.fused_score, params, x, tau, use_pallas, interpret)
+
+
+def _score(kernel, params, x, tau, use_pallas, interpret) -> ScoreResult:
     if use_pallas is None:
         use_pallas = default_use_pallas()
     if interpret is None:
@@ -65,13 +58,9 @@ def score(
     tau_rows = jnp.broadcast_to(
         jnp.asarray(tau, jnp.float32), lead
     ).reshape(-1)
-    if fused:
-        err, flag = kops.fused_score(
-            rows, params, tau_rows, use_pallas=use_pallas, interpret=interpret
-        )
-    else:
-        err = anomaly.reconstruction_errors(ae.apply, params, rows)
-        flag = anomaly.flag_anomalies(err, tau_rows)
+    err, flag = kernel(
+        rows, params, tau_rows, use_pallas=use_pallas, interpret=interpret
+    )
     # Non-finite errors (NaN telemetry, poisoned/diverged model) are
     # anomalous by policy: ``NaN > tau`` is False, which would otherwise
     # silently pass the corrupt rows as normal.
@@ -85,8 +74,8 @@ def quantize_params(params: Any) -> Any:
     Each layer ``{"w", "b"}`` becomes ``{"qw" int8, "sw" (1, d_out) f32,
     "b" f32}`` with ``w ≈ qw * sw`` (``sw = amax(|w|, axis=0) / 127``);
     biases stay f32 (they are a rounding error of the weight bytes).
-    Reuses the symmetric-amax scheme of ``kernels/quant8`` at per-column
-    granularity, which keeps the reconstruction-error shift within
+    Reuses the symmetric-amax scheme of ``kernels/ref.quant8_ref`` at
+    per-column granularity, which keeps the reconstruction-error shift within
     ~0.5/127 of each column's dynamic range — tight enough that threshold
     flags survive (parity-tested).  This is the opt-in
     ``weight_dtype="int8"`` serving representation; dequantisation happens
@@ -108,8 +97,8 @@ def quantize_params(params: Any) -> Any:
 
 def dequantize_params(qparams: Any) -> Any:
     """Materialise f32 ``{"w", "b"}`` layers from :func:`quantize_params`
-    output (the unfused/legacy pipeline and tests use this; the fused
-    paths dequantise in-program instead)."""
+    output (the tests' oracle uses this; :func:`score_q8` dequantises
+    in-program instead)."""
     return [
         {"w": layer["qw"].astype(jnp.float32) * layer["sw"].reshape(1, -1),
          "b": layer["b"]}
@@ -124,34 +113,13 @@ def score_q8(
     *,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-    fused: bool = True,
 ) -> ScoreResult:
     """:func:`score` over int8-quantised serving weights.
 
-    ``qparams`` comes from :func:`quantize_params`; the fused paths
-    (oracle and Pallas) dequantise per output channel inside the score
-    program, so the weight buffers stay int8 end to end.  ``fused=False``
-    materialises f32 weights and runs the legacy three-program pipeline —
-    the equivalence baseline, exactly like the f32 path's opt-out."""
-    if use_pallas is None:
-        use_pallas = default_use_pallas()
-    if interpret is None:
-        interpret = not default_use_pallas()
-    lead = x.shape[:-1]
-    rows = x.reshape(-1, x.shape[-1])
-    tau_rows = jnp.broadcast_to(
-        jnp.asarray(tau, jnp.float32), lead
-    ).reshape(-1)
-    if fused:
-        err, flag = kops.fused_score_q8(
-            rows, qparams, tau_rows, use_pallas=use_pallas, interpret=interpret
-        )
-    else:
-        params = dequantize_params(qparams)
-        err = anomaly.reconstruction_errors(ae.apply, params, rows)
-        flag = anomaly.flag_anomalies(err, tau_rows)
-    flag = jnp.where(jnp.isfinite(err), flag, True)
-    return ScoreResult(err.reshape(lead), flag.reshape(lead))
+    ``qparams`` comes from :func:`quantize_params`; the score program
+    (oracle and Pallas alike) dequantises per output channel inside it, so
+    the weight buffers stay int8 end to end."""
+    return _score(kops.fused_score_q8, qparams, x, tau, use_pallas, interpret)
 
 
 def fleet_tau(

@@ -78,13 +78,12 @@ class ScorePrograms:
         weight_dtype: str = "f32",
         use_pallas: bool | None = None,
         interpret: bool | None = None,
-        fused: bool = True,
     ):
         if weight_dtype not in ("f32", "int8"):
             raise ValueError(f"weight_dtype must be f32|int8, got {weight_dtype!r}")
         self.weight_dtype = weight_dtype
         self.compiles: dict[int, int] = {}
-        self._kw = dict(use_pallas=use_pallas, interpret=interpret, fused=fused)
+        self._kw = dict(use_pallas=use_pallas, interpret=interpret)
         self._fns: dict[int, Callable] = {}
 
     def prepare(self, params: Any) -> Any:
@@ -232,7 +231,6 @@ class ScoringService:
         programs: ScorePrograms | None = None,
         use_pallas: bool | None = None,
         interpret: bool | None = None,
-        fused: bool = True,
     ):
         if (tau is None) and (calibrator is None):
             raise ValueError("need a fixed tau or a StreamingCalibrator")
@@ -255,7 +253,7 @@ class ScoringService:
         if programs is None:
             programs = ScorePrograms(
                 weight_dtype=weight_dtype, use_pallas=use_pallas,
-                interpret=interpret, fused=fused,
+                interpret=interpret,
             )
         elif programs.weight_dtype != weight_dtype:
             raise ValueError(

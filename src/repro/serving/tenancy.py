@@ -48,7 +48,6 @@ class MultiTenantService:
         clock: Callable[[], float] = time.monotonic,
         use_pallas: bool | None = None,
         interpret: bool | None = None,
-        fused: bool = True,
     ):
         self.buckets = tuple(sorted(set(buckets or (int(batch_rows),))))
         self.max_wait_s = max_wait_s
@@ -56,7 +55,7 @@ class MultiTenantService:
         self._clock = clock
         self.programs = ScorePrograms(
             weight_dtype=weight_dtype, use_pallas=use_pallas,
-            interpret=interpret, fused=fused,
+            interpret=interpret,
         )
         self._tenants: dict[str, ScoringService] = {}
 

@@ -25,8 +25,9 @@ from benchmarks import run as bench_run
 # check_kernel_micro.compare (shared by check_serve_bench)
 # ---------------------------------------------------------------------------
 
-def _kernel_json(us_ref: float) -> dict:
-    return {"rows": [{"n": 1024, "us_ref": us_ref}]}
+def _kernel_json(us_fused_ref: float) -> dict:
+    return {"agg_rows": [{"n_clients": 8, "d": 1352,
+                          "us_fused_ref": us_fused_ref}]}
 
 
 def test_kernel_gate_passes_within_threshold():
@@ -41,11 +42,11 @@ def test_kernel_gate_trips_on_regression():
         _kernel_json(400.0), _kernel_json(100.0), threshold=3.0
     )
     assert len(failures) == 1
-    assert "us_ref" in failures[0]
+    assert "us_fused_ref" in failures[0]
 
 
 def test_kernel_gate_fails_loudly_on_missing_row():
-    fresh = {"rows": []}  # the refactor dropped the cell
+    fresh = {"agg_rows": []}  # the refactor dropped the cell
     failures = check_kernel_micro.compare(
         fresh, _kernel_json(100.0), threshold=3.0
     )
@@ -55,7 +56,8 @@ def test_kernel_gate_fails_loudly_on_missing_row():
 def test_kernel_gate_skips_baseline_without_metric():
     """A baseline predating the metric is 'no trend yet', not a failure."""
     failures = check_kernel_micro.compare(
-        _kernel_json(100.0), {"rows": [{"n": 1024}]}, threshold=3.0
+        _kernel_json(100.0), {"agg_rows": [{"n_clients": 8, "d": 1352}]},
+        threshold=3.0,
     )
     assert failures == []
 

@@ -1,13 +1,14 @@
 """Tests for the fused compress-and-aggregate path.
 
-Covers the ISSUE-2 acceptance points: ref-oracle parity of the fused op
-against the unfused compress -> fog_aggregate pipeline (random cluster
-assignments, zero-weight non-participants, the n < BLOCK_ELEMS padding
-edge), Pallas-interpret vs jnp-oracle parity, the round-loop dispatch
-(fused vs ``CompressorConfig(fused=False)``), and shard_map-vs-single-
-device equivalence on a forced multi-device CPU mesh (subprocess, since
-XLA device flags must be set before jax initialises).
+Covers parity of the fused op with a per-client ``kernels/ref`` oracle
+followed by ``fog_aggregate`` (random cluster assignments, zero-weight
+non-participants, the n < BLOCK_ELEMS padding edge), Pallas-interpret vs
+oracle parity over the kernel grid, the round loop against the same
+oracle, and shard_map-vs-single-device equivalence on a forced
+multi-device CPU mesh (subprocess, since XLA device flags must be set
+before jax initialises).
 """
+import functools
 import os
 import subprocess
 import sys
@@ -20,9 +21,11 @@ import pytest
 
 from repro.core import aggregation as agg
 from repro.core import compression as comp
-from repro.kernels import fused_agg, ops
+from repro.kernels import fused_agg, ops, ref
 
 N_FOG = 4
+SIZES = [64, 1024, 1352, 4096, 8192 + 17, 65536]
+KFRACS = [0.02, 0.05, 0.25]
 
 
 def _inputs(n_clients, d, seed=0, zero_weight_every=3):
@@ -38,12 +41,49 @@ def _inputs(n_clients, d, seed=0, zero_weight_every=3):
     return deltas, err, fog_id, weights
 
 
-def _unfused(deltas, err, fog_id, weights, cfg):
-    recon, new_err = jax.vmap(
-        lambda d_, e_: comp.compress_update(d_, e_, cfg)
-    )(deltas, err)
+@functools.partial(jax.jit, static_argnames="quantize")
+def _ref_blocks(deltas, err, k, quantize):
+    """Each client through ``kernels/ref``: EF block Top-K at ``k`` per
+    block (+ the int8 round trip).  Returns (recon, new_err), both (N, d)."""
+    n, d = deltas.shape
+    nb = max(1, -(-d // ops.BLOCK_ELEMS))
+
+    def blocks(a):
+        return jnp.pad(a, ((0, 0), (0, nb * ops.BLOCK_ELEMS - d))).reshape(n, nb, -1)
+
+    if quantize:
+        q, scale, new_err = jax.vmap(lambda x, e: ref.compress_ref(x, e, k))(
+            blocks(deltas), blocks(err))
+        recon = ref.dequant8_ref(q, scale)
+    else:
+        recon, new_err = jax.vmap(
+            lambda x, e: ref.blockwise_topk_ef_ref(x, e, k))(blocks(deltas), blocks(err))
+    return recon.reshape(n, -1)[:, :d], new_err.reshape(n, -1)[:, :d]
+
+
+def _ref_compress(deltas, err, cfg):
+    """Per-client compression by the oracle: ``kernels/ref`` for blockwise
+    configs, the jnp global Top-K (it has no kernel) otherwise."""
+    if cfg.mode == "global":
+        return jax.vmap(lambda d_, e_: comp.compress_update(d_, e_, cfg))(deltas, err)
+    k_frac = comp.blockwise_k_frac(deltas.shape[1], cfg.rho_s)
+    k = max(1, int(round(k_frac * ops.BLOCK_ELEMS)))
+    return _ref_blocks(deltas, err, k, cfg.quant_bits < 32)
+
+
+def _oracle(deltas, err, fog_id, weights, cfg):
+    recon, new_err = _ref_compress(deltas, err, cfg)
     fog_up, fog_w = agg.fog_aggregate(recon, fog_id, weights, N_FOG)
     return fog_up, fog_w, new_err
+
+
+def _ref_accumulate(deltas, err, fog_id, weights, n_fog, cfg, chunk=None):
+    """``agg.compress_and_accumulate``'s contract from the oracle: per-client
+    compression, then the weighted segment-sums."""
+    recon, new_err = _ref_compress(deltas, err, cfg)
+    fog_sum = jax.ops.segment_sum(recon * weights[:, None], fog_id, num_segments=n_fog)
+    fog_weight = jax.ops.segment_sum(weights, fog_id, num_segments=n_fog)
+    return fog_sum, fog_weight, new_err
 
 
 @pytest.mark.parametrize(
@@ -55,16 +95,14 @@ def _unfused(deltas, err, fog_id, weights, cfg):
     ],
 )
 def test_fused_blockwise_matches_unfused_pipeline(d):
-    """compress_and_aggregate == per-client compress_update + fog_aggregate
-    to float tolerance on random cluster assignments."""
+    """compress_and_aggregate == per-client kernels/ref compression +
+    fog_aggregate to float tolerance on random cluster assignments."""
     deltas, err, fog_id, weights = _inputs(11, d)
     cfg = comp.CompressorConfig(rho_s=0.05, quant_bits=8, mode="blockwise")
     fog_up, fog_w, new_err = agg.compress_and_aggregate(
         deltas, err, fog_id, weights, N_FOG, cfg
     )
-    ref_up, ref_w, ref_err = _unfused(
-        deltas, err, fog_id, weights, cfg.replace(fused=False)
-    )
+    ref_up, ref_w, ref_err = _oracle(deltas, err, fog_id, weights, cfg)
     np.testing.assert_allclose(np.asarray(fog_w), np.asarray(ref_w), rtol=1e-6)
     np.testing.assert_allclose(
         np.asarray(fog_up), np.asarray(ref_up), rtol=1e-5, atol=1e-6
@@ -82,7 +120,7 @@ def test_fused_global_matches_unfused_pipeline():
     fog_up, fog_w, new_err = agg.compress_and_aggregate(
         deltas, err, fog_id, weights, N_FOG, cfg
     )
-    ref_up, ref_w, ref_err = _unfused(deltas, err, fog_id, weights, cfg)
+    ref_up, ref_w, ref_err = _oracle(deltas, err, fog_id, weights, cfg)
     np.testing.assert_allclose(
         np.asarray(fog_up), np.asarray(ref_up), rtol=1e-5, atol=1e-7
     )
@@ -96,9 +134,7 @@ def test_fused_topk_only_matches_unfused_pipeline():
     fog_up, _, new_err = agg.compress_and_aggregate(
         deltas, err, fog_id, weights, N_FOG, cfg
     )
-    ref_up, _, ref_err = _unfused(
-        deltas, err, fog_id, weights, cfg.replace(fused=False)
-    )
+    ref_up, _, ref_err = _oracle(deltas, err, fog_id, weights, cfg)
     np.testing.assert_allclose(
         np.asarray(fog_up), np.asarray(ref_up), rtol=1e-5, atol=1e-6
     )
@@ -137,24 +173,63 @@ def test_empty_fog_gets_zero_update():
     np.testing.assert_array_equal(np.asarray(fog_up[1:]), 0.0)
 
 
-@pytest.mark.parametrize("d", [1352, 8192 + 17, 65536])
-@pytest.mark.parametrize("quantize", [True, False])
-def test_pallas_interpret_matches_ref(d, quantize):
-    """The fused kernel body (interpret mode) must agree with the jnp
-    oracle — same bisection threshold and int8 rules."""
-    deltas, err, fog_id, weights = _inputs(6, d, seed=d)
-    fs_r, ne_r = ops.compress_aggregate(
-        deltas, err, fog_id, weights, N_FOG, 0.05, quantize=quantize,
-        use_pallas=False,
-    )
-    fs_p, ne_p = ops.compress_aggregate(
-        deltas, err, fog_id, weights, N_FOG, 0.05, quantize=quantize,
-        use_pallas=True, interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(fs_p), np.asarray(fs_r), rtol=1e-5, atol=1e-4
-    )
-    np.testing.assert_allclose(np.asarray(ne_p), np.asarray(ne_r), atol=1e-5)
+def _grid():
+    """The kernel grid, one client per fog; where it meets the shared-fog
+    grid (k_frac 0.05 at 1352 / 8209 / 65536) six clients share four fogs
+    at random weights, some zero."""
+    for d in SIZES:
+        for k_frac in KFRACS:
+            for quantize in (False, True):
+                shared = k_frac == 0.05 and d in (1352, 8192 + 17, 65536)
+                yield pytest.param(d, k_frac, quantize, shared,
+                                   id=f"{d}-{k_frac}-{'q8' if quantize else 'topk'}"
+                                      f"{'-shared' if shared else ''}")
+
+
+@pytest.mark.parametrize("d,k_frac,quantize,shared", list(_grid()))
+def test_pallas_interpret_matches_ref(d, k_frac, quantize, shared):
+    """The fused kernel body (interpret mode) and the ops oracle must agree
+    with kernels/ref applied client by client — same bisection threshold
+    and int8 rules — and sum each client into its fog at its weight."""
+    if shared:
+        deltas, err, fog_id, weights = _inputs(6, d, seed=d)
+        n_fog = N_FOG
+    else:
+        deltas, err, _, _ = _inputs(1, d, seed=d)
+        fog_id, weights, n_fog = jnp.zeros((1,), jnp.int32), jnp.ones((1,)), 1
+    k = max(1, int(round(k_frac * ops.BLOCK_ELEMS)))
+    recon, ne_o = _ref_blocks(deltas, err, k, quantize)
+    fs_o = jax.ops.segment_sum(recon * weights[:, None], fog_id, num_segments=n_fog)
+    for use_pallas in (True, False):
+        fs, ne = ops.compress_aggregate(
+            deltas, err, fog_id, weights, n_fog, k_frac, quantize=quantize,
+            use_pallas=use_pallas, interpret=True,
+        )
+        np.testing.assert_allclose(
+            np.asarray(fs), np.asarray(fs_o), rtol=1e-5, atol=1e-4
+        )
+        np.testing.assert_allclose(np.asarray(ne), np.asarray(ne_o), atol=1e-5)
+
+
+@pytest.mark.parametrize("d", [1352, 20000])
+@pytest.mark.parametrize("rho_s", [1.0, 2.0])
+def test_dense_blockwise_is_quantise_only(d, rho_s):
+    """A blockwise config at rho_s >= 1 runs the fused kernel keeping every
+    coordinate: kernels/ref's int8 round trip at a whole block."""
+    deltas, err, fog_id, weights = _inputs(5, d, seed=7)
+    recon, ref_err = _ref_blocks(deltas, err, ops.BLOCK_ELEMS, quantize=True)
+    ref_up, ref_w = agg.fog_aggregate(recon, fog_id, weights, N_FOG)
+    for use_pallas in (True, False):
+        cfg = comp.CompressorConfig(rho_s=rho_s, quant_bits=8, mode="blockwise",
+                                    use_pallas=use_pallas, interpret=True)
+        assert cfg.enabled and not cfg.is_sparse
+        fog_up, fog_w, new_err = agg.compress_and_aggregate(
+            deltas, err, fog_id, weights, N_FOG, cfg)
+        np.testing.assert_allclose(np.asarray(fog_w), np.asarray(ref_w), rtol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(fog_up), np.asarray(ref_up), rtol=1e-5, atol=1e-6
+        )
+        np.testing.assert_allclose(np.asarray(new_err), np.asarray(ref_err), atol=1e-6)
 
 
 @pytest.mark.parametrize(
@@ -232,9 +307,9 @@ def test_tiles_per_step_are_bit_identical_to_one_tile_a_step(
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_round_loop_fused_matches_unfused():
-    """End-to-end: hfl.train with the fused default == the legacy
-    per-client pipeline (CompressorConfig(fused=False))."""
+def test_round_loop_fused_matches_unfused(monkeypatch):
+    """End-to-end: hfl.train with the fused kernel == the same rounds with
+    compression and fog sums done client by client by kernels/ref."""
     from repro.data.synthetic import SyntheticConfig, generate, normalize
     from repro.launch import experiment as exp
     from repro.models import autoencoder as ae
@@ -247,10 +322,8 @@ def test_round_loop_fused_matches_unfused():
     cfg = exp.make_config(n_sensors=10, n_fog=3, rounds=2, local_epochs=1,
                           compressor=cc)
     p1, m1 = hfl.train(jax.random.key(2), params0, ae.loss, ds, cfg)
-    p2, m2 = hfl.train(
-        jax.random.key(2), params0, ae.loss, ds,
-        cfg.replace(compressor=cc.replace(fused=False)),
-    )
+    monkeypatch.setattr(agg, "compress_and_accumulate", _ref_accumulate)
+    p2, m2 = hfl.train(jax.random.key(2), params0, ae.loss, ds, cfg)
     np.testing.assert_allclose(
         np.asarray(m1.loss), np.asarray(m2.loss), rtol=1e-5
     )

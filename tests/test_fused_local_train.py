@@ -5,9 +5,13 @@ Covers the ISSUE-4 acceptance points: fused-vs-``local_sgd``-scan parity
 to float tolerance (plain SGD and FedProx ``mu > 0``, window sizes that do
 not divide the batch size, E = 1 and E = 5), Pallas-interpret vs
 jnp-oracle parity, the auto-fallback rule for non-AE models, end-to-end
-``hfl.train`` / ``flat_fl.train_flat`` fused-vs-unfused equivalence, the
-engine's local-solver resolution, and the Eq. 21 empty-fog latency fix.
+``hfl.train`` / ``flat_fl.train_flat`` equivalence of the fused kernel
+with the scan path (the autoencoder as a detector that is not
+``fusable``), the engine's local-solver resolution, and the Eq. 21
+empty-fog latency fix.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,13 +20,14 @@ from jax.flatten_util import ravel_pytree
 
 from repro.core import cooperation as coop
 from repro.core import hfl
-from repro.data.pipeline import multi_epoch_indices
+from repro.data.pipeline import multi_epoch_batches, multi_epoch_indices
 from repro.kernels import ops
 from repro.launch.mesh import make_mesh
 from repro.models import autoencoder as ae
 from repro.optim.sgd import (
     LocalTrainConfig,
     fusable_params,
+    local_sgd,
     make_client_solver,
 )
 
@@ -38,14 +43,19 @@ def _clients(n, window, seed=0):
     return jax.random.normal(jax.random.key(seed), (n, window, D))
 
 
+def _scan_detector():
+    """The paper autoencoder on the scan path: per-client ``local_sgd``
+    over a gathered (E * nb, bs, D) batch stream."""
+    return dataclasses.replace(ae.detector(), fusable=False)
+
+
 def _legacy(params, data, keys, batch_size, epochs, lr, mu):
-    """The pre-fusion client phase: per-client scan over a gathered
-    (E * nb, bs, D) batch stream."""
     solver = make_client_solver(
-        ae.loss, batch_size=batch_size, epochs=epochs, lr=lr, prox_mu=mu,
-        solver=LocalTrainConfig(fused=False),
+        _scan_detector(), batch_size=batch_size, epochs=epochs, lr=lr,
+        prox_mu=mu,
     )
-    return solver(params, data, keys)
+    deltas, stats = solver(params, data, keys)
+    return deltas, stats["loss"]
 
 
 @pytest.mark.parametrize(
@@ -208,11 +218,13 @@ def test_non_ae_models_fall_back():
         quad_loss, batch_size=32, epochs=1, lr=0.05
     )
     d_c, _ = solver(params, data, keys)
-    legacy = make_client_solver(
-        quad_loss, batch_size=32, epochs=1, lr=0.05,
-        solver=LocalTrainConfig(fused=False),
-    )
-    d_l, _ = legacy(params, data, keys)
+
+    def scan_one(dd, kk):
+        batches = multi_epoch_batches(kk, dd, 32, 1)
+        p1, _ = local_sgd(quad_loss, params, batches, 0.05)
+        return ravel_pytree(jax.tree_util.tree_map(jnp.subtract, p1, params))[0]
+
+    d_l = jax.vmap(scan_one)(data, keys)
     np.testing.assert_array_equal(np.asarray(d_c), np.asarray(d_l))
 
 
@@ -231,14 +243,11 @@ def _tiny_setup(prox_mu=0.0):
 
 @pytest.mark.parametrize("prox_mu", [0.0, 0.01])
 def test_hfl_train_fused_matches_unfused(prox_mu):
-    """End to end: hfl.train with the fused default == the legacy scan
-    path (LocalTrainConfig(fused=False)) to float tolerance."""
+    """End to end: hfl.train with the fused kernel == the scan path (the
+    autoencoder as a detector that is not fusable) to float tolerance."""
     ds, params0, cfg = _tiny_setup(prox_mu)
     p1, m1 = hfl.train(jax.random.key(2), params0, ae.loss, ds, cfg)
-    p2, m2 = hfl.train(
-        jax.random.key(2), params0, ae.loss, ds,
-        cfg.replace(local_solver=LocalTrainConfig(fused=False)),
-    )
+    p2, m2 = hfl.train(jax.random.key(2), params0, _scan_detector(), ds, cfg)
     np.testing.assert_allclose(
         np.asarray(m1.loss), np.asarray(m2.loss), rtol=1e-5
     )
@@ -253,8 +262,7 @@ def test_flat_train_fused_matches_unfused():
     ds, params0, cfg = _tiny_setup(prox_mu=0.01)   # FedProx in-kernel
     p1, m1 = flat_fl.train_flat(jax.random.key(2), params0, ae.loss, ds, cfg)
     p2, m2 = flat_fl.train_flat(
-        jax.random.key(2), params0, ae.loss, ds,
-        cfg.replace(local_solver=LocalTrainConfig(fused=False)),
+        jax.random.key(2), params0, _scan_detector(), ds, cfg
     )
     np.testing.assert_allclose(
         np.asarray(m1.loss), np.asarray(m2.loss), rtol=1e-5
@@ -269,12 +277,14 @@ def test_engine_resolves_local_solver():
 
     eng = eng_mod.Engine()
     ls = eng.resolve_local_solver(LocalTrainConfig())
-    assert ls.fused
     assert ls.use_pallas == eng_mod.default_use_pallas()
-    # the explicit opt-out is respected
-    off = LocalTrainConfig(fused=False)
-    assert eng.resolve_local_solver(off) == off
+    assert ls.interpret == (not ls.use_pallas)
+    assert eng.resolve_local_solver(ls) == ls
     assert eng.resolve_config(hfl.HFLConfig()).local_solver == ls
+    # the detector, not the solver config, picks the scan path: an engine
+    # training a detector that is not fusable resolves the same backend
+    scan = eng_mod.Engine(detector=_scan_detector())
+    assert scan.resolve_config(hfl.HFLConfig()).local_solver == ls
 
 
 def test_mesh_pod_local_epochs_runs_and_degenerates():

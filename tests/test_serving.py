@@ -1,8 +1,8 @@
 """Tests for the online anomaly-scoring subsystem (repro.serving).
 
-Covers the ISSUE-3 acceptance points: fused-vs-unfused equivalence of the
-score path against the ``core/anomaly`` oracle (all-normal / all-anomalous
-windows and sub-block padding included), ref-vs-Pallas(interpret) kernel
+Covers equivalence of the fused score path with the ``core/anomaly``
+oracle (all-normal / all-anomalous windows and sub-block padding
+included), ref-vs-Pallas(interpret) kernel
 parity, streaming-vs-one-shot calibration (exact below capacity,
 convergent beyond it), the micro-batching service's hot-swap with a PINNED
 compile count, ``Engine.score`` trial-vmapped equivalence, and the
@@ -49,8 +49,8 @@ def _oracle(params, x, tau):
     ],
 )
 def test_fused_score_matches_unfused_anomaly_oracle(shape):
-    """serving.score(fused=True) == reconstruction_errors + flag_anomalies
-    to float tolerance, flags exactly."""
+    """serving.score == reconstruction_errors + flag_anomalies to float
+    tolerance, flags exactly."""
     params = _params()
     x = jax.random.normal(jax.random.key(3), shape)
     err_o, _ = _oracle(params, x, jnp.inf)
@@ -165,18 +165,18 @@ def test_reservoir_empty_state_is_inf():
 def test_score_flags_nonfinite_errors_as_anomalous():
     """NaN telemetry produces a NaN reconstruction error; ``err > tau`` is
     False for NaN, so without the policy override corrupt rows would pass
-    as normal.  They must flag True on both the fused and legacy paths."""
+    as normal.  The oracle pipeline shows the hazard; the score path must
+    flag them True."""
     params = _params()
     x = jax.random.normal(jax.random.key(33), (5, 32))
     x = x.at[1].set(jnp.nan).at[3, 0].set(jnp.inf)
-    for fused in (True, False):
-        res = serving_score_fn(
-            params, x, jnp.inf, use_pallas=False, fused=fused
-        )
-        flag = np.asarray(res.flag)
-        assert flag[1] and flag[3], f"fused={fused}"
-        # Finite rows keep the tau=inf verdict: not anomalous.
-        np.testing.assert_array_equal(flag[[0, 2, 4]], False)
+    err_o, flag_o = _oracle(params, x, jnp.inf)
+    assert not np.isfinite(np.asarray(err_o)[[1, 3]]).any()
+    assert not np.asarray(flag_o).any()
+    flag = np.asarray(serving_score_fn(params, x, jnp.inf, use_pallas=False).flag)
+    assert flag[1] and flag[3]
+    # Finite rows keep the tau=inf verdict: not anomalous.
+    np.testing.assert_array_equal(flag[[0, 2, 4]], False)
 
 
 def test_calibrator_excludes_nonfinite_errors():

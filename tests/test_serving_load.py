@@ -233,17 +233,17 @@ def test_quantize_dequantize_roundtrip_error_bounded():
 
 
 def test_score_q8_fused_matches_dequantized_unfused():
+    """score_q8 == the core/anomaly oracle on the dequantised weights."""
     params = _params(seed=3, d=32, hidden=(16, 8, 16))
     qp = quantize_params(params)
     x = jnp.asarray(_rows(300, seed=3, d=32))
-    fused = score_q8(qp, x, 1.0, use_pallas=False, fused=True)
-    legacy = score_q8(qp, x, 1.0, use_pallas=False, fused=False)
+    fused = score_q8(qp, x, 1.0, use_pallas=False)
+    err = anomaly.reconstruction_errors(ae.apply, dequantize_params(qp), x)
     np.testing.assert_allclose(
-        np.asarray(fused.error), np.asarray(legacy.error),
-        rtol=1e-5, atol=1e-5,
+        np.asarray(fused.error), np.asarray(err), rtol=1e-5, atol=1e-5,
     )
     np.testing.assert_array_equal(
-        np.asarray(fused.flag), np.asarray(legacy.flag)
+        np.asarray(fused.flag), np.asarray(anomaly.flag_anomalies(err, 1.0))
     )
 
 
