@@ -265,9 +265,11 @@ class Engine:
         self.percentile = percentile
         self.point_adjusted = point_adjusted
         self._programs: dict[Any, Callable] = {}
-        # Tiles per grid step of each program's compress kernels, as its
-        # tracing call chose them (``engine.compress_tiles_per_step``).
-        self._compress_tiles: dict[Any, list[int]] = {}
+        # What a grid step of each program's Pallas kernels takes, per
+        # kernel family, as its tracing call chose it
+        # (``engine.compress_tiles_per_step``,
+        # ``engine.local_train_packs_per_step``).
+        self._grid_steps: dict[Any, dict[str, list[int]]] = {}
         self._last_calls: list[tuple[Callable, tuple]] = []
         self.compile_count = 0
         self.call_log: list[dict] = []
@@ -532,13 +534,17 @@ class Engine:
                     client_mesh, jax.sharding.PartitionSpec(None, "data")
                 )
                 stacked = jax.device_put(stacked, on_mesh)
-        with kops.compress_tiles_traced() as tiles:
+        with kops.grid_steps_traced() as steps:
             out, wall = self._timed_call(fn, keys, stacked)
-        if tiles:
-            self._compress_tiles[cache_key] = list(dict.fromkeys(tiles))
-        if telemetry.recording() and cache_key in self._compress_tiles:
-            telemetry.observe("engine.compress_tiles_per_step",
-                              self._compress_tiles[cache_key])
+        if any(steps.values()):
+            self._grid_steps[cache_key] = {
+                kind: list(dict.fromkeys(v)) for kind, v in steps.items()}
+        if telemetry.recording():
+            noted = self._grid_steps.get(cache_key, {})
+            for kind, name in (("compress", "engine.compress_tiles_per_step"),
+                               ("local_train", "engine.local_train_packs_per_step")):
+                if noted.get(kind):
+                    telemetry.observe(name, noted[kind])
         if store is not None:
             with telemetry.span("engine.publish"):
                 params0 = jax.tree_util.tree_map(lambda a: a[0, 0], out.pop("params"))

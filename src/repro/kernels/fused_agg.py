@@ -295,17 +295,19 @@ def slot_pad(k: int) -> int:
 
 
 def tiles_per_step(n_tiles: int, resident_bytes: int,
-                   tile_bytes: int = STEP_TILE_BYTES) -> int:
-    """Tiles each grid step of a compress kernel bisects together.
+                   tile_bytes: int = STEP_TILE_BYTES,
+                   most: int = MAX_TILES_PER_STEP) -> int:
+    """Tiles each grid step of a kernel takes together.
 
-    At most :data:`MAX_TILES_PER_STEP`, and no more than fit the VMEM
-    budget beside ``resident_bytes`` at ``tile_bytes`` a tile.  Among the
-    counts from that cap down to half of it, the largest that divides
+    At most ``most`` (for the compress kernels
+    :data:`MAX_TILES_PER_STEP`), and no more than fit the VMEM budget
+    beside ``resident_bytes`` at ``tile_bytes`` a tile.  Among the counts
+    from that cap down to half of it, the largest that divides
     ``n_tiles`` is taken, so that no step is ragged; where none divides,
     the cap is taken and the last step is ragged.
     """
     fit = (VMEM_BUDGET - resident_bytes) // tile_bytes
-    cap = max(1, min(MAX_TILES_PER_STEP, fit, n_tiles))
+    cap = max(1, min(most, fit, n_tiles))
     for t in range(cap, cap // 2, -1):
         if n_tiles % t == 0:
             return t

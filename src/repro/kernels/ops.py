@@ -48,29 +48,32 @@ def _pad_blocks_batch(x: jax.Array) -> tuple[jax.Array, int]:
     return padded.reshape(n_rows, nb, _fa.BLOCK_ROWS, _fa.BLOCK_LANES), d
 
 
-# The tiles per grid step of each compress kernel a program calls, noted
-# while :func:`compress_tiles_traced` collects.
-_traced_tiles: list[int] | None = None
+# What each grid step of the Pallas kernels a program calls takes
+# together, per kernel family ("compress": tiles of a compress kernel,
+# "local_train": packs of the local-train kernel), noted while
+# :func:`grid_steps_traced` collects.
+_traced_steps: dict[str, list[int]] | None = None
 
 
 @contextlib.contextmanager
-def compress_tiles_traced() -> Iterator[list[int]]:
-    """Collect the tiles per grid step of every compress kernel that a
-    program tracing inside the block calls, in call order.  A program
-    traces once: a later call of it notes nothing, so the caller keeps
-    what the tracing call collected."""
-    global _traced_tiles
-    outer, _traced_tiles = _traced_tiles, []
+def grid_steps_traced() -> Iterator[dict[str, list[int]]]:
+    """Collect, in call order, the tiles per grid step of every compress
+    kernel and the packs per grid step of every local-train kernel that a
+    program tracing inside the block calls.  A program traces once: a
+    later call of it notes nothing, so the caller keeps what the tracing
+    call collected."""
+    global _traced_steps
+    outer, _traced_steps = _traced_steps, {"compress": [], "local_train": []}
     try:
-        yield _traced_tiles
+        yield _traced_steps
     finally:
-        _traced_tiles = outer
+        _traced_steps = outer
 
 
-def _note_tiles(tiles: int) -> int:
-    if _traced_tiles is not None:
-        _traced_tiles.append(tiles)
-    return tiles
+def _note_step(kind: str, n: int) -> int:
+    if _traced_steps is not None:
+        _traced_steps[kind].append(n)
+    return n
 
 
 def _static_scalar(x, name: str) -> float:
@@ -162,7 +165,8 @@ def compress_aggregate(
     """
     if use_pallas:
         k = max(1, int(round(_static_scalar(k_frac, "k_frac") * BLOCK_ELEMS)))
-        tiles = _note_tiles(_fa.dense_tiles_per_step(deltas.shape[0], n_fog))
+        tiles = _note_step(
+            "compress", _fa.dense_tiles_per_step(deltas.shape[0], n_fog))
         return _compress_aggregate_pallas(
             deltas, err, fog_id, weights, n_fog, k, quantize, interpret,
             tiles=tiles,
@@ -233,7 +237,8 @@ def compress_wire(
     if use_pallas:
         n, d = deltas.shape
         nb = max(1, -(-d // BLOCK_ELEMS))
-        tiles = _note_tiles(_fa.wire_tiles_per_step(n, nb, _fa.slot_pad(k)))
+        tiles = _note_step(
+            "compress", _fa.wire_tiles_per_step(n, nb, _fa.slot_pad(k)))
         return _compress_wire_pallas(deltas, err, k, quantize, interpret,
                                      tiles)
     return _compress_wire_ref(deltas, err, k, quantize)
@@ -541,25 +546,49 @@ def _diag_blocks(a, pack, width, shape, row_step, n):
     return jnp.stack(parts, axis=1).reshape(-1, rows, cols)[:n]
 
 
+def _local_train_layout(params, data_shape, idx_shape):
+    """The kernel layout of a local-train call: (pack P, lane width Wc of
+    a client, padded layer dims, padded window, padded batch, padded
+    steps)."""
+    _, window, d = data_shape
+    steps, bsz = idx_shape[1], idx_shape[2]
+    dims = (d,) + tuple(layer["w"].shape[1] for layer in params)
+    pack, width = local_train_pack(dims), max(dims)
+    # Client c of a pack owns lanes [c * width, c * width + d_l) of layer l.
+    dims_pad = tuple(
+        _round_up((pack - 1) * width + dd, _flt.LANES) for dd in dims)
+    return (pack, width, dims_pad, _round_up(window, _flt.LANES),
+            _round_up(bsz, _flt.SUBLANES), _round_up(steps, _flt.SUBLANES))
+
+
+def _local_train_packs(params, data_shape, idx_shape) -> int:
+    """Packs each grid step of the local-train kernel trains together on
+    a call with these shapes: ``fused_local_train.
+    local_train_packs_per_step`` on the call's padded blocks."""
+    pack, _, dims_pad, w_pad, b_pad, s_pad = _local_train_layout(
+        params, data_shape, idx_shape)
+    resident, per_pack = _flt.pack_vmem_bytes(
+        w_pad, dims_pad[0], s_pad, pack * b_pad,
+        list(zip(dims_pad, dims_pad[1:])), b_pad)
+    n_packs = -(-data_shape[0] // pack)
+    return _flt.local_train_packs_per_step(n_packs, per_pack, resident)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("lr", "prox_mu", "interpret")
+    jax.jit, static_argnames=("lr", "prox_mu", "interpret", "packs")
 )
 def _local_train_pallas(
-    params, data, idx, lr: float, prox_mu: float, interpret: bool
+    params, data, idx, lr: float, prox_mu: float, interpret: bool,
+    packs: int,
 ):
     ws = tuple(layer["w"] for layer in params)
     bs = tuple(layer["b"] for layer in params)
     n, window, d = data.shape
     steps, bsz = idx.shape[1], idx.shape[2]
-    lanes, sub = _flt.LANES, _flt.SUBLANES
-    dims = (d,) + tuple(w.shape[1] for w in ws)
-    pack, width = local_train_pack(dims), max(dims)
-    n_all = _round_up(n, pack)          # pad clients fill the last pack
-    # Client c of a pack owns lanes [c * width, c * width + d_l) of layer l.
-    dims_pad = tuple(_round_up((pack - 1) * width + dd, lanes) for dd in dims)
-    w_pad = _round_up(window, lanes)
-    b_pad = _round_up(bsz, sub)
-    s_pad = _round_up(steps, sub)
+    pack, width, dims_pad, w_pad, b_pad, s_pad = _local_train_layout(
+        params, data.shape, idx.shape)
+    # Pad clients fill the last pack, and pad packs the last grid step.
+    n_all = _round_up(n, pack * packs)
     x = jnp.zeros((n_all, window, d), jnp.float32).at[:n].set(
         data.astype(jnp.float32))
     x = _side_by_side(x, pack, width)
@@ -580,7 +609,7 @@ def _local_train_pallas(
     )
     dws_p, dbs_p, loss = _flt.local_train_blocks(
         x_pad, idx_pad, ws_pad, bs_pad, steps, bsz, lr, prox_mu,
-        interpret, pack=pack, width=width,
+        interpret, pack=pack, width=width, packs=packs,
     )
     dws = [_diag_blocks(dw, pack, width, w.shape, width, n)
            for dw, w in zip(dws_p, ws)]
@@ -603,11 +632,12 @@ def local_train(
 
     Layout owner for :mod:`repro.kernels.fused_local_train`: it packs
     :func:`local_train_pack` clients side by side into each 128-lane tile
-    (windows and biases in lane blocks, weights as diagonal copies),
-    zero-pads windows and every layer dimension to LANES multiples and the
-    (steps, bsz) index tables to SUBLANES multiples on both axes, -1-filled
-    so padded rows select nothing, then cuts each client's diagonal block
-    back out.  ``idx`` comes from
+    (windows and biases in lane blocks, weights as diagonal copies), pads
+    the pack count to whole grid steps of K packs (K from the call's
+    shapes), zero-pads windows and every layer dimension to LANES
+    multiples and the (steps, bsz) index tables to SUBLANES multiples on
+    both axes, -1-filled so padded rows select nothing, then cuts each
+    client's diagonal block back out.  ``idx`` comes from
     :func:`repro.data.pipeline.multi_epoch_indices`, which makes this
     batch-for-batch identical to ``local_sgd`` over
     ``multi_epoch_batches`` — without the dense (steps, bsz, D) stream.
@@ -621,9 +651,11 @@ def local_train(
     The deltas chain straight into :func:`compress_aggregate`.
     """
     if use_pallas:
+        packs = _note_step(
+            "local_train", _local_train_packs(params, data.shape, idx.shape))
         return _local_train_pallas(
             params, data, idx, _static_scalar(lr, "lr"),
-            _static_scalar(prox_mu, "prox_mu"), interpret,
+            _static_scalar(prox_mu, "prox_mu"), interpret, packs,
         )
     use_prox = not (isinstance(prox_mu, (int, float)) and prox_mu == 0.0)
     return _local_train_ref(params, data, idx, lr, prox_mu, use_prox)

@@ -29,6 +29,7 @@ not locked: record from one thread.
 | ``engine.publish`` | span, ns | ``Engine.run``: hand-off of trial (0, 0) to a store |
 | ``engine.local_train_pack`` | counter | ``Engine.run``, on the Pallas local-train path: clients per kernel tile |
 | ``engine.compress_tiles_per_step`` | counter | ``Engine.run``, on the Pallas compress path: tiles each grid step of the job's compress kernel bisects together |
+| ``engine.local_train_packs_per_step`` | counter | ``Engine.run``, on the Pallas local-train path: packs each grid step of the job's local-train kernel trains together |
 | ``engine.detector_params`` | counter | ``Engine.run``, once a job: the detector's d |
 | ``engine.local_windows`` | counter | ``Engine.run``, once a job: samples a sensor trains a round |
 """

@@ -21,7 +21,8 @@ from jax.flatten_util import ravel_pytree
 from repro.core import cooperation as coop
 from repro.core import hfl
 from repro.data.pipeline import multi_epoch_batches, multi_epoch_indices
-from repro.kernels import ops
+from repro.kernels import fused_agg, ops
+from repro.kernels import fused_local_train as flt
 from repro.launch.mesh import make_mesh
 from repro.models import autoencoder as ae
 from repro.optim.sgd import (
@@ -171,6 +172,64 @@ def test_packed_clients_equal_one_client_per_tile():
     np.testing.assert_allclose(
         np.asarray(l_packed), np.asarray(l_one), rtol=1e-6
     )
+
+
+@pytest.mark.parametrize(
+    "n,dim,mu,packs",
+    [
+        pytest.param(200, D, 0.0, 10, id="N200-divides"),
+        pytest.param(68, D, 0.0, 16, id="N68-ragged"),
+        pytest.param(7, D, 0.0, 2, id="N7-fewer-than-a-step"),
+        pytest.param(200, D, 0.01, 10, id="N200-fedprox"),
+        pytest.param(60, 38, 0.0, 10, id="D38-P3"),
+        pytest.param(20, 100, 0.01, 10, id="D100-P1"),
+    ],
+)
+def test_packs_per_step_equal_one_pack_a_step(n, dim, mu, packs):
+    """K packs a grid step, as the helper picks K from the call's shapes,
+    give each client exactly the deltas and loss of one pack a step: 50
+    packs at K = 10; 17 packs at K = 16 (a ragged last step of pad
+    packs); 2 packs, fewer clients than one step holds; FedProx; the
+    D = 38 layout (P = 3, 20 packs); a width over 64 (P = 1)."""
+    window, batch_size, epochs = 64, 32, 1
+    params = _params(dim=dim)
+    data = jax.random.normal(jax.random.key(n), (n, window, dim))
+    idx = _idx(n, window, batch_size, epochs)
+    assert ops._local_train_packs(params, data.shape, idx.shape) == packs
+    d_k, l_k = ops.local_train(
+        params, data, idx, 0.05, mu, use_pallas=True, interpret=True
+    )
+    d_1, l_1 = ops._local_train_pallas(params, data, idx, 0.05, mu, True, packs=1)
+    np.testing.assert_array_equal(np.asarray(d_k), np.asarray(d_1))
+    np.testing.assert_array_equal(np.asarray(l_k), np.asarray(l_1))
+
+
+def test_packs_per_step_helper():
+    """K divides the pack count where a divisor lies between half the cap
+    and the cap, never passes the cap or the VMEM budget, is at least 1,
+    and is smaller for widths whose packed blocks are larger."""
+    cap, budget = flt.MAX_PACKS_PER_STEP, fused_agg.VMEM_BUDGET
+    mib = 1 << 20
+    assert flt.local_train_packs_per_step(50, mib) == 10
+    assert flt.local_train_packs_per_step(128, mib) == cap
+    assert flt.local_train_packs_per_step(1, mib) == 1
+    assert flt.local_train_packs_per_step(3, 2 * budget) == 1
+    for n_packs in range(1, 200):
+        for per_pack, resident in ((mib, 0), (5 * mib, 10 * mib), (30 * mib, 0)):
+            k = flt.local_train_packs_per_step(n_packs, per_pack, resident)
+            most = min(cap, (budget - resident) // per_pack, n_packs)
+            assert 1 <= k <= most, (n_packs, per_pack)
+            assert resident + k * per_pack <= budget, (n_packs, per_pack)
+            divisors = [t for t in range(most // 2 + 1, most + 1) if n_packs % t == 0]
+            assert k == (max(divisors) if divisors else most), (n_packs, per_pack)
+
+    def packs(dim, hidden, n=200):
+        p = jax.eval_shape(lambda: ae.init(jax.random.key(0), dim, hidden))
+        return ops._local_train_packs(p, (n, 256, dim), (n, 40, 32))
+
+    paper = packs(D, HIDDEN)
+    assert ops.local_train_pack((1024, 512, 256, 512, 1024)) == 1
+    assert 1 <= packs(1024, (512, 256, 512)) < paper == 10
 
 
 def test_solver_dispatches_fused_and_matches_scan():

@@ -181,6 +181,42 @@ def test_the_pallas_path_records_the_local_train_pack_once_per_job(tmp_path, mon
     np.testing.assert_array_equal(telemetry.records("engine.local_train_pack"), [pack, pack])
 
 
+def test_the_oracle_path_records_no_local_train_packs_per_step(engine_run):
+    _, s = engine_run
+    assert "engine.local_train_packs_per_step" not in s
+
+
+def test_the_pallas_path_records_the_local_train_packs_per_step_once_per_job(
+        tmp_path, monkeypatch):
+    """The counter holds the packs per grid step that the job's local-train
+    kernel was handed, on the call that traced the program and on a later
+    call that reuses it."""
+    data = normalize(generate(jax.random.key(0), SyntheticConfig(
+        n_sensors=12, train_len=48, val_len=24, test_len=48)))
+    cfg = exp.make_config(n_sensors=12, n_fog=3, rounds=1, local_epochs=1)
+    # The Pallas local-train path, its kernel body interpreted on this host.
+    monkeypatch.setattr(eng_mod.Engine, "resolve_local_solver",
+                        lambda self, ls: ls.replace(use_pallas=True, interpret=True))
+    handed = []
+    kernel = ops._local_train_pallas
+
+    def spy(*args, **kw):
+        handed.append(args[-1])
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(ops, "_local_train_pallas", spy)
+    eng = eng_mod.Engine()
+    with jax.profiler.trace(str(tmp_path)):
+        for seeds in ((1,), (2,)):
+            eng.run("hfl-selective", cfg, seeds, data)
+    dim = data.train.shape[-1]
+    # 12 sensors, 4 a pack: 3 packs, all in one grid step.
+    assert ops.local_train_pack((dim, *eng.hidden, dim)) == 4
+    assert set(handed) == {3}
+    np.testing.assert_array_equal(
+        telemetry.records("engine.local_train_packs_per_step"), [3, 3])
+
+
 def test_the_pallas_path_records_the_compress_tiles_once_per_job(tmp_path, monkeypatch):
     """The counter holds the tiles per grid step that the job's compress
     kernel was handed, on the call that traced the program and on a later

@@ -96,18 +96,22 @@ def _kernel_names(fn, *args) -> list[str]:
 
 def test_local_train_compiles(one_chip):
     """The packed kernel (the paper AE puts 4 clients in each 128-lane
-    tile) compiles at N = 200 and 2,000 and keeps its wrapper's name."""
+    tile) compiles at N = 200, at the fleet cell's 512-client chunk and at
+    N = 2,000, training 10, 16 and 10 packs in each grid step, and keeps
+    its wrapper's name."""
     window, batch, epochs = 256, 32, 5
     steps = epochs * (window // batch)
     assert ops.local_train_pack(DIMS) == 4
-    for n in (N, 2000):
-        names = _kernel_names(
-            lambda p, x, idx: ops.local_train(p, x, idx, 0.01, 0.0, **PALLAS),
-            _ae_params(one_chip),
-            _spec(one_chip, (n, window, DIM)),
-            _spec(one_chip, (n, steps, batch), jnp.int32),
-        )
+    for n, packs in ((N, 10), (512, 16), (2000, 10)):
+        with ops.grid_steps_traced() as noted:
+            names = _kernel_names(
+                lambda p, x, idx: ops.local_train(p, x, idx, 0.01, 0.0, **PALLAS),
+                _ae_params(one_chip),
+                _spec(one_chip, (n, window, DIM)),
+                _spec(one_chip, (n, steps, batch), jnp.int32),
+            )
         assert names == ["local_train_blocks"], n
+        assert noted["local_train"] == [packs], (n, noted)
 
 
 @pytest.mark.parametrize("n_fog", [N_FOG, N], ids=["fogs", "identity"])
@@ -142,12 +146,13 @@ def test_wire_emit_compiles_at_the_cells_chunks(one_chip, n, d):
     Transformer cell's 8-client chunk of 590 blocks compile with more than
     one tile bisected in each grid step."""
     k_frac = comp.blockwise_k_frac(d, RHO_S)
-    with ops.compress_tiles_traced() as tiles:
+    with ops.grid_steps_traced() as steps:
         names = _kernel_names(
             lambda dl, e: ops.compress_wire(dl, e, k_frac, **PALLAS),
             _spec(one_chip, (n, d)), _spec(one_chip, (n, d)),
         )
     assert names == ["compress_wire_blocks"]
+    tiles = steps["compress"]
     assert len(tiles) == 1 and tiles[0] > 1, tiles
 
 
